@@ -7,7 +7,7 @@
 # the cwd lands on sys.path instead.
 PYTHON ?= python
 
-.PHONY: all test test-unit test-manifests lint sanitize chaos durability explore fleetbench replicabench partitionbench overloadbench zonedrill usagebench warmbench obs loadtest images bench dryrun platform serve spawn-latency suspend-bench webbench native kind-smoke conformance
+.PHONY: all test test-unit test-manifests lint sanitize chaos durability explore fleetbench replicabench partitionbench overloadbench zonedrill usagebench warmbench obs loadtest images bench chip-smoke dryrun platform serve spawn-latency suspend-bench webbench native kind-smoke conformance
 
 all: lint test
 
@@ -35,7 +35,7 @@ conformance:
 # cross-checks every os.environ knob against analysis/knobs.json,
 # GUIDE.md, and manifest env stanzas.
 lint:
-	$(PYTHON) -m compileall -q odh_kubeflow_tpu tests loadtest bench.py __graft_entry__.py
+	$(PYTHON) -m compileall -q odh_kubeflow_tpu tests loadtest bench.py chip_smoke.py __graft_entry__.py
 	$(PYTHON) -m odh_kubeflow_tpu.analysis
 	$(PYTHON) -m odh_kubeflow_tpu.analysis.knobs
 	$(PYTHON) -m odh_kubeflow_tpu.analysis.protocol
@@ -234,6 +234,12 @@ images:
 bench:
 	$(PYTHON) bench.py
 
+# the quickest proof that the system still starts on the chip: trainer
+# + completion server at full Llama-3.2-1B size, one process, one
+# device. Exits non-zero without a TPU (so: on the machine that has one)
+chip-smoke:
+	$(PYTHON) chip_smoke.py
+
 # all-in-one platform with the sim kubelet (see docs/GUIDE.md)
 platform:
 	$(PYTHON) -m odh_kubeflow_tpu.platform --sim
@@ -255,7 +261,7 @@ kind-smoke:
 
 # multi-chip sharding compile check on a virtual 8-device CPU mesh
 dryrun:
-	XLA_FLAGS="--xla_force_host_platform_device_count=8" $(PYTHON) -c \
+	$(PYTHON) -c \
 	  "import importlib.util; \
 	   s = importlib.util.spec_from_file_location('g', '__graft_entry__.py'); \
 	   m = importlib.util.module_from_spec(s); s.loader.exec_module(m); \

@@ -1,0 +1,483 @@
+"""The quickest proof that the system still starts on the chip.
+
+    python3 chip_smoke.py          (on a machine with a TPU; `make chip-smoke`)
+
+Drives the main path once, through the entry points a user has, at the
+full width and depth of Llama-3.2-1B (16 layers, D 2048, vocab 128256,
+bf16; random weights from a seed), in ONE process on ONE device:
+
+1. *kernels* — the three flash-attention kernels (forward, dq, dkv) at
+   the model's head shape (32 q / 8 kv heads × 64), with and without
+   ``segment_ids``, compiled by Mosaic and compared with the XLA
+   ``dense_attention`` reference (the comparison tests/ makes in
+   interpret mode on the CPU);
+2. *train* — ``Trainer`` with LoRA r16 at batch 8 × seq 1024: steps on
+   one repeated ``make_fake_batch`` (loss finite and falling), then on
+   batches from ``train.data.pack_documents`` (``segment_ids`` and
+   ``loss_mask`` present), every step run by the ahead-of-time
+   executable;
+3. *serve* — what ``python -m odh_kubeflow_tpu.models.serve --config
+   llama3_1b --int8`` builds, on a local port: concurrent completions
+   of different prompt lengths, two identical greedy prompts, one SSE
+   stream — every reply from the decode engine, which has not failed.
+
+It checks that no fallback that hides the device fired: attention
+resolved to ``flash``, no pallas op defaulted to interpret mode, the
+compiled steps contain Mosaic custom calls, the lazy jit compiled
+nothing, no completion came from the one-shot path.
+
+Exit code 0 and, as the LAST line of stdout,
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``
+only if every phase passed. Without a TPU (including an inherited
+``JAX_PLATFORMS=cpu``) it exits 2 and prints no result. It starts no
+process; the threads it starts (engine, HTTP server, clients) are
+stopped before it returns.
+"""
+
+from __future__ import annotations
+
+import functools
+import json
+import math
+import os
+import sys
+import threading
+import time
+import traceback
+
+import jax
+import jax.numpy as jnp
+
+BATCH, SEQ = 8, 1024
+FAKE_KEYS = ("tokens", "targets")
+PACKED_KEYS = ("tokens", "targets", "segment_ids", "loss_mask")
+
+
+class _CompileCounters:
+    """What jax's own monitoring says about compilation in this run:
+    compile requests that consulted the persistent cache, how many of
+    them it answered, how many entries it wrote, and the seconds the
+    backend spent compiling the rest."""
+
+    def __init__(self):
+        self.requests = self.hits = self.writes = 0
+        self.backend_compile_s = 0.0
+        jax.monitoring.register_event_listener(self._event)
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+
+    def _event(self, event, **_):
+        if event == "/jax/compilation_cache/compile_requests_use_cache":
+            self.requests += 1
+        elif event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.writes += 1  # jax counts a miss when it writes the entry
+
+    def _duration(self, event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.backend_compile_s += duration
+
+    def report(self) -> dict:
+        return {
+            "requests": self.requests,
+            "hits": self.hits,
+            "compiled_again": self.requests - self.hits,
+            "entries_written": self.writes,
+            "backend_compile_s": round(self.backend_compile_s, 1),
+        }
+
+
+def _rel_err(got, ref) -> float:
+    got = got.astype(jnp.float32)
+    ref = ref.astype(jnp.float32)
+    return float(jnp.linalg.norm(got - ref) / jnp.linalg.norm(ref))
+
+
+def assert_kernels_compile() -> None:
+    """The pallas ops pick interpret mode from the backend; on the chip
+    path none of them may."""
+    from odh_kubeflow_tpu.ops import (
+        pallas_attention,
+        pallas_grouped_matmul,
+        pallas_int4,
+    )
+
+    for mod in (pallas_attention, pallas_grouped_matmul, pallas_int4):
+        if mod._interpret_default():
+            raise AssertionError(
+                f"{mod.__name__} would run in interpret mode on this backend"
+            )
+
+
+def check_kernels(cfg) -> dict:
+    """Flash fwd + bwd against the dense reference at the training
+    leg's geometry (one 1024 block), with and without segment walls.
+    bf16 operands, so agreement is norm-wise: about 1e-2 on the chip,
+    4e-3 in interpret mode, O(1) when a kernel is wrong — bound 5e-2."""
+    from odh_kubeflow_tpu.ops import pallas_attention
+    from odh_kubeflow_tpu.ops.attention import dense_attention
+
+    B, S = 2, SEQ
+    kq, kk, kv, kt = jax.random.split(jax.random.key(7), 4)
+    shape_q = (B, S, cfg.num_heads, cfg.head_dim)
+    shape_kv = (B, S, cfg.num_kv_heads, cfg.head_dim)
+    q = jax.random.normal(kq, shape_q, jnp.bfloat16)
+    k = jax.random.normal(kk, shape_kv, jnp.bfloat16)
+    v = jax.random.normal(kv, shape_kv, jnp.bfloat16)
+    tangent = jax.random.normal(kt, shape_q, jnp.bfloat16)
+    # three documents a row, walls off any block grid
+    pos = jnp.arange(S)[None, :]
+    seg = (
+        1 + (pos >= (S * 3) // 10) + (pos >= (S * 3) // 4 + 9)
+    ).astype(jnp.int32) * jnp.ones((B, 1), jnp.int32)
+
+    # operands travel as arguments: an array closed over by a jitted
+    # function is lowered as a literal constant of the program
+    def fwd_and_grads(fn, q, k, v, tangent, segment_ids):
+        def loss(q, k, v):
+            out = fn(q, k, v, causal=True, segment_ids=segment_ids)
+            return jnp.sum(out.astype(jnp.float32) * tangent), out
+
+        (_, out), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True
+        )(q, k, v)
+        return (out, *grads)
+
+    flash = jax.jit(
+        functools.partial(fwd_and_grads, pallas_attention.flash_attention)
+    )
+    dense = jax.jit(functools.partial(fwd_and_grads, dense_attention))
+    errs = {}
+    for name, segment_ids in (("plain", None), ("segments", seg)):
+        got = flash(q, k, v, tangent, segment_ids)
+        with jax.default_matmul_precision("highest"):
+            ref = dense(q, k, v, tangent, segment_ids)
+        for part, g, r in zip(("out", "dq", "dk", "dv"), got, ref):
+            err = _rel_err(g, r)
+            errs[f"{name}.{part}"] = round(err, 5)
+            if not err < 5e-2:  # also catches NaN
+                raise AssertionError(
+                    f"flash {name} {part} disagrees with dense_attention: "
+                    f"relative error {err}"
+                )
+    return {"rel_err_vs_dense": errs}
+
+
+def _documents(vocab: int, n_tokens: int, seed: int):
+    """Seeded documents of heavy-tailed length (a few past one row)."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    docs, total = [], 0
+    while total < n_tokens:
+        n = int(min(2 + rng.pareto(1.2) * 120, 2.5 * SEQ))
+        docs.append(rng.integers(1, vocab, size=n, dtype=np.int32))
+        total += n
+    return docs
+
+
+def _finite_losses(metrics_list, what: str) -> list:
+    losses = [float(m["loss"]) for m in metrics_list]
+    gnorms = [float(m["grad_norm"]) for m in metrics_list]
+    if not all(math.isfinite(x) for x in losses + gnorms):
+        raise AssertionError(f"{what}: non-finite loss/grad {losses} {gnorms}")
+    if not all(g > 0 for g in gnorms):
+        raise AssertionError(
+            f"{what}: zero gradient norm — the backward did not reach "
+            f"the adapters: {gnorms}"
+        )
+    return losses
+
+
+def train_leg(cfg, device) -> dict:
+    from odh_kubeflow_tpu import native
+    from odh_kubeflow_tpu.models import LoraConfig
+    from odh_kubeflow_tpu.models.llama import resolved_attention_impl
+    from odh_kubeflow_tpu.parallel.mesh import MeshConfig, build_mesh
+    from odh_kubeflow_tpu.train import TrainConfig, Trainer
+    from odh_kubeflow_tpu.train.data import pack_documents
+
+    # Trainer(mesh=None) takes ALL of jax.devices(): on a four-chip
+    # host that silently becomes an fsdp=4 run
+    mesh = build_mesh(MeshConfig(fsdp=1), [device])
+    trainer = Trainer(
+        cfg,
+        TrainConfig(warmup_steps=2, total_steps=100),
+        lora_cfg=LoraConfig(rank=16),
+        mesh=mesh,
+        precompile_batch=(BATCH, SEQ, FAKE_KEYS),
+    )
+    trainer.precompile_async(BATCH, SEQ, PACKED_KEYS)
+    with jax.set_mesh(mesh):
+        impl = resolved_attention_impl(cfg)
+    if impl != "flash":
+        raise AssertionError(f"attention resolved to {impl!r}, not 'flash'")
+    used = sorted(str(d) for d in trainer.params["embed"].devices())
+    if used != [str(device)]:
+        raise AssertionError(f"trainer placed params on {used}, not {device}")
+
+    fake = trainer.make_fake_batch(BATCH, SEQ)
+    fake_losses = _finite_losses(
+        [trainer.train_step(fake) for _ in range(6)], "fake batch"
+    )
+    if not fake_losses[-1] < fake_losses[0]:
+        raise AssertionError(
+            f"loss did not fall on a repeated batch: {fake_losses}"
+        )
+
+    docs = _documents(cfg.vocab_size, 3 * BATCH * SEQ + SEQ, seed=11)
+    packed_metrics, n_segments = [], []
+    for batch in pack_documents(docs, BATCH, SEQ):
+        if set(batch) != set(PACKED_KEYS):
+            raise AssertionError(f"packed batch keys {sorted(batch)}")
+        n_segments.append(int(batch["segment_ids"].max()))
+        packed_metrics.append(trainer.train_step(batch))
+    if len(packed_metrics) < 3 or max(n_segments) < 2:
+        raise AssertionError(
+            f"packing gave {len(packed_metrics)} batches, max segments "
+            f"{n_segments}"
+        )
+    packed_losses = _finite_losses(packed_metrics, "packed batches")
+
+    # the steps above ran the ahead-of-time executables, and those hold
+    # Mosaic kernels: the lazy jit never compiled, both keys resolved
+    # to a Compiled, and its HLO calls tpu_custom_call
+    lazy = trainer._compiled._cache_size()
+    if lazy:
+        raise AssertionError(f"the lazy-jit step compiled {lazy} program(s)")
+    for keys in (FAKE_KEYS, PACKED_KEYS):
+        exe = trainer._aot.get((BATCH, SEQ, tuple(sorted(keys))))
+        if not isinstance(exe, jax.stages.Compiled):
+            raise AssertionError(f"no AOT executable for {keys}: {exe!r}")
+        if "tpu_custom_call" not in exe.as_text():
+            raise AssertionError(
+                f"the compiled step for {keys} holds no Mosaic kernel"
+            )
+    return {
+        "mesh": {k: v for k, v in mesh.shape.items() if v > 1} or "1 device",
+        "devices_used": used,
+        "attention_impl": impl,
+        "fake_batch_losses": [round(x, 4) for x in fake_losses],
+        "packed_losses": [round(x, 4) for x in packed_losses],
+        "packed_max_segments_per_row": n_segments,
+        "packer": "native" if native.available() else "python",
+        "lazy_jit_compiles": lazy,
+    }
+
+
+def _post(port: int, body: dict, timeout: float = 600.0):
+    import http.client
+
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=timeout)
+    try:
+        conn.request(
+            "POST",
+            "/v1/completions",
+            body=json.dumps(body),
+            headers={"Content-Type": "application/json"},
+        )
+        resp = conn.getresponse()
+        return resp.status, resp.getheader("Content-Type"), resp.read()
+    finally:
+        conn.close()
+
+
+def serve_leg(cfg) -> dict:
+    import numpy as np
+
+    from odh_kubeflow_tpu.models.serve import build_service, serve
+
+    # the CLI's own construction path (models/serve.py main)
+    service, args = build_service(
+        ["--config", "llama3_1b", "--int8", "--host", "127.0.0.1",
+         "--port", "0"]
+    )
+    engine = service.engine
+    httpd = serve(service, host=args.host, port=args.port)
+    port = httpd.server_address[1]
+    try:
+        rng = np.random.default_rng(5)
+        max_tokens = 16
+        prompts = [
+            rng.integers(1, cfg.vocab_size, size=n).tolist()
+            for n in (5, 40, 200)  # buckets 64, 64, 256
+        ]
+        twin = prompts[1]
+        bodies = [
+            {"prompt": p, "max_tokens": max_tokens} for p in prompts
+        ] + [{"prompt": twin, "max_tokens": max_tokens}]
+        replies: dict = {}
+
+        def client(i, body):
+            try:
+                replies[i] = _post(port, body)
+            except Exception as e:  # noqa: BLE001 — reported below
+                replies[i] = e
+
+        threads = [
+            threading.Thread(target=client, args=(i, b), daemon=True)
+            for i, b in enumerate(bodies)
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=900)
+        completions = []
+        for i in range(len(bodies)):
+            got = replies.get(i)
+            if not isinstance(got, tuple) or got[0] != 200:
+                raise AssertionError(f"completion {i}: {got!r}")
+            out = json.loads(got[2])
+            if out["usage"].get("engine") is not True:
+                raise AssertionError(
+                    f"completion {i} did not come from the engine: "
+                    f"{out['usage']}"
+                )
+            toks = out["completions"][0]
+            if len(toks) != max_tokens or not all(
+                isinstance(t, int) and 0 <= t < cfg.vocab_size for t in toks
+            ):
+                raise AssertionError(f"completion {i}: bad tokens {toks}")
+            completions.append(toks)
+        if completions[1] != completions[3]:
+            raise AssertionError(
+                "two identical greedy prompts gave different tokens: "
+                f"{completions[1]} vs {completions[3]}"
+            )
+
+        # one SSE stream of the same prompt: frames are tokens, the
+        # last frame repeats them, and they are the non-streamed answer
+        status, ctype, raw = _post(
+            port, {"prompt": twin, "max_tokens": max_tokens, "stream": True}
+        )
+        if status != 200 or ctype != "text/event-stream":
+            raise AssertionError(f"stream: {status} {ctype} {raw[:300]!r}")
+        frames = [
+            json.loads(line[len("data: "):])
+            for line in raw.decode().split("\n\n")
+            if line.startswith("data: ")
+        ]
+        streamed = [f["token"] for f in frames if "token" in f]
+        final = frames[-1]
+        if final.get("done") is not True or "error" in final:
+            raise AssertionError(f"stream ended badly: {final}")
+        if streamed != final["tokens"] or streamed != completions[1]:
+            raise AssertionError(
+                f"stream {streamed} / final {final['tokens']} / "
+                f"non-streamed {completions[1]}"
+            )
+        if engine.failure is not None:
+            raise AssertionError(f"engine failed: {engine.failure!r}")
+        used = sorted(
+            str(d) for d in engine._state["cache"]["k"].devices()
+        )
+        return {
+            "requests": len(bodies) + 1,
+            "prompt_lengths": [len(p) for p in prompts] + [len(twin)] * 2,
+            "tokens_each": max_tokens,
+            "all_from_engine": True,
+            "engine_failure": None,
+            "devices_used": used,
+            "decode_steps": engine.decode_steps,
+            "tokens_emitted": engine.tokens_emitted,
+        }
+    finally:
+        httpd.shutdown()
+        httpd.server_close()
+        engine.stop()
+
+
+def main() -> int:
+    t0 = time.monotonic()
+    try:
+        devices = jax.devices()
+    except RuntimeError as e:  # the named platform could not start
+        print(f"chip_smoke: no chip found: {e}", file=sys.stderr)
+        return 2
+    if devices[0].platform != "tpu":
+        print(
+            "chip_smoke: no chip found: jax.devices()[0].platform is "
+            f"{devices[0].platform!r} (JAX_PLATFORMS="
+            f"{os.environ.get('JAX_PLATFORMS')!r}); this smoke runs on a "
+            "TPU only",
+            file=sys.stderr,
+        )
+        return 2
+
+    import jaxlib
+
+    from odh_kubeflow_tpu.models import LlamaConfig
+    from odh_kubeflow_tpu.warmup.compilecache import install_process_cache
+
+    assert_kernels_compile()
+    # the kernels phase compiles before any Trainer or engine exists:
+    # join the cache now, by the same rule they follow
+    cache_dir = install_process_cache()
+    counters = _CompileCounters()
+    device = devices[0]
+    device_info = {
+        "platform": device.platform,
+        "kind": device.device_kind,
+        "count": len(devices),
+    }
+    cfg = LlamaConfig.llama3_1b(dtype=jnp.bfloat16)
+    report: dict = {
+        "device": {**device_info, "used": [str(device)]},
+        "versions": {
+            "jax": jax.__version__,
+            "jaxlib": jaxlib.__version__,
+            "libtpu": _libtpu_version(),
+        },
+        "model": {
+            "config": "llama3_1b",
+            "layers": cfg.num_layers,
+            "hidden": cfg.hidden_size,
+            "vocab": cfg.vocab_size,
+            "params": cfg.num_params(),
+        },
+    }
+    phases = (
+        ("kernels", lambda: check_kernels(cfg)),
+        ("train", lambda: train_leg(cfg, device)),
+        ("serve", lambda: serve_leg(cfg)),
+    )
+    failed = []
+    for name, fn in phases:
+        t_phase = time.monotonic()
+        try:
+            result = fn()
+            result["ok"] = True
+        except Exception as e:  # noqa: BLE001 — a phase fails the smoke
+            traceback.print_exc()
+            result = {"ok": False, "error": f"{type(e).__name__}: {e}"[:500]}
+            failed.append(name)
+        result["wall_s"] = round(time.monotonic() - t_phase, 1)
+        report[name] = result
+        print(f"chip_smoke phase {name}: {json.dumps(result)}", flush=True)
+    report["compile_cache"] = {
+        "dir": cache_dir,
+        "placed_by": "JAX_COMPILATION_CACHE_DIR"
+        if os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        else "checkout",
+        **counters.report(),
+    }
+    report["wall_s"] = round(time.monotonic() - t0, 1)
+    print(f"chip_smoke report: {json.dumps(report)}", flush=True)
+    if failed:
+        print(f"chip_smoke: FAILED phases: {failed}", file=sys.stderr)
+        return 1
+    print(json.dumps({"ok": True, "device": device_info}), flush=True)
+    return 0
+
+
+def _libtpu_version() -> str:
+    from importlib import metadata
+
+    try:
+        return metadata.version("libtpu")
+    except metadata.PackageNotFoundError:
+        return "not installed"
+
+
+if __name__ == "__main__":
+    sys.exit(main())

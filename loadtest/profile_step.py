@@ -129,7 +129,7 @@ def main() -> None:
     # warm: compile + one steady-state step
     for _ in range(2):
         metrics = trainer.train_step(fake)
-    float(metrics["loss"])  # host transfer = sync on the relay backend
+    float(metrics["loss"])  # host fetch: waits for the queued steps
 
     logdir = tempfile.mkdtemp(prefix="prof_")
     with jax.profiler.trace(logdir):

@@ -75,8 +75,8 @@ def main() -> None:
     from odh_kubeflow_tpu.utils.tpu import peak_flops_per_chip
 
     peak_fl = peak_flops_per_chip(jax.devices()[0])
-    mfu = bench["flops_per_s"] / peak_fl if peak_fl else 0.0
-    mfu_3x = bench["train_equiv_flops_per_s"] / peak_fl if peak_fl else 0.0
+    mfu = bench["flops_per_s"] / peak_fl
+    mfu_3x = bench["train_equiv_flops_per_s"] / peak_fl
     print(
         json.dumps(
             {

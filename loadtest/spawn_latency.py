@@ -14,10 +14,16 @@ BASELINE.md's headline latency metric. Two measured segments:
    trainer, and run one train step to a fetched loss. Cold-compile
    time is the dominant term and is measured for real — twice, in
    subprocesses routed through the compile-cache *service* (warmup/
-   subsystem): the cold run populates a staging dir that is ingested
-   as content-addressed ``CompileCacheEntry`` artifacts, the warm run
-   gets a dir materialized back from the service — the exact path a
-   warm-pool standby's pre-compiled cache mount takes.
+   subsystem): the cold run fills the process cache directory, which
+   is ingested as content-addressed ``CompileCacheEntry`` artifacts;
+   the directory is emptied and materialized back from the service
+   for the warm run — the exact path a warm-pool standby's
+   pre-compiled cache mount takes. The directory is the one every
+   process of this checkout uses (``JAX_COMPILATION_CACHE_DIR`` if
+   set, else the checkout's own): the cold leg EMPTIES it.
+
+This process initialises no JAX backend: on a machine with a chip the
+chip belongs to the child that runs the first step.
 
 ``--warm-only`` (``make warmbench``) needs no accelerator: it races a
 cold spawn against a warm-pool claim in ONE sim run (the cold spawn
@@ -336,7 +342,7 @@ def measure_first_jax_step() -> dict:
     }
     t_step = time.monotonic()
     metrics = trainer.train_step(batch)
-    loss = float(metrics["loss"])  # host transfer = the only real sync
+    loss = float(metrics["loss"])  # host fetch: waits for the step
     first_step_s = time.monotonic() - t_step
     return {
         "device": getattr(devices[0], "device_kind", "cpu"),
@@ -399,27 +405,26 @@ def record(result: dict) -> None:
     path.write_text(text)
 
 
-def _first_step_subprocess(cache_dir: str) -> dict:
-    """Run measure_first_jax_step in a fresh interpreter with the
-    persistent compilation cache pointed at ``cache_dir`` — the only
-    way to measure a cold/warm pair (an in-process rerun would hit
-    jax's in-memory jit cache and measure nothing)."""
-    import os
+def _child(flag: str) -> dict:
+    """Run one ``--first-step-only`` / ``--compile-probe`` leg in a
+    fresh interpreter — the only way to measure a cold/warm pair (an
+    in-process rerun would hit jax's in-memory jit cache and measure
+    nothing). The child inherits the environment and finds its cache
+    directory by the one rule (``compilecache.process_cache_dir``);
+    nothing here re-points it."""
     import subprocess
 
-    env = dict(
-        os.environ,
-        JAX_COMPILATION_CACHE_DIR=cache_dir,
-        JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="1",
-    )
     out = subprocess.run(
-        [sys.executable, "-m", "loadtest.spawn_latency", "--first-step-only"],
-        env=env,
+        [sys.executable, "-m", "loadtest.spawn_latency", flag],
         capture_output=True,
         text=True,
-        check=True,
         timeout=580,
     )
+    if out.returncode:
+        raise RuntimeError(
+            f"spawn_latency {flag} exited {out.returncode}:\n"
+            f"{out.stderr[-4000:]}"
+        )
     return json.loads(out.stdout.strip().splitlines()[-1])
 
 
@@ -427,8 +432,6 @@ def _cache_service(root: str):
     """A standalone compile-cache service over a throwaway apiserver —
     the same CompileCacheService the platform embeds, so the bench
     exercises the real ingest/materialize/GC path, not a lookalike."""
-    import os
-
     from odh_kubeflow_tpu.machinery.store import APIServer
     from odh_kubeflow_tpu.warmup import register_warmup
     from odh_kubeflow_tpu.warmup.compilecache import (
@@ -438,40 +441,55 @@ def _cache_service(root: str):
 
     api = APIServer()
     register_warmup(api)
-    return CompileCacheService(
-        api, CompileCacheConfig(cache_dir=os.path.join(root, "svc"))
-    )
+    return CompileCacheService(api, CompileCacheConfig(cache_dir=root))
+
+
+def _empty_dir(path: str) -> None:
+    """Leave ``path`` existing and empty (the path itself must not
+    change: it is part of jax's cache key)."""
+    import os
+    import shutil
+
+    os.makedirs(path, exist_ok=True)
+    for name in os.listdir(path):
+        full = os.path.join(path, name)
+        if os.path.isdir(full) and not os.path.islink(full):
+            shutil.rmtree(full)
+        else:
+            os.unlink(full)
 
 
 def measure_compile_cache_roundtrip(probe: bool = False) -> dict:
     """Cold subprocess → ingest into the service → materialize → warm
     subprocess. ``probe=True`` swaps the Llama trainer for a small
     compile-heavy jitted probe so the roundtrip runs on CPU in CI."""
-    import os
     import tempfile
 
-    import shutil
+    from odh_kubeflow_tpu.warmup.compilecache import process_cache_dir
 
-    runner = _probe_subprocess if probe else _first_step_subprocess
+    flag = "--compile-probe" if probe else "--first-step-only"
     topo = "bench"
-    with tempfile.TemporaryDirectory(prefix="warmcc-") as root:
-        svc = _cache_service(root)
-        # XLA folds the cache-dir path into the compile-env key, so a
-        # hit requires the SAME mount path cold and warm — which is the
-        # production contract anyway: COMPILE_CACHE_MOUNT pins one
-        # stable path into every pod
-        mount = os.path.join(root, "mount")
-        os.makedirs(mount)
-        cold = runner(mount)  # cold: fills the mount
+    # XLA folds the cache-dir path into the compile-env key, so a hit
+    # requires the SAME path cold and warm — which is the production
+    # contract anyway: COMPILE_CACHE_MOUNT pins one stable path into
+    # every pod. Here it is the directory the children resolve
+    # themselves; a cold leg empties it, it does not invent another.
+    mount = process_cache_dir()
+    # the service's artifact store is not a process's cache directory
+    with tempfile.TemporaryDirectory(prefix="warmcc-svc-") as svc_root:
+        svc = _cache_service(svc_root)
+        _empty_dir(mount)
+        cold = _child(flag)  # cold: fills the mount
         ingested = svc.ingest_dir(mount, topology=topo)
-        shutil.rmtree(mount)  # fresh pod: the mount starts empty ...
+        _empty_dir(mount)  # fresh pod: the mount starts empty ...
         materialized = svc.materialize_dir(mount, topology=topo)
-        warm = runner(mount)  # ... holding only what the service served
+        warm = _child(flag)  # ... holding only what the service served
         stats = svc.stats()
     return {
         "first_step": cold,
         "first_step_warm": warm,
         "compile_cache": {
+            "dir": mount,
             "ingested": ingested,
             "materialized": materialized,
             **stats,
@@ -500,30 +518,8 @@ def _compile_probe() -> dict:
     step(x).block_until_ready()
     return {
         "first_step_compile_s": round(time.monotonic() - t0, 3),
-        "cache_dir": cache_dir or "",
+        "cache_dir": cache_dir,
     }
-
-
-def _probe_subprocess(cache_dir: str) -> dict:
-    import os
-    import subprocess
-
-    env = dict(
-        os.environ,
-        JAX_COMPILATION_CACHE_DIR=cache_dir,
-        # the probe is small; cache everything so the warm run hits
-        JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
-        JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0",
-    )
-    out = subprocess.run(
-        [sys.executable, "-m", "loadtest.spawn_latency", "--compile-probe"],
-        env=env,
-        capture_output=True,
-        text=True,
-        check=True,
-        timeout=580,
-    )
-    return json.loads(out.stdout.strip().splitlines()[-1])
 
 
 def measure_warm_spawn() -> dict:
@@ -671,14 +667,13 @@ def main() -> None:
     parser.add_argument(
         "--first-step-only",
         action="store_true",
-        help="internal: just the ready→first-step half, honoring "
-        "JAX_COMPILATION_CACHE_DIR from the environment",
+        help="just the ready→first-step half (the user's first cell), "
+        "in this process",
     )
     parser.add_argument(
         "--compile-probe",
         action="store_true",
-        help="internal: the compile-heavy CPU probe, honoring "
-        "JAX_COMPILATION_CACHE_DIR from the environment",
+        help="internal: the compile-heavy CPU probe",
     )
     parser.add_argument(
         "--warm-only",

@@ -348,7 +348,7 @@ def main() -> int:
     import jax
 
     # control-plane logic + a tiny trainer: CPU is the right venue even
-    # when a TPU is attached (deterministic, no remote compiles)
+    # when a TPU is attached (deterministic, and the chip stays free)
     try:
         jax.config.update("jax_platforms", "cpu")
     except RuntimeError:

@@ -193,15 +193,15 @@ class DecodeEngine:
         spec_k: int = 4,
         spec_rounds_per_call: int = 4,
         metrics_registry: Optional[prometheus.Registry] = None,
-        compile_cache_dir: Optional[str] = None,
     ):
         # persistent XLA compile cache (warmup/ subsystem): the serving
         # path's prefill/decode programs are the biggest cold-start
-        # compiles after the train step. Explicit kwarg wins; falls back
-        # to JAX_COMPILATION_CACHE_DIR; no-op when neither is set.
+        # compiles after the train step. The directory is placed from
+        # outside (JAX_COMPILATION_CACHE_DIR) or is the fixed
+        # in-checkout one — never chosen here.
         from odh_kubeflow_tpu.warmup.compilecache import install_process_cache
 
-        install_process_cache(compile_cache_dir)
+        install_process_cache()
 
         self.params = params
         self.cfg = cfg
@@ -241,10 +241,9 @@ class DecodeEngine:
         self.draft_params = draft_params
         self.draft_cfg = draft_cfg
         self.spec_k = spec_k
-        # host→device round-trips dominate small per-call programs (a
-        # dispatch costs ~ms locally, tens of ms over a relay): run
-        # several speculative rounds inside one jitted call, exactly as
-        # the token path batches `chunk` steps
+        # a speculative round is a small program next to the host's
+        # dispatch + fetch of it: run several rounds inside one jitted
+        # call, exactly as the token path batches `chunk` steps
         self.spec_rounds_per_call = max(1, spec_rounds_per_call)
         if draft_params is not None:
             assert draft_cfg is not None, "draft_params needs draft_cfg"
@@ -390,9 +389,8 @@ class DecodeEngine:
     def _unpack_admission(packed, bucket):
         """One host→device transfer per admission: ``packed`` [1,
         bucket+7] int32 = padded prompt ‖ [L, slot, max_tokens, top_k,
-        eos, temp_bits, top_p_bits] (floats bit-cast). Relay transports
-        charge a full round-trip per array — six scalar uploads per
-        admission measured ~2s of the ~3s admission cost."""
+        eos, temp_bits, top_p_bits] (floats bit-cast): the prompt and
+        its six request scalars travel as one array, not seven."""
         prompt = packed[:, :bucket]
         meta = packed[0, bucket:]
         length, slot, max_tokens, top_k, eos = (
@@ -975,7 +973,7 @@ class DecodeEngine:
         reference deleted memory. Fail every in-flight and queued
         request immediately (their ``result()`` raises instead of
         hanging out a timeout), and make future ``submit()`` raise so
-        callers fall back to the one-shot path. Idempotent: the first
+        the server answers with an error. Idempotent: the first
         failure wins (the clean-stop drain must not overwrite a device
         error) and re-finishing an already-finished request is a no-op
         for its consumers."""

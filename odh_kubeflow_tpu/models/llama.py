@@ -23,6 +23,7 @@ This model is the flagship workload for the platform's north star
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Callable, Optional
 
@@ -492,11 +493,7 @@ def resolved_attention_impl(cfg: LlamaConfig) -> str:
     am = jax.sharding.get_abstract_mesh()
     if not am.empty and am.shape.get(AXIS_CONTEXT, 1) > 1:
         return "ring"
-    try:
-        backend = jax.default_backend()
-    except Exception:  # noqa: BLE001 — no backend yet
-        backend = "cpu"
-    return "flash" if backend == "tpu" else "dense"
+    return "flash" if jax.default_backend() == "tpu" else "dense"
 
 
 def _select_attention(cfg: LlamaConfig) -> Callable:
@@ -505,27 +502,69 @@ def _select_attention(cfg: LlamaConfig) -> Callable:
     if cfg.attention_impl == "dense":
         return partial(dense_attention, causal=True)
     if cfg.attention_impl == "flash":
-        try:
-            from odh_kubeflow_tpu.ops.pallas_attention import flash_attention
-        except ImportError as e:
-            raise NotImplementedError(
-                "attention_impl='flash' requires ops/pallas_attention (pallas "
-                "TPU kernel); not available in this build"
-            ) from e
-        return partial(flash_attention, causal=True)
+        return _flash_per_shard
     if cfg.attention_impl == "ring":
-        try:
-            from odh_kubeflow_tpu.parallel.ring_attention import ring_attention
-        except ImportError as e:
-            raise NotImplementedError(
-                "attention_impl='ring' requires parallel/ring_attention "
-                "(context-parallel mesh axis); not available in this build"
-            ) from e
+        from odh_kubeflow_tpu.parallel.ring_attention import ring_attention
+
         return partial(ring_attention, causal=True)
     raise ValueError(
         f"unknown attention_impl {cfg.attention_impl!r}; "
         "expected 'dense', 'flash', or 'ring'"
     )
+
+
+def _flash_per_shard(q, k, v, *, segment_ids=None):
+    """Causal flash attention that also runs under a multi-device mesh.
+
+    GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+    shard_map" — and Mosaic wants EVERY mesh axis manual, trivial ones
+    included), so under a mesh the kernel runs per shard inside a
+    shard_map over the whole mesh: batch rows over (data, fsdp, expert)
+    — the layout ``batch_spec`` already gives the batch — and heads
+    over ``tensor``, each when it divides (else that dimension
+    replicates and XLA gathers it at the boundary). Attention is
+    independent across rows and heads: no collective. With no mesh, or
+    one device, the kernel is called directly.
+
+    Inside a pipeline stage ``pipe`` is already Manual. A nested map
+    over the remaining axes computes the right thing, but Mosaic's
+    check reads only the innermost map's axes and still refuses; naming
+    ``pipe`` again passes the check and makes shard_map psum the
+    cotangents over it — wrong gradients (both seen in PR 21: four-chip
+    run, CPU cross-lowering). So on the TPU flash inside a pipeline
+    stage is an error (ROADMAP S7); in interpret mode the kernel is
+    ordinary ops that GSPMD partitions itself."""
+    from odh_kubeflow_tpu.ops.pallas_attention import flash_attention
+
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty or am.size == 1:
+        return flash_attention(q, k, v, causal=True, segment_ids=segment_ids)
+    if any(t != jax.sharding.AxisType.Auto for t in am.axis_types):
+        if jax.default_backend() == "tpu":
+            raise NotImplementedError(
+                "flash attention inside a partly-manual shard_map (a "
+                "pipeline stage) cannot be lowered by Mosaic; set "
+                "attention_impl='dense' for pipelined meshes (ROADMAP S7)"
+            )
+        return flash_attention(q, k, v, causal=True, segment_ids=segment_ids)
+    batch_ax = tuple(
+        a for a in (AXIS_DATA, AXIS_FSDP, AXIS_EXPERT) if a in am.axis_names
+    )
+    if not batch_ax or q.shape[0] % math.prod(am.shape[a] for a in batch_ax):
+        batch_ax = None
+    t = am.shape.get(AXIS_TENSOR, 1)
+    head_ax = AXIS_TENSOR if t > 1 and k.shape[2] % t == 0 else None
+    qkv_spec = P(batch_ax, None, head_ax, None)
+    args, in_specs = (q, k, v), (qkv_spec, qkv_spec, qkv_spec)
+    if segment_ids is not None:
+        args, in_specs = (*args, segment_ids), (*in_specs, P(batch_ax, None))
+
+    def local(q_, k_, v_, seg_=None):
+        return flash_attention(q_, k_, v_, causal=True, segment_ids=seg_)
+
+    return jax.shard_map(
+        local, mesh=am, in_specs=in_specs, out_specs=qkv_spec, check_vma=False
+    )(*args)
 
 
 def _make_layer_fn(cfg: LlamaConfig, attention_fn: Callable,

@@ -1250,7 +1250,11 @@ def _moe_mlp_grouped_ep(
         mesh=am,
         in_specs=(bspec, bspec, mspec, bank_specs, P(None)),
         out_specs=(bspec, P()),
-        axis_names=frozenset(batch_axes),
+        # manual over EVERY Auto axis, not just the batch axes the
+        # specs name: Mosaic refuses to lower a kernel while any mesh
+        # axis is left to GSPMD, trivial ones included (tensor/context
+        # are size 1 here — _grouped_ep_usable — so they only replicate)
+        axis_names=frozenset(auto),
         check_vma=False,
     )(x, router_logits, mask, banks, base)
     out = constrain(out, llama._activation_spec())

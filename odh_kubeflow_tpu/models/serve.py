@@ -88,10 +88,8 @@ class CompletionService:
         self.max_compiled = 32
         # continuous batching (models/engine.py): concurrent requests
         # join a persistent slot-batched decode loop instead of
-        # serialising behind the lock — measured 1.75x aggregate tok/s
-        # at 8 staggered streams on one v5e (loadtest/
-        # continuous_batching.py). Off (0) falls back to the one-shot
-        # bucketed path for every request.
+        # serialising behind the lock (loadtest/continuous_batching.py).
+        # Off (0) takes the one-shot bucketed path for every request.
         self.engine = None
         if engine_slots > 0:
             from odh_kubeflow_tpu.models.engine import DecodeEngine
@@ -180,6 +178,12 @@ class CompletionService:
         deterministic one-shot decodes (and a 400 on streams)."""
         if not prompts or any(not p for p in prompts):
             raise ValueError("prompts must be non-empty token-id lists")
+        eng = self.engine
+        if eng is not None and eng.failure is not None:
+            # a server built around an engine whose engine died is
+            # down: answering from the one-shot path would report 200
+            # over a lost device (the stream handler says 500 too)
+            raise RuntimeError(f"decode engine is down: {eng.failure!r}")
 
         # greedy single-prompt requests take the speculative path when
         # a draft model is attached: identical output, lower latency
@@ -195,13 +199,11 @@ class CompletionService:
         # whose rng is reproducible per call. ALL prompts are checked
         # against the engine bounds before any is submitted, so a
         # too-long prompt can't strand its batchmates in running slots
-        # while the fallback recomputes everything.
-        eng = self.engine
+        # while the one-shot path recomputes everything.
         if (
             eng is not None
             and not speculate
             and seed is None
-            and eng.failure is None
             and all(
                 len(p) <= eng.prompt_buckets[-1]
                 and len(p) + max_tokens <= eng.max_len
@@ -410,16 +412,16 @@ def serve(
     return httpd
 
 
-def main(argv: Optional[list] = None) -> None:
-    """``python -m odh_kubeflow_tpu.models.serve`` — serve a model.
+def build_service(argv: Optional[list] = None):
+    """Parse the CLI, load the params and build the service — everything
+    ``python -m odh_kubeflow_tpu.models.serve`` does before it binds a
+    port. Returns ``(service, args)``.
 
     Loads base params (random-init demo mode without --checkpoint; a
     LoRA adapter checkpoint from ``train/checkpoint.py`` gets merged
-    when one is given), optionally quantizes to int8, and serves
-    completions.
+    when one is given) and optionally quantizes to int8.
     """
     import argparse
-    import time
 
     from odh_kubeflow_tpu.models.llama import init_params
 
@@ -495,18 +497,10 @@ def main(argv: Optional[list] = None) -> None:
             from odh_kubeflow_tpu.models.quant import quantize_params
 
             params = jax.jit(quantize_params, donate_argnums=0)(params)
-        service = CompletionService(
-            params, cfg, engine_slots=args.engine_slots
+        return (
+            CompletionService(params, cfg, engine_slots=args.engine_slots),
+            args,
         )
-        httpd = serve(service, host=args.host, port=args.port)
-        print(
-            f"completion server on http://{args.host}:"
-            f"{httpd.server_address[1]} (config={args.config}, "
-            f"int8={args.int8})",
-            flush=True,
-        )
-        while True:
-            time.sleep(3600)
 
     cfg = getattr(LlamaConfig, args.config)(dtype=jnp.bfloat16)
 
@@ -565,6 +559,15 @@ def main(argv: Optional[list] = None) -> None:
         spec_k=args.spec_k,
         engine_slots=args.engine_slots,
     )
+    return service, args
+
+
+def main(argv: Optional[list] = None) -> None:
+    """``python -m odh_kubeflow_tpu.models.serve`` — build the service
+    (:func:`build_service`), bind the port, and block."""
+    import time
+
+    service, args = build_service(argv)
     httpd = serve(service, host=args.host, port=args.port)
     print(
         f"completion server on http://{args.host}:{httpd.server_address[1]}"

@@ -4,9 +4,12 @@ The TPU compute path is JAX/XLA; these are the host-side hot loops
 around it. Each component ships as a single .cpp with a plain C ABI
 (this image has no pybind11) plus a ctypes wrapper here. The shared
 object is built on first use with the system g++ and cached next to the
-source; everything degrades gracefully to the pure-Python
-implementation when no compiler is available (``available()`` →
-False), so the package has no hard native dependency.
+source under a name that carries the digest of that source (and of the
+build flags), so an object built from another tree — a copy, a
+checkout, a stale build — is never loaded: its name does not match.
+Everything degrades gracefully to the pure-Python implementation when
+no compiler is available (``available()`` → False), so the package has
+no hard native dependency.
 
 Build explicitly with ``make native`` (top-level Makefile) or let the
 first import compile lazily.
@@ -15,6 +18,8 @@ first import compile lazily.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import shutil
 import subprocess
@@ -26,9 +31,9 @@ import numpy as np
 
 _DIR = os.path.dirname(__file__)
 _SRC = os.path.join(_DIR, "packer.cpp")
-_SO = os.path.join(_DIR, "libodhkf_native.so")
+_SO_STEM = "libodhkf_native"
 _JT_SRC = os.path.join(_DIR, "jsontree.cpp")
-_JT_SO = os.path.join(_DIR, "_odhkf_jsontree.so")
+_JT_SO_STEM = "_odhkf_jsontree"
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -37,10 +42,18 @@ _jt_mod = None
 _jt_tried = False
 
 
-def _compile(src: str, out: str, extra: list[str], force: bool) -> Optional[str]:
+def _compile(src: str, stem: str, extra: list[str], force: bool) -> Optional[str]:
+    """Build ``src`` into ``<stem>.<digest>.so`` beside it (or reuse
+    that file), where the digest covers the source bytes and the extra
+    flags. mtimes say nothing after a copy or a checkout; the content
+    does."""
+    h = hashlib.sha256()
+    with open(src, "rb") as f:
+        h.update(f.read())
+    h.update("\0".join(extra).encode())
+    out = os.path.join(_DIR, f"{stem}.{h.hexdigest()[:16]}.so")
     if not force and os.path.exists(out):
-        if os.path.getmtime(out) >= os.path.getmtime(src):
-            return out
+        return out
     cxx = shutil.which("g++") or shutil.which("c++") or shutil.which("clang++")
     if cxx is None:
         return None
@@ -56,6 +69,12 @@ def _compile(src: str, out: str, extra: list[str], force: bool) -> Optional[str]
     finally:
         if os.path.exists(tmp):
             os.unlink(tmp)
+    for stale in glob.glob(os.path.join(_DIR, f"{stem}.*.so")):
+        if stale != out:
+            try:
+                os.unlink(stale)  # built from a source that is gone
+            except OSError:
+                pass
     return out
 
 
@@ -70,13 +89,13 @@ def build(force: bool = False) -> Optional[str]:
     try:
         _compile(
             _JT_SRC,
-            _JT_SO,
+            _JT_SO_STEM,
             ["-I" + sysconfig.get_paths()["include"]],
             force,
         )
     except (OSError, subprocess.CalledProcessError):
         pass
-    return _compile(_SRC, _SO, [], force)
+    return _compile(_SRC, _SO_STEM, [], force)
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -128,7 +147,7 @@ def _jsontree_module():
 
             so = _compile(
                 _JT_SRC,
-                _JT_SO,
+                _JT_SO_STEM,
                 ["-I" + sysconfig.get_paths()["include"]],
                 False,
             )

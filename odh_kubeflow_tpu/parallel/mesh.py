@@ -110,18 +110,22 @@ def build_mesh(
     if config is None:
         config = local_mesh_config(devices)
     config.validate(len(devices))
-    if len(devices) == 1:
+    if len(devices) == 1 or _all_cpu(devices):
         device_grid = np.array(devices).reshape(config.shape)
     else:
-        try:
-            device_grid = mesh_utils.create_device_mesh(
-                config.shape, devices=devices
-            )
-        except (ValueError, AssertionError):
-            # CPU / virtual device fallback: topology-aware assignment is
-            # a TPU-only concern; any assignment is functionally correct.
-            device_grid = np.array(devices).reshape(config.shape)
+        device_grid = mesh_utils.create_device_mesh(
+            config.shape, devices=devices
+        )
     return Mesh(device_grid, AXIS_ORDER)
+
+
+def _all_cpu(devices: Sequence[jax.Device]) -> bool:
+    """Virtual CPU devices have no topology: any assignment is
+    functionally correct, so list order is used. On an accelerator a
+    failed topology-aware assignment is an error, never a reshape —
+    list order there silently puts the bandwidth-hungry axes on
+    non-adjacent chips."""
+    return all(d.platform == "cpu" for d in devices)
 
 
 def build_hybrid_mesh(
@@ -135,8 +139,8 @@ def build_hybrid_mesh(
     v5e-8 slices doing FSDP inside each slice and gradient all-reduce
     across slices, the standard multislice recipe. On real TPU
     multislice the topology-aware assignment keeps DCN axes on slice
-    boundaries; virtual/CPU devices fall back to a plain reshape
-    (functionally identical)."""
+    boundaries; virtual CPU devices take a plain reshape (functionally
+    identical)."""
     devices = list(devices if devices is not None else jax.devices())
     shape = tuple(i * d for i, d in zip(ici.shape, dcn.shape))
     if math.prod(shape) != len(devices):
@@ -144,12 +148,12 @@ def build_hybrid_mesh(
             f"ici {ici.shape} × dcn {dcn.shape} = {math.prod(shape)} devices, "
             f"but {len(devices)} are available"
         )
-    try:
+    if _all_cpu(devices):
+        device_grid = np.array(devices).reshape(shape)
+    else:
         device_grid = mesh_utils.create_hybrid_device_mesh(
             ici.shape, dcn.shape, devices=devices
         )
-    except (ValueError, AssertionError, KeyError):
-        device_grid = np.array(devices).reshape(shape)
     return Mesh(device_grid, AXIS_ORDER)
 
 

@@ -4,12 +4,20 @@ UID.
 Session state is an opaque JSON-able tree (kernel variables, execution
 counters — whatever the in-pod snapshot hook hands over). It is
 canonically serialized once, digested (sha256 — the bit-identity
-receipt the resume path and the property tests verify), and written
-through ``train.checkpoint.CheckpointManager`` — the same orbax-backed
-manager training state uses, so session snapshots inherit its
-async-capable IO, ``max_to_keep`` GC, and fsspec path support (PVC
-paths and ``gs://`` buckets alike). Where orbax/jax is unavailable the
-store degrades to plain JSON files with the same layout and receipts.
+receipt the resume path and the property tests verify), and written as
+plain files (``backend="json"``, the default) or, on request, through
+``train.checkpoint.CheckpointManager`` (``backend="orbax"`` — the
+manager training state uses, for its fsspec path support: ``gs://``
+buckets beside PVC paths). Layout and receipts are the same.
+
+**The store runs in the control plane, which must leave the
+accelerator alone**: a chip belongs to one process, and a platform
+process that initialised a JAX backend would take it from the notebook
+kernel it is about to start. The default backend imports no JAX at
+all. The orbax backend hands orbax numpy arrays and asks for numpy
+back, so this module touches no device — but orbax itself calls
+``jax.process_index()``, which initialises the backends, so it is for
+control planes on hosts without a chip.
 
 **Zone replication** (:class:`ReplicatedCheckpointStore`): a single
 backing store is one failure domain — a zone loss takes every
@@ -55,28 +63,18 @@ class SessionCheckpointStore:
         self,
         root: str,
         *,
-        backend: str = "auto",
+        backend: str = "json",
         max_to_keep: int = 2,
     ):
+        if backend not in ("json", "orbax"):
+            raise ValueError(
+                f"unknown session checkpoint backend {backend!r}; "
+                "expected 'json' or 'orbax'"
+            )
         self.root = root
         self.max_to_keep = max_to_keep
-        # "auto" resolves lazily at first IO — constructing the store
-        # (e.g. at Platform boot) must not pay the jax/orbax import
-        self._backend = backend
+        self.backend = backend
         self._managers: dict[str, Any] = {}
-
-    @property
-    def backend(self) -> str:
-        if self._backend == "auto":
-            try:
-                from odh_kubeflow_tpu.train.checkpoint import (  # noqa: F401
-                    CheckpointManager,
-                )
-
-                self._backend = "orbax"
-            except Exception:  # jax/orbax not importable → file fallback
-                self._backend = "json"
-        return self._backend
 
     # -- paths / metadata ----------------------------------------------------
 
@@ -127,10 +125,9 @@ class SessionCheckpointStore:
         prev = self._read_meta(uid)
         step = (int(prev["step"]) + 1) if prev else 0
         if self.backend == "orbax":
-            import jax.numpy as jnp
             import numpy as np
 
-            arr = jnp.asarray(np.frombuffer(payload, np.uint8))
+            arr = np.frombuffer(payload, np.uint8)
             mngr = self._manager(uid)
             mngr.save(step, {"session": arr}, force=True)
             mngr.wait_until_finished()
@@ -161,19 +158,11 @@ class SessionCheckpointStore:
             return None
         step = int(meta["step"])
         if self.backend == "orbax":
-            import jax
             import numpy as np
 
             mngr = self._manager(uid)
-            like = {
-                "session": jax.ShapeDtypeStruct(
-                    (int(meta["sizeBytes"]),),
-                    np.uint8,
-                    sharding=jax.sharding.SingleDeviceSharding(
-                        jax.devices()[0]
-                    ),
-                )
-            }
+            # a numpy target restores to host memory: no device
+            like = {"session": np.empty((int(meta["sizeBytes"]),), np.uint8)}
             restored = mngr.restore(like, step=step)
             payload = bytes(np.asarray(restored["session"]))
         else:
@@ -286,7 +275,7 @@ class ReplicatedCheckpointStore:
         self,
         zones: dict[str, str],
         *,
-        backend: str = "auto",
+        backend: str = "json",
         max_to_keep: int = 2,
     ):
         if not zones:

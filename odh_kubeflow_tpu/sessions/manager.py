@@ -84,7 +84,10 @@ class SessionConfig:
     # where checkpoints live (PVC path or gs:// prefix); empty → a
     # process-local temp dir (sim / tests)
     checkpoint_dir: str = ""
-    backend: str = "auto"  # orbax | json | auto
+    # json (plain files; imports no JAX) | orbax (fsspec paths; orbax
+    # initialises a JAX backend, so not on a host whose chip a child
+    # process needs — see sessions/checkpoint.py)
+    backend: str = "json"
     # zone-replicated checkpoints: comma-separated ``zone=path`` (one
     # independent volume per failure domain) or bare zone names
     # (subdirs of checkpoint_dir — sim/dev). ≥2 zones turns every
@@ -116,7 +119,7 @@ class SessionConfig:
         env = os.environ
         return SessionConfig(
             checkpoint_dir=env.get("SESSION_CHECKPOINT_DIR", ""),
-            backend=env.get("SESSION_CHECKPOINT_BACKEND", "auto"),
+            backend=env.get("SESSION_CHECKPOINT_BACKEND", "json"),
             zones=env.get("SESSION_CHECKPOINT_ZONES", ""),
             zone_heal_retry_seconds=float(
                 env.get("SESSION_ZONE_HEAL_RETRY_SECONDS", "30")

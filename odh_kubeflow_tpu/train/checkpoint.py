@@ -27,6 +27,7 @@ import os
 from typing import Any, Optional
 
 import jax
+import numpy as np
 import orbax.checkpoint as ocp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -81,14 +82,15 @@ class CheckpointManager:
     def restore(self, state_like: Params, step: Optional[int] = None) -> Params:
         """``state_like`` is either a matching tree of arrays or an
         abstract (ShapeDtypeStruct + sharding) tree; arrays land sharded
-        per the target's NamedShardings."""
+        per the target's NamedShardings. A numpy leaf restores to a
+        numpy array in host memory (no device is touched)."""
         if step is None:
             step = self.latest_step()
         if step is None:
             raise FileNotFoundError(f"no checkpoint under {self.directory}")
         abstract = jax.tree_util.tree_map(
             lambda x: x
-            if isinstance(x, jax.ShapeDtypeStruct)
+            if isinstance(x, (jax.ShapeDtypeStruct, np.ndarray))
             else jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding),
             state_like,
         )

@@ -20,14 +20,20 @@ _PEAK_TFLOPS_BY_KIND = {
 
 
 def peak_flops_per_chip(device: jax.Device | None = None) -> float:
-    """Peak bf16 FLOP/s for one chip; 0.0 when unknown (e.g. CPU)."""
+    """Peak bf16 FLOP/s for one chip. A ``device_kind`` that is not in
+    the table (a CPU, a chip nobody entered) raises: a utilization
+    against a peak of zero is not a number."""
     if device is None:
         device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "")
+    kind = device.device_kind
     for name, tflops in _PEAK_TFLOPS_BY_KIND.items():
         if kind.startswith(name):
             return tflops * 1e12
-    return 0.0
+    raise ValueError(
+        f"no peak FLOP/s known for device_kind {kind!r} "
+        f"(platform {device.platform!r}); known: "
+        f"{sorted(_PEAK_TFLOPS_BY_KIND)}"
+    )
 
 
 # GKE scheduling metadata: accelerator-type string (the

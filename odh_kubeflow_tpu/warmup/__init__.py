@@ -1,7 +1,7 @@
 """Warm-start subsystem: compilation cache + warm session pools.
 
 Cold-start is the biggest per-user latency the platform controls: every
-fresh kernel pays a 5-13s XLA compile (BENCH_r03-r05) and full container
+fresh kernel pays a multi-second XLA compile and full container
 start. This package kills both, in two cooperating halves:
 
 - ``compilecache`` — a content-addressed compilation artifact store

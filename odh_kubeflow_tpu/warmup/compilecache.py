@@ -1,8 +1,8 @@
 """Compilation-cache service: compile once, load everywhere.
 
-The platform's answer to the 5-13s XLA compile every fresh kernel and
-engine replica pays (BENCH_r03-r05; the 1B train-step compile alone is
-~14s cold). A compiled program is a pure function of its
+The platform's answer to the XLA compile every fresh kernel and engine
+replica pays (the 1B train-step compile alone is ~14s cold). A compiled
+program is a pure function of its
 :class:`CompileKey` — (program fingerprint, topology/mesh shape,
 compiler version) — so the artifact is content-addressed and shared
 across sessions, trainer runs, and engine replicas:
@@ -25,12 +25,13 @@ across sessions, trainer runs, and engine replicas:
   and LRU+TTL GC under ``COMPILE_CACHE_MAX_BYTES`` /
   ``COMPILE_CACHE_TTL_SECONDS``;
 - :meth:`ingest_dir` / :meth:`materialize_dir` bridge jax's own
-  persistent compilation cache: a cold process pointed at a staging
-  ``JAX_COMPILATION_CACHE_DIR`` writes artifacts, ``ingest_dir``
+  persistent compilation cache: a cold process writes artifacts into
+  its cache directory (:func:`process_cache_dir`), ``ingest_dir``
   registers them with the service, and ``materialize_dir`` stages
-  digest-verified artifacts into a fresh directory for the next
-  process (notebook kernels get that directory as their
-  ``JAX_COMPILATION_CACHE_DIR`` mount).
+  digest-verified artifacts back into that SAME path for the next
+  process — the path is part of jax's cache key, so the directory is
+  named by the deployment (``JAX_COMPILATION_CACHE_DIR``, the
+  ``COMPILE_CACHE_MOUNT`` every pod gets), never invented here.
 """
 
 from __future__ import annotations
@@ -63,13 +64,10 @@ _COMPILE_BUCKETS = (0.1, 0.5, 1.0, 2.5, 5.0, 10.0, 20.0, 60.0)
 def compiler_version() -> str:
     """The compiler identity axis of the cache key: artifacts from one
     jax/jaxlib (and hence XLA/libtpu) build must never serve another."""
-    try:
-        import jax
-        import jaxlib
+    import jax
+    import jaxlib
 
-        return f"jax-{jax.__version__}+jaxlib-{jaxlib.__version__}"
-    except Exception:  # noqa: BLE001 — key axis degrades, never raises
-        return "unknown"
+    return f"jax-{jax.__version__}+jaxlib-{jaxlib.__version__}"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -333,6 +331,9 @@ class CompileCacheService:
         self.api = api
         self.config = config or CompileCacheConfig()
         self.now = time_fn
+        # the service's own content-addressed artifact store — never a
+        # process's jax cache directory (see process_cache_dir), so an
+        # ephemeral root is safe when no COMPILE_CACHE_DIR places it
         root = self.config.cache_dir or tempfile.mkdtemp(
             prefix="compile-cache-"
         )
@@ -634,14 +635,6 @@ class CompileCacheService:
 
     # -- jax persistent-cache bridge -----------------------------------------
 
-    def staging_dir(self, tag: str) -> str:
-        """A fresh directory a process can use as its
-        ``JAX_COMPILATION_CACHE_DIR`` — cold compiles land here, then
-        ``ingest_dir`` promotes them into the shared store."""
-        path = os.path.join(self.root, "staging", tag)
-        os.makedirs(path, exist_ok=True)
-        return path
-
     def ingest_dir(
         self,
         path: str,
@@ -739,24 +732,61 @@ class CompileCacheService:
         }
 
 
-def install_process_cache(cache_dir: Optional[str] = None) -> Optional[str]:
-    """Point THIS process's jax persistent compilation cache at
-    ``cache_dir`` (or ``$JAX_COMPILATION_CACHE_DIR``) with thresholds
-    zeroed so every compile is eligible. The in-process half of the
-    service: the trainer's precompile path and the engine's decode
-    compile call it before their first jit, so a staged/materialized
-    cache directory turns those compiles into loads. No-op (returns
-    None) when no directory is configured or jax is absent."""
-    path = cache_dir or os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
-    if not path:
-        return None
-    try:
-        import jax
+# The one place a process's jax persistent-cache directory is decided
+# when nobody placed it from outside: a fixed, git-ignored directory at
+# the root of the checkout (the parent of this package). The path is
+# part of jax's cache key, so it must not depend on tempfile, a pid or
+# a clock — a directory that moves never hits.
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_compile_cache",
+)
 
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        return path
-    except Exception:  # noqa: BLE001 — cache wiring must never break a run
-        return None
+
+def process_cache_dir() -> str:
+    """Where this process's jax persistent compilation cache lives:
+    ``$JAX_COMPILATION_CACHE_DIR`` if set — that directory and no
+    other — else the fixed in-checkout directory. Touches neither jax
+    nor the filesystem (launchers use it to find the directory their
+    children will use)."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _CHECKOUT_CACHE_DIR
+
+
+def install_process_cache() -> str:
+    """Join THIS process to the persistent compilation cache at
+    :func:`process_cache_dir` and return that path. The trainer and the
+    decode engine call it before their first trace.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set, jax has read the variable
+    itself at import and this function sets no directory; it only
+    checks that jax's setting IS that directory (a variable exported
+    after ``import jax``, or a ``jax.config.update`` elsewhere, would
+    otherwise leave the process caching somewhere nobody placed).
+    Without it, the fixed in-checkout directory is configured.
+
+    jax's minimum-compile-time threshold (1 s) is zeroed, for a
+    measured reason: under it a repeated ``chip_smoke.py`` on a v5e
+    compiled 40 of its 59 programs again (11.2 s of a 47.6 s run, PR
+    21) — the streaming init and the engine are many small programs.
+    Tests are not affected: tests/conftest.py turns the cache off.
+
+    Errors propagate: a process that was meant to share a cache and
+    silently does not pays every cold compile, every run."""
+    import jax
+
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        configured = jax.config.jax_compilation_cache_dir
+        if configured != env:
+            raise RuntimeError(
+                f"JAX_COMPILATION_CACHE_DIR={env!r} but jax caches at "
+                f"{configured!r}: export the variable before jax is "
+                "imported, and set the directory nowhere else"
+            )
+        return env
+    os.makedirs(_CHECKOUT_CACHE_DIR, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", _CHECKOUT_CACHE_DIR)
+    return _CHECKOUT_CACHE_DIR

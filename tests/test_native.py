@@ -153,3 +153,27 @@ def test_native_pack_fuzz_edge_cases():
             for a, b in zip(py, nat):
                 for k in a:
                     np.testing.assert_array_equal(a[k], b[k], err_msg=f"{trial}/{k}")
+
+
+def test_built_object_is_keyed_on_the_content_of_its_source(tmp_path, monkeypatch):
+    """A copy or a checkout makes mtimes arbitrary (and the chip tool
+    copies ignored files too): the object's name carries the digest of
+    its source, so one built from another tree is never loaded."""
+    import os
+    import shutil
+
+    src = tmp_path / "packer.cpp"
+    shutil.copy(native._SRC, src)
+    monkeypatch.setattr(native, "_DIR", str(tmp_path))
+    built = native._compile(str(src), "libx", [], False)
+    os.utime(built, (0, 0))  # older than the source: still the right one
+    assert native._compile(str(src), "libx", [], False) == built
+    assert native._compile(str(src), "libx", ["-DX"], False) != built
+
+    stale = tmp_path / "libx.0123456789abcdef.so"
+    stale.write_bytes(b"built from some other tree")
+    src.write_text(src.read_text() + "\n// edited\n")
+    rebuilt = native._compile(str(src), "libx", [], False)
+    assert rebuilt != built and os.path.getsize(rebuilt) > 1000
+    # what was built from a source that is gone is swept, never loaded
+    assert not stale.exists() and not os.path.exists(built)

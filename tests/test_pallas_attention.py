@@ -231,6 +231,16 @@ def test_attn_remat_policy_skips_flash_forward_recompute():
     assert n_none == 4, n_none
     assert n_attn == 3, n_attn
 
+    # under a mesh the kernels run per shard inside a shard_map
+    # (llama._flash_per_shard); the policy must still see the named
+    # residuals through it
+    from odh_kubeflow_tpu.parallel.mesh import MeshConfig, build_mesh
+
+    with jax.set_mesh(build_mesh(MeshConfig(fsdp=4, tensor=2), jax.devices())):
+        sharded = str(jax.make_jaxpr(jax.grad(loss_fn(cfg_attn)))(params))
+    assert "shard_map" in sharded
+    assert sharded.count("pallas_call") == 3, sharded.count("pallas_call")
+
     g_ref = jax.grad(loss_fn(cfg0))(params)
     g_attn = jax.grad(loss_fn(cfg_attn))(params)
     flat_r, _ = jax.tree_util.tree_flatten(g_ref)
@@ -251,3 +261,4 @@ def test_multiblock_non_causal_full_blocks():
     assert jnp.allclose(got, ref, atol=2e-5, rtol=2e-5), (
         float(jnp.abs(got - ref).max())
     )
+

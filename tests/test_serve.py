@@ -343,25 +343,38 @@ def test_engine_mode_http_concurrent():
             svc.engine.stop()
 
 
-def test_engine_failure_falls_back_to_bucketed_path():
-    """A dead engine (device failure marked in engine.failure) must not
-    black-hole the server: complete() routes around it through the
-    one-shot bucketed path and still answers."""
+def test_engine_failure_is_an_error_not_a_one_shot_answer():
+    """A dead engine (device failure marked in engine.failure) is a
+    dead server: complete() raises and the HTTP surface answers 500 —
+    it does not serve a 200 from the one-shot path over a lost
+    device. Seeded requests (which never used the engine) included."""
     cfg = LlamaConfig.tiny(dtype=jnp.float32)
     params = llama.init_params(jax.random.PRNGKey(0), cfg)
     svc = CompletionService(
         params, cfg, prompt_buckets=(8, 16), batch_buckets=(1, 2),
         engine_slots=2, engine_max_len=64,
     )
+    httpd = serve(svc, host="127.0.0.1", port=0)
     try:
         ok = svc.complete([[1, 2, 3]], max_tokens=4)
         assert ok["usage"].get("engine") is True
 
         svc.engine.failure = RuntimeError("simulated device loss")
-        out = svc.complete([[1, 2, 3]], max_tokens=4)
-        assert "engine" not in out["usage"]  # bucketed path answered
-        assert len(out["completions"][0]) == 4
+        with pytest.raises(RuntimeError, match="decode engine is down"):
+            svc.complete([[1, 2, 3]], max_tokens=4)
+        with pytest.raises(RuntimeError, match="decode engine is down"):
+            svc.complete([[1, 2, 3]], max_tokens=4, seed=0)
+        req = urllib.request.Request(
+            f"http://127.0.0.1:{httpd.server_address[1]}/v1/completions",
+            data=json.dumps({"prompt": [1, 2, 3], "max_tokens": 4}).encode(),
+            headers={"Content-Type": "application/json"},
+        )
+        with pytest.raises(urllib.error.HTTPError) as err:
+            urllib.request.urlopen(req, timeout=60)
+        assert err.value.code == 500
+        assert "decode engine is down" in json.loads(err.value.read())["error"]
     finally:
+        httpd.shutdown()
         svc.engine.stop()
 
 

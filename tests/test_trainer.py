@@ -15,13 +15,35 @@ def _loss_decreases(trainer, steps=8, batch_size=8):
 
 
 def test_lora_training_single_device():
+    """Also the AOT contract: with ``precompile_batch`` the step that
+    runs IS the ahead-of-time executable (the lazy jit compiles
+    nothing), and a failed ahead-of-time compile is raised where it is
+    joined — not stored and compiled again lazily."""
+    import pytest
+
+    keys = ("targets", "tokens")
     trainer = Trainer(
         LlamaConfig.tiny(dtype=jnp.float32),
         TrainConfig(learning_rate=1e-2, warmup_steps=1, total_steps=20),
         lora_cfg=LoraConfig(rank=4),
         mesh=build_mesh(MeshConfig(), jax.devices()[:1]),
+        precompile_batch=(8, 32, keys),
     )
     _loss_decreases(trainer)
+    assert isinstance(trainer._aot[(8, 32, keys)], jax.stages.Compiled)
+    assert trainer._compiled._cache_size() == 0
+
+    class Refuses:
+        def lower(self, *a, **k):
+            raise RuntimeError("mosaic refused the kernel")
+
+    lazy, trainer._compiled = trainer._compiled, Refuses()
+    trainer.precompile_async(8, 64, keys)
+    trainer._aot_threads[(8, 64, keys)].join(timeout=60)
+    trainer._compiled = lazy
+    with pytest.raises(RuntimeError, match="mosaic refused"):
+        trainer.train_step(trainer.make_fake_batch(8, 64))
+    assert lazy._cache_size() == 0
 
 
 def test_full_finetune_sharded_fsdp_tp(devices8):
@@ -35,12 +57,21 @@ def test_full_finetune_sharded_fsdp_tp(devices8):
 
 
 def test_lora_sharded_matches_single_device(devices8):
-    """Same seed, same data: an fsdp=8-sharded LoRA step must produce the
-    same loss trajectory as single-device (SPMD is semantics-preserving)."""
+    """Same seed, same data: a sharded LoRA step must produce the same
+    loss trajectory as single-device (SPMD is semantics-preserving).
+    The sharded side runs FLASH attention, which GSPMD cannot partition
+    (on the TPU Mosaic refuses to lower it outside a fully-manual
+    shard_map): ``llama._flash_per_shard`` maps it over the mesh — rows
+    over fsdp, heads over tensor — forward and backward."""
+    import dataclasses
+
     cfg = LlamaConfig.tiny(dtype=jnp.float32)
     tc = TrainConfig(learning_rate=1e-2, warmup_steps=1, total_steps=20)
     t1 = Trainer(cfg, tc, LoraConfig(rank=4), build_mesh(MeshConfig(), jax.devices()[:1]))
-    t8 = Trainer(cfg, tc, LoraConfig(rank=4), build_mesh(MeshConfig(fsdp=8), devices8))
+    t8 = Trainer(
+        dataclasses.replace(cfg, attention_impl="flash"), tc,
+        LoraConfig(rank=4), build_mesh(MeshConfig(fsdp=4, tensor=2), devices8),
+    )
     l1 = _loss_decreases(t1)
     l8 = _loss_decreases(t8)
     np.testing.assert_allclose(l1, l8, rtol=2e-3)

@@ -271,7 +271,10 @@ def test_cache_entries_survive_wal_failover(tmp_path):
 
 def test_ingest_and_materialize_bridge_jax_cache_dirs(tmp_path):
     svc, _ = cache_service(tmp_path)
-    staging = svc.staging_dir("cold-run")
+    # the caller names the directory (a process's cache directory is
+    # placed by the deployment, not handed out by the service)
+    staging = str(tmp_path / "cold-run")
+    os.makedirs(staging)
     for name, data in (("fp-aaa", b"prog a"), ("fp-bbb", b"prog b")):
         with open(os.path.join(staging, name), "wb") as f:
             f.write(data)
@@ -291,18 +294,71 @@ def test_ingest_and_materialize_bridge_jax_cache_dirs(tmp_path):
     )
 
 
-def test_install_process_cache(tmp_path, monkeypatch):
+def test_install_process_cache_one_rule(tmp_path, monkeypatch):
+    """The directory rule: JAX_COMPILATION_CACHE_DIR set → that
+    directory and no other, and the code sets none (jax read the
+    variable itself); unset → the fixed, git-ignored directory inside
+    the checkout. No keyword beats the variable, nothing comes from
+    tempfile."""
+    import inspect
+    import subprocess
+
     import jax
 
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    assert install_process_cache() is None  # unconfigured → no-op
-    target = str(tmp_path / "jaxcc")
+    from odh_kubeflow_tpu.warmup import compilecache
+
+    assert not inspect.signature(install_process_cache).parameters
+    from odh_kubeflow_tpu.models.engine import DecodeEngine
+
+    assert "compile_cache_dir" not in inspect.signature(
+        DecodeEngine.__init__
+    ).parameters
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    fixed = os.path.join(repo, ".jax_compile_cache")
+    before = jax.config.jax_compilation_cache_dir
     try:
-        assert install_process_cache(target) == target
-        assert os.path.isdir(target)
+        # unset → the fixed in-checkout path, the same on every call
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert compilecache.process_cache_dir() == fixed
+        assert install_process_cache() == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+        assert os.path.isdir(fixed)
+        ignored = subprocess.run(
+            ["git", "check-ignore", "-q", fixed], cwd=repo
+        )
+        assert ignored.returncode in (0, 128)  # 128: not a git checkout
+        # set after jax was imported, jax has NOT read it: the process
+        # would cache somewhere nobody placed — an error, not a guess
+        target = str(tmp_path / "jaxcc")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", target)
+        assert compilecache.process_cache_dir() == target
+        with pytest.raises(RuntimeError, match="before jax is imported"):
+            install_process_cache()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+    # set from outside before the process started — jax read it at
+    # import (simulated: both hold the same value): that directory is
+    # returned and the code sets none
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", target)
+    jax.config.update("jax_compilation_cache_dir", target)
+    seen = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update", lambda k, v: (seen.append(k), real_update(k, v))
+    )
+    try:
+        assert install_process_cache() == target
+        assert "jax_compilation_cache_dir" not in seen
         assert jax.config.jax_compilation_cache_dir == target
     finally:
-        jax.config.update("jax_compilation_cache_dir", None)
+        real_update("jax_compilation_cache_dir", before)
+
+    # no tempfile on the path from a process to its cache directory
+    for fn in (install_process_cache, compilecache.process_cache_dir):
+        assert "tempfile" not in inspect.getsource(fn)
+    assert not hasattr(CompileCacheService, "staging_dir")
 
 
 # ---------------------------------------------------------------------------
