@@ -23,8 +23,9 @@ bf16; random weights from a seed), in ONE process on ONE device:
 
 It checks that no fallback that hides the device fired: attention
 resolved to ``flash``, no pallas op defaulted to interpret mode, the
-compiled steps contain Mosaic custom calls, the lazy jit compiled
-nothing, no completion came from the one-shot path.
+compiled steps contain Mosaic custom calls, no step ran the lazy jit
+(the trainer's own ``aot_steps`` / ``lazy_steps``), no completion came
+from the one-shot path.
 
 Exit code 0 and, as the LAST line of stdout,
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``
@@ -240,13 +241,16 @@ def train_leg(cfg, device) -> dict:
     packed_losses = _finite_losses(packed_metrics, "packed batches")
 
     # the steps above ran the ahead-of-time executables, and those hold
-    # Mosaic kernels: the lazy jit never compiled, both keys resolved
-    # to a Compiled, and its HLO calls tpu_custom_call
-    lazy = trainer._compiled._cache_size()
-    if lazy:
-        raise AssertionError(f"the lazy-jit step compiled {lazy} program(s)")
+    # Mosaic kernels: the trainer counted no lazy step, both shapes
+    # resolve to a Compiled, and its HLO calls tpu_custom_call
+    n_steps = len(fake_losses) + len(packed_losses)
+    if trainer.lazy_steps or trainer.aot_steps != n_steps:
+        raise AssertionError(
+            f"{trainer.lazy_steps} of {n_steps} steps ran the lazy jit "
+            f"({trainer.aot_steps} the ahead-of-time executable)"
+        )
     for keys in (FAKE_KEYS, PACKED_KEYS):
-        exe = trainer._aot.get((BATCH, SEQ, tuple(sorted(keys))))
+        exe = trainer.compiled_step(BATCH, SEQ, keys)
         if not isinstance(exe, jax.stages.Compiled):
             raise AssertionError(f"no AOT executable for {keys}: {exe!r}")
         if "tpu_custom_call" not in exe.as_text():
@@ -261,7 +265,8 @@ def train_leg(cfg, device) -> dict:
         "packed_losses": [round(x, 4) for x in packed_losses],
         "packed_max_segments_per_row": n_segments,
         "packer": "native" if native.available() else "python",
-        "lazy_jit_compiles": lazy,
+        "aot_steps": trainer.aot_steps,
+        "lazy_steps": trainer.lazy_steps,
     }
 
 

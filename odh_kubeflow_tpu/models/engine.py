@@ -41,9 +41,18 @@ import jax.numpy as jnp
 
 from odh_kubeflow_tpu.models.generate import family_forward, init_cache
 from odh_kubeflow_tpu.models.llama import LlamaConfig
-from odh_kubeflow_tpu.utils import prometheus
+from odh_kubeflow_tpu.utils import prometheus, tracing
+from odh_kubeflow_tpu.utils.profiling import hot_span
 
 Params = dict[str, Any]
+
+# Names of the jitted programs as a profile's ``XLA Modules`` line (and
+# the compile log) shows them, ``jit_<name>``: the decode chunk under
+# exactly this name, and every program of the prefill family (whole
+# prompt, with a cached prefix, an interior part, the final part, the
+# prefix seeding, the draft's prefill) with this substring in its name.
+DECODE_PROGRAM = "_decode_chunk"
+PREFILL_PROGRAM_TAG = "_prefill"
 
 # TTFT spans fast warm admissions to cold-compile prefills
 _TTFT_BUCKETS = (
@@ -115,6 +124,27 @@ class _Request:
     # streaming from this process would see, chunk bursts included)
     submit_t: float = 0.0
     times: list[float] = dataclasses.field(default_factory=list)
+    # the request's identifier, and the id of its ``engine.request``
+    # trace (recorded when it finishes, from the stamps below)
+    request_id: str = dataclasses.field(default_factory=tracing.new_trace_id)
+    submit_wall: float = 0.0  # submit_t on the wall clock
+    # when the loop took it from the queue with a slot free (monotonic)
+    admit_t: Optional[float] = None
+    finish_t: Optional[float] = None
+    slot: int = -1
+    bucket: int = -1
+    prefix_hit: bool = False
+    # set when the request failed because the ENGINE did (a device
+    # failure in some turn, or a stop), through no fault of its own:
+    # the trace id of the ``engine.turn`` that failed, "" for a stop
+    failed_by: Optional[str] = None
+
+    @property
+    def complete(self) -> bool:
+        """Every token it asked for, or its eos, has been emitted."""
+        return len(self.tokens) >= self.max_tokens or (
+            bool(self.tokens) and self.tokens[-1] == self.eos_id
+        )
 
     def cancel(self) -> None:
         """Abandon the stream (client went away): the engine frees the
@@ -144,6 +174,9 @@ class _Request:
             self.token_q.put(tok)
 
     def _finish(self) -> None:
+        if self.finish_t is None:
+            self.finish_t = time.monotonic()
+            _record_request(self)
         self.done.set()
         if self.token_q is not None:
             self.token_q.put(None)
@@ -166,6 +199,84 @@ class _Request:
             yield tok
         if self.error is not None:
             raise self.error
+
+
+def _program(fn, name: str, **static):
+    """``fn`` with ``static`` bound, under ``name``: jax names a jitted
+    program after its function, and a bare ``functools.partial`` has no
+    name (the program would be ``jit__unknown`` in every trace)."""
+    bound = functools.partial(fn, **static)
+    bound.__name__ = name
+    return bound
+
+
+def _record_request(req: _Request) -> None:
+    """The request's trace, written once, when it finishes, from the
+    stamps it carries (no span is held open across loop turns): root
+    ``engine.request`` (submit to finish) over ``engine.request.queued``
+    (submit to the loop taking it with a slot free),
+    ``engine.request.first_token`` (from there to the emit of its first
+    token: the prefill and the fetch deferred to the next chunk) and
+    ``engine.request.decode`` (first token to finish). A request that
+    ends early has the phases it reached, the last one cut at the end.
+    Children first: the root's outcome decides whether the collector
+    keeps the trace, and it pulls them from the ring. Only a request
+    whose own admission raised closes with status ``error`` (and is
+    kept): one taken down with the engine has ``outcome=failed`` and
+    ``failed_by`` = the trace of the turn that failed, which IS kept,
+    so a failure under a long queue cannot fill the kept store."""
+    end = req.finish_t
+    first = req.times[0] if req.times else None
+    if req.error is not None and not req.cancelled:
+        outcome = "failed"
+    elif req.cancelled and not req.complete:
+        outcome = "cancelled"
+    else:
+        outcome = "finished"
+    root_id = tracing.new_span_id()
+
+    def record(name, t_from, t_to, parent, **kw):
+        tracing.record_span(tracing.SpanRecord(
+            trace_id=req.request_id,
+            span_id=root_id if not parent else tracing.new_span_id(),
+            parent_span_id=parent,
+            name=name,
+            start=req.submit_wall + (t_from - req.submit_t),
+            duration=t_to - t_from,
+            start_mono=t_from,
+            **kw,
+        ))
+
+    marks = (
+        ("engine.request.queued", req.submit_t, req.admit_t),
+        ("engine.request.first_token", req.admit_t, first),
+        ("engine.request.decode", first, end),
+    )
+    for name, t_from, t_to in marks:
+        if t_from is not None:
+            record(name, t_from, end if t_to is None else t_to, root_id)
+    attrs = {
+        "prompt_len": len(req.prompt),
+        "max_tokens": req.max_tokens,
+        "tokens": len(req.tokens),
+        "outcome": outcome,
+    }
+    if req.admit_t is not None:
+        attrs.update(
+            slot=req.slot, bucket=req.bucket, prefix_hit=req.prefix_hit
+        )
+    own_fault = outcome == "failed" and req.failed_by is None
+    if outcome == "failed" and not own_fault:
+        attrs["failed_by"] = req.failed_by
+    record(
+        "engine.request", req.submit_t, end, "",
+        status="error" if own_fault else "ok",
+        error=(
+            f"{type(req.error).__name__}: {req.error}"
+            if outcome == "failed" else ""
+        ),
+        attrs=attrs,
+    )
 
 
 class DecodeEngine:
@@ -316,6 +427,11 @@ class DecodeEngine:
         self.m_queue_depth = reg.gauge(
             "serving_queue_depth", "Requests waiting for a decode slot"
         )
+        self.m_queue_wait = reg.histogram(
+            "serving_queue_wait_seconds",
+            "Time from request submit to the loop taking it with a slot free",
+            buckets=_TTFT_BUCKETS,
+        )
         self.m_occupancy = reg.gauge(
             "serving_batch_occupancy",
             "Fraction of decode slots active after the last chunk",
@@ -326,6 +442,10 @@ class DecodeEngine:
         self.decode_steps = 0
         self.tokens_emitted = 0
         self.spec_rounds = 0
+        # loop turns that had work, and prefill programs dispatched
+        # (whole prompts, parts of a chunked admission, prefix seeding)
+        self.turns = 0
+        self.prefill_calls = 0
         # set on unrecoverable device failure; submit() then raises
         self.failure: Optional[Exception] = None
         self._slot_req: list[Optional[_Request]] = [None] * S
@@ -337,9 +457,13 @@ class DecodeEngine:
         self._wake = threading.Event()
         self._stopped = False
         self._prefill_fns: dict[int, Any] = {}
-        self._decode_fn = jax.jit(self._decode_chunk, donate_argnums=1)
+        self._decode_fn = jax.jit(
+            _program(self._decode_chunk, DECODE_PROGRAM), donate_argnums=1
+        )
         self._decode_greedy_fn = jax.jit(
-            functools.partial(self._decode_chunk, greedy=True),
+            _program(
+                self._decode_chunk, f"{DECODE_PROGRAM}_greedy", greedy=True
+            ),
             donate_argnums=1,
         )
         self._spec_fn = (
@@ -453,9 +577,10 @@ class DecodeEngine:
             logits, (rem_len - 1)[None, None, None], axis=1
         )[:, 0, :]
         rng, sub = jax.random.split(state["rng"])
-        first = sample_logits_rowwise(
-            last, sub, temp[None], top_k[None], top_p[None]
-        )[0]
+        with jax.named_scope("sampler"):
+            first = sample_logits_rowwise(
+                last, sub, temp[None], top_k[None], top_p[None]
+            )[0]
         return self._write_slot_state(
             state, sub_cache, kv_mask1, slot, first, total, req_vec, rng
         )
@@ -520,10 +645,11 @@ class DecodeEngine:
                     jnp.int32
                 )
             else:
-                nxt = sample_logits_rowwise(
-                    logits[:, 0, :], sub, st["temp"], st["top_k"],
-                    st["top_p"],
-                )
+                with jax.named_scope("sampler"):
+                    nxt = sample_logits_rowwise(
+                        logits[:, 0, :], sub, st["temp"], st["top_k"],
+                        st["top_p"],
+                    )
             remaining = st["remaining"] - active.astype(jnp.int32)
             finished = (nxt == st["eos"]) | (remaining <= 0)
             new_active = active & ~finished
@@ -565,13 +691,13 @@ class DecodeEngine:
         sub_cache = init_cache(
             cache_cfg, 1, self.max_len, state["cache"]["k"].dtype
         )
-        sub_cache = self._seed_prefix(sub_cache, prefix_kv, plen=plen)
+        sub_cache = self._prefill_seed(sub_cache, prefix_kv, plen=plen)
         return self._prefill_tail(
             params, lora, state, sub_cache, packed, jnp.int32(plen),
             bucket=bucket,
         )
 
-    def _seed_prefix(self, sub_cache, prefix_kv, *, plen: int):
+    def _prefill_seed(self, sub_cache, prefix_kv, *, plen: int):
         """Seed a fresh batch-1 cache with a prefix-cache entry (the
         chunked-admission analogue of _prefill_ext's seeding)."""
         return {
@@ -612,7 +738,10 @@ class DecodeEngine:
     def _draft_prefill_runner(self, bucket: int):
         if bucket not in self._draft_prefill_fns:
             self._draft_prefill_fns[bucket] = jax.jit(
-                functools.partial(self._draft_prefill, bucket=bucket),
+                _program(
+                    self._draft_prefill,
+                    f"_draft{PREFILL_PROGRAM_TAG}_{bucket}", bucket=bucket,
+                ),
                 donate_argnums=1,
             )
         return self._draft_prefill_fns[bucket]
@@ -734,7 +863,10 @@ class DecodeEngine:
     def _prefill_runner(self, bucket: int):
         if bucket not in self._prefill_fns:
             self._prefill_fns[bucket] = jax.jit(
-                functools.partial(self._prefill, bucket=bucket),
+                _program(
+                    self._prefill, f"{PREFILL_PROGRAM_TAG}_{bucket}",
+                    bucket=bucket,
+                ),
                 donate_argnums=2,
             )
         return self._prefill_fns[bucket]
@@ -743,8 +875,10 @@ class DecodeEngine:
         key = (plen, bucket)
         if key not in self._prefill_fns:
             self._prefill_fns[key] = jax.jit(
-                functools.partial(
-                    self._prefill_ext, plen=plen, bucket=bucket
+                _program(
+                    self._prefill_ext,
+                    f"{PREFILL_PROGRAM_TAG}_ext_{plen}_{bucket}",
+                    plen=plen, bucket=bucket,
                 ),
                 donate_argnums=2,
             )
@@ -754,7 +888,10 @@ class DecodeEngine:
         key = ("part", width)
         if key not in self._prefill_fns:
             self._prefill_fns[key] = jax.jit(
-                functools.partial(self._prefill_part, width=width),
+                _program(
+                    self._prefill_part, f"{PREFILL_PROGRAM_TAG}_part_{width}",
+                    width=width,
+                ),
                 donate_argnums=2,
             )
         return self._prefill_fns[key]
@@ -766,16 +903,22 @@ class DecodeEngine:
             # into state's larger buffers, so its donation could never
             # be used (it would just warn)
             self._prefill_fns[key] = jax.jit(
-                functools.partial(self._prefill_tail, bucket=bucket),
+                _program(
+                    self._prefill_tail, f"{PREFILL_PROGRAM_TAG}_final_{bucket}",
+                    bucket=bucket,
+                ),
                 donate_argnums=2,
             )
         return self._prefill_fns[key]
 
-    def _seed_prefix_runner(self, plen: int):
+    def _prefill_seed_runner(self, plen: int):
         key = ("seed", plen)
         if key not in self._prefill_fns:
             self._prefill_fns[key] = jax.jit(
-                functools.partial(self._seed_prefix, plen=plen),
+                _program(
+                    self._prefill_seed, f"{PREFILL_PROGRAM_TAG}_seed_{plen}",
+                    plen=plen,
+                ),
                 donate_argnums=0,
             )
         return self._prefill_fns[key]
@@ -823,6 +966,18 @@ class DecodeEngine:
             self._prefix_cache[key] = entry
             return
 
+    def _note_prefill(self, req: _Request, slot: int, bucket: int,
+                      prefix_hit: bool, part: str) -> None:
+        """One prefill program is about to be dispatched for ``req``:
+        counted, stamped on the request (for its ``engine.request``
+        span) and noted as an event on the turn's ``engine.admit``."""
+        self.prefill_calls += 1
+        req.slot, req.bucket, req.prefix_hit = slot, bucket, prefix_hit
+        tracing.add_event(
+            "prefill", request=req.request_id, slot=slot, bucket=bucket,
+            prefix_hit=prefix_hit, part=part,
+        )
+
     def _admit(self, req: _Request) -> None:
         slot = self._slot_req.index(None)
         L = len(req.prompt)
@@ -834,6 +989,7 @@ class DecodeEngine:
             row[0, bucket + 1] = slot
             packed = jnp.asarray(row)
             self.prefix_hits += 1
+            self._note_prefill(req, slot, bucket, True, "whole")
             self._state, first = self._prefill_ext_runner(plen, bucket)(
                 self.params, self.lora, self._state, entry, packed,
             )
@@ -843,6 +999,7 @@ class DecodeEngine:
             row = self.pack_admission(req.prompt, self.pad_id, bucket, req)
             row[0, bucket + 1] = slot
             packed = jnp.asarray(row)
+            self._note_prefill(req, slot, bucket, False, "whole")
             self._state, first = self._prefill_runner(bucket)(
                 self.params, self.lora, self._state, packed,
             )
@@ -892,7 +1049,8 @@ class DecodeEngine:
         plen, entry = self._match_prefix(req.prompt)
         if plen is not None:
             self.prefix_hits += 1
-            sub_cache = self._seed_prefix_runner(plen)(sub_cache, entry)
+            self._note_prefill(req, slot, plen, True, "seed")
+            sub_cache = self._prefill_seed_runner(plen)(sub_cache, entry)
             start = plen
         else:
             self.prefix_misses += 1
@@ -920,6 +1078,9 @@ class DecodeEngine:
             seg = jnp.asarray(
                 [req.prompt[consumed:consumed + C]], jnp.int32
             )
+            self._note_prefill(
+                req, slot, C, adm["had_prefix"], f"part@{consumed}"
+            )
             adm["sub"] = self._prefill_part_runner(C)(
                 self.params, self.lora, adm["sub"], seg,
                 jnp.int32(consumed),
@@ -931,6 +1092,7 @@ class DecodeEngine:
         row = self.pack_admission(rem, self.pad_id, C, req)
         row[0, C + 1] = slot
         packed = jnp.asarray(row)
+        self._note_prefill(req, slot, C, adm["had_prefix"], "final")
         self._state, first = self._prefill_final_runner(C)(
             self.params, self.lora, self._state, adm["sub"], packed,
             jnp.int32(consumed),
@@ -980,10 +1142,20 @@ class DecodeEngine:
         if self.failure is None:
             self.failure = exc
         self._admitting = None  # its request is failed via _slot_req
+        # the span that raised (a phase of the turn, or the admission's
+        # request) carries status ``error``; every other request names
+        # that turn's trace instead of being kept as an error of its own
+        turn = tracing.current()
+        failed_by = turn.trace_id if turn is not None else ""
+
+        def fail(req: _Request) -> None:
+            if req.finish_t is None:
+                req.error, req.failed_by = exc, failed_by
+            req._finish()
+
         for slot, req in enumerate(self._slot_req):
             if req is not None:
-                req.error = exc
-                req._finish()
+                fail(req)
                 self._slot_req[slot] = None
         while True:
             try:
@@ -991,8 +1163,15 @@ class DecodeEngine:
             except queue.Empty:
                 break
             if req is not None:
-                req.error = exc
-                req._finish()
+                fail(req)
+
+    def _fail_admission(self, req: _Request, exc: Exception) -> None:
+        """An admission raised: its ``engine.admit`` span closes with
+        status ``error``, its request fails first, then the engine."""
+        tracing.set_status("error", f"{type(exc).__name__}: {exc}")
+        req.error = exc
+        req._finish()
+        self._fail_engine(exc)
 
     def _loop(self) -> None:
         try:
@@ -1012,7 +1191,36 @@ class DecodeEngine:
 
     def _run_loop(self) -> None:
         while not self._stopped:
-            admitted = False
+            if (
+                self._admitting is None
+                and all(r is None for r in self._slot_req)
+                and self._queue.empty()
+            ):
+                # nothing in flight, nothing queued: one span per idle
+                # period, a root of its own (never "slow"), so that an
+                # idle engine neither writes a turn every poll nor has
+                # its wait kept as a stalled turn
+                with hot_span("engine.idle"):
+                    while not (
+                        self._wake.wait(timeout=0.05)
+                        or self._stopped
+                        or not self._queue.empty()
+                    ):
+                        pass
+                    self._wake.clear()
+                continue
+            self.turns += 1
+            with hot_span("engine.turn", turn=self.turns):
+                if not self._turn():
+                    return
+
+    def _turn(self) -> bool:
+        """One turn of the loop: admit, then (if anything decodes)
+        dispatch a chunk, fetch it, emit. Each phase is a child span of
+        the caller's ``engine.turn`` and they tile it; a phase that
+        fails closes with status ``error``. False: the loop must exit
+        (stop sentinel, or the engine failed)."""
+        with hot_span("engine.admit"):
             if self._admitting is not None:
                 # one prefill part per loop turn: active slots get a
                 # decode chunk below before the next part runs
@@ -1020,24 +1228,23 @@ class DecodeEngine:
                 try:
                     self._admit_step()
                 except Exception as e:  # noqa: BLE001 — state integrity unknown
-                    req.error = e
-                    req._finish()
-                    self._fail_engine(e)
-                    return
-                admitted = True
+                    self._fail_admission(req, e)
+                    return False
             while self._admitting is None and None in self._slot_req:
                 try:
                     req = self._queue.get_nowait()
                 except queue.Empty:
                     break
                 if req is None:
-                    return
+                    return False
                 if req.cancelled:
                     # client left while the request was still queued:
                     # don't spend a prefill (possibly a fresh compile)
                     # on it
                     req._finish()
                     continue
+                req.admit_t = time.monotonic()
+                self.m_queue_wait.observe(req.admit_t - req.submit_t)
                 try:
                     if (
                         self.prefill_chunk is not None
@@ -1046,114 +1253,109 @@ class DecodeEngine:
                         self._begin_chunked_admit(req)
                     else:
                         self._admit(req)
-                    admitted = True
                 except Exception as e:  # noqa: BLE001 — state integrity unknown
-                    req.error = e
-                    req._finish()
-                    self._fail_engine(e)
-                    return
+                    self._fail_admission(req, e)
+                    return False
             self.m_queue_depth.set(self._queue.qsize())
-            adm_slot = (
-                self._admitting["slot"]
-                if self._admitting is not None
-                else -1
+        adm_slot = (
+            self._admitting["slot"] if self._admitting is not None else -1
+        )
+        if not any(
+            r is not None and s != adm_slot
+            for s, r in enumerate(self._slot_req)
+        ):
+            # nothing decoding: a chunked admission runs its parts
+            # back-to-back, one turn each
+            return True
+        # two compiled chunk programs: the greedy one (argmax, no
+        # vocab sorts) whenever every in-flight request is greedy —
+        # the common serving mix — else the general sampler
+        all_greedy = all(
+            r is None or r.temperature <= 0 for r in self._slot_req
+        )
+        if self._spec_fn is not None:
+            # draft attached (greedy-only by submit contract):
+            # spec_rounds_per_call rounds per loop turn
+            program, chunk_fn = "spec", self._spec_fn
+            weights = (self.params, self.lora, self.draft_params)
+        else:
+            program = "greedy" if all_greedy else "sample"
+            chunk_fn = (
+                self._decode_greedy_fn if all_greedy else self._decode_fn
             )
-            if not any(
-                r is not None and s != adm_slot
-                for s, r in enumerate(self._slot_req)
-            ):
-                if self._admitting is not None:
-                    continue  # nothing decoding: run parts back-to-back
-                if not admitted:
-                    self._wake.wait(timeout=0.05)
-                    self._wake.clear()
-                continue
-            # two compiled chunk programs: the greedy one (argmax, no
-            # vocab sorts) whenever every in-flight request is greedy —
-            # the common serving mix — else the general sampler
-            all_greedy = all(
-                r is None or r.temperature <= 0 for r in self._slot_req
-            )
-            try:
-                if self._spec_fn is not None:
-                    # draft attached (greedy-only by submit contract):
-                    # spec_rounds_per_call rounds per loop turn
-                    self._state, (toks, mask) = self._spec_fn(
-                        (self.params, self.lora, self.draft_params),
-                        self._state,
-                    )
-                    self.spec_rounds += self.spec_rounds_per_call
-                else:
-                    decode = (
-                        self._decode_greedy_fn
-                        if all_greedy
-                        else self._decode_fn
-                    )
-                    self._state, (toks, mask) = decode(
-                        (self.params, self.lora), self._state
-                    )
-                pending = self._pending_first
-                self._pending_first = []
+            weights = (self.params, self.lora)
+        try:
+            with hot_span("engine.dispatch", program=program):
+                self._state, (toks, mask) = chunk_fn(weights, self._state)
+            if self._spec_fn is not None:
+                self.spec_rounds += self.spec_rounds_per_call
+            pending = self._pending_first
+            self._pending_first = []
+            # the host waiting for the device: the chunk's tokens and
+            # the first tokens of the prefills dispatched before it
+            with hot_span("engine.fetch", first_tokens=len(pending)):
                 toks, mask, firsts = jax.device_get(
                     (toks, mask, [f for (_r, f, _s) in pending])
                 )
-            except Exception as e:  # noqa: BLE001 — state integrity unknown
-                self._fail_engine(e)
-                return
-            for (preq, _f, pslot), tok in zip(pending, firsts):
-                tok = int(tok)
-                preq._emit(tok)
-                self._observe_emit(preq)
-                self.tokens_emitted += 1
-                if tok == preq.eos_id:
-                    preq._finish()
-                    # free the slot on device: its chunk emissions are
-                    # masked off by the active flag at the next update
-                    self._state["active"] = (
-                        self._state["active"].at[pslot].set(False)
-                    )
-                    self._slot_req[pslot] = None
-            self.decode_steps += (
-                self.spec_rounds_per_call
-                if self._spec_fn is not None
-                else self.chunk
-            )
-            for slot, req in enumerate(self._slot_req):
-                if req is None:
-                    continue
-                if (
-                    self._admitting is not None
-                    and self._admitting["slot"] == slot
-                ):
-                    # mid-admission slot: device-inactive, no
-                    # emissions; cancellation is _admit_step's job
-                    # (freeing it here would race a re-claim)
-                    continue
-                if req.cancelled:
-                    # client abandoned the stream: deactivate the slot
-                    # on device (stops its kv growth and emission) and
-                    # free it now instead of decoding for nobody
-                    self._state["active"] = (
-                        self._state["active"].at[slot].set(False)
-                    )
-                    req._finish()
-                    self._slot_req[slot] = None
-                    continue
-                for t, live in zip(toks[slot], mask[slot]):
-                    if live:
-                        req._emit(int(t))
-                        self._observe_emit(req)
-                        self.tokens_emitted += 1
-                if (
-                    len(req.tokens) >= req.max_tokens
-                    or (req.tokens and req.tokens[-1] == req.eos_id)
-                ):
-                    req._finish()
-                    self._slot_req[slot] = None
-            self.m_occupancy.set(
-                sum(1 for r in self._slot_req if r is not None)
-                / float(self.n_slots)
-            )
+        except Exception as e:  # noqa: BLE001 — state integrity unknown
+            self._fail_engine(e)
+            return False
+        with hot_span("engine.emit"):
+            self._emit_chunk(pending, firsts, toks, mask)
+        return True
+
+    def _emit_chunk(self, pending, firsts, toks, mask) -> None:
+        for (preq, _f, pslot), tok in zip(pending, firsts):
+            tok = int(tok)
+            preq._emit(tok)
+            self._observe_emit(preq)
+            self.tokens_emitted += 1
+            if tok == preq.eos_id:
+                preq._finish()
+                # free the slot on device: its chunk emissions are
+                # masked off by the active flag at the next update
+                self._state["active"] = (
+                    self._state["active"].at[pslot].set(False)
+                )
+                self._slot_req[pslot] = None
+        self.decode_steps += (
+            self.spec_rounds_per_call
+            if self._spec_fn is not None
+            else self.chunk
+        )
+        for slot, req in enumerate(self._slot_req):
+            if req is None:
+                continue
+            if (
+                self._admitting is not None
+                and self._admitting["slot"] == slot
+            ):
+                # mid-admission slot: device-inactive, no
+                # emissions; cancellation is _admit_step's job
+                # (freeing it here would race a re-claim)
+                continue
+            if req.cancelled:
+                # client abandoned the stream: deactivate the slot
+                # on device (stops its kv growth and emission) and
+                # free it now instead of decoding for nobody
+                self._state["active"] = (
+                    self._state["active"].at[slot].set(False)
+                )
+                req._finish()
+                self._slot_req[slot] = None
+                continue
+            for t, live in zip(toks[slot], mask[slot]):
+                if live:
+                    req._emit(int(t))
+                    self._observe_emit(req)
+                    self.tokens_emitted += 1
+            if req.complete:
+                req._finish()
+                self._slot_req[slot] = None
+        self.m_occupancy.set(
+            sum(1 for r in self._slot_req if r is not None)
+            / float(self.n_slots)
+        )
 
     # -- public API ---------------------------------------------------------
 
@@ -1207,6 +1409,7 @@ class DecodeEngine:
             eos_id=-1 if eos_id is None else int(eos_id),
             token_q=queue.Queue() if stream else None,
             submit_t=time.monotonic(),
+            submit_wall=time.time(),
         )
         self._queue.put(req)
         self.m_queue_depth.set(self._queue.qsize())

@@ -313,13 +313,18 @@ def _maybe_lora(name: str, x: jnp.ndarray, w, lora_layer,
     ``w`` may be an un-dequantized int8 leaf (the W8A8 decode path);
     ``xq_sx`` optionally carries x already activation-quantized (shared
     across projections reading the same input)."""
-    if isinstance(w, dict):
-        if xq_sx is not None:
-            y = _int8_matmul_pre(xq_sx[0], xq_sx[1], w, x.dtype)
+    # the scope names in this file (frozen_matmul, dequant, attention,
+    # kv_cache_write, kv_cache_read) are the blocks PERF.md section 5
+    # names as where the time goes; they reach a profile as the
+    # operations' op_name, never as an event's name
+    with jax.named_scope("frozen_matmul"):
+        if isinstance(w, dict):
+            if xq_sx is not None:
+                y = _int8_matmul_pre(xq_sx[0], xq_sx[1], w, x.dtype)
+            else:
+                y = _int8_matmul(x, w)
         else:
-            y = _int8_matmul(x, w)
-    else:
-        y = x @ w.astype(x.dtype)
+            y = x @ w.astype(x.dtype)
     if lora_layer is not None and name in lora_layer:
         a = lora_layer[name]["a"].astype(x.dtype)  # [D, r]
         b = lora_layer[name]["b"].astype(x.dtype)  # [r, out]
@@ -369,7 +374,8 @@ def _decoder_layer(
     # Under w8a8_decode (cache path only), int8 matmul weights skip
     # dequant entirely — _maybe_lora runs them on the int8 MXU.
     keep = cache_layer is not None and cfg.w8a8_decode
-    layer = _maybe_dequant(layer, cfg.dtype, keep_int8_matmuls=keep)
+    with jax.named_scope("dequant"):
+        layer = _maybe_dequant(layer, cfg.dtype, keep_int8_matmuls=keep)
 
     h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
     # W8A8: wq/wk/wv read the same input — quantize it once
@@ -393,7 +399,8 @@ def _decoder_layer(
             q, kk, vv, cache_layer, cache_index, kv_mask
         )
     else:
-        attn = attention_fn(q, kk, vv, segment_ids=segment_ids)
+        with jax.named_scope("attention"):
+            attn = attention_fn(q, kk, vv, segment_ids=segment_ids)
     # named so the "attn" remat policy can pin exactly this tensor
     attn = _checkpoint_name(attn, "attn_out")
     attn = attn.reshape(B, S, cfg.q_dim)
@@ -435,45 +442,47 @@ def cache_write_and_attend(
     layout (``models/engine.py``): each batch slot sits at its own
     depth, so writes scatter per-row — S must be 1 on that path.
     """
-    if getattr(cache_index, "ndim", 0) == 1:
-        B, S = q.shape[0], q.shape[1]
-        rows = jnp.arange(B)
-        if S == 1:
-            ck = cache_layer["k"].at[rows, cache_index].set(
-                kk[:, 0].astype(cache_layer["k"].dtype)
-            )
-            cv = cache_layer["v"].at[rows, cache_index].set(
-                vv[:, 0].astype(cache_layer["v"].dtype)
-            )
+    with jax.named_scope("kv_cache_write"):
+        if getattr(cache_index, "ndim", 0) == 1:
+            B, S = q.shape[0], q.shape[1]
+            rows = jnp.arange(B)
+            if S == 1:
+                ck = cache_layer["k"].at[rows, cache_index].set(
+                    kk[:, 0].astype(cache_layer["k"].dtype)
+                )
+                cv = cache_layer["v"].at[rows, cache_index].set(
+                    vv[:, 0].astype(cache_layer["v"].dtype)
+                )
+            else:
+                # per-row offsets with a multi-token window — the engine's
+                # speculative verify (k+1 tokens per slot, each slot at its
+                # own depth). Clamp keeps ragged slots in bounds; the
+                # engine's kv_mask excludes anything beyond the real window.
+                S_max = cache_layer["k"].shape[1]
+                cols = jnp.clip(
+                    cache_index[:, None] + jnp.arange(S)[None, :], 0, S_max - 1
+                )
+                ck = cache_layer["k"].at[rows[:, None], cols].set(
+                    kk.astype(cache_layer["k"].dtype)
+                )
+                cv = cache_layer["v"].at[rows[:, None], cols].set(
+                    vv.astype(cache_layer["v"].dtype)
+                )
         else:
-            # per-row offsets with a multi-token window — the engine's
-            # speculative verify (k+1 tokens per slot, each slot at its
-            # own depth). Clamp keeps ragged slots in bounds; the
-            # engine's kv_mask excludes anything beyond the real window.
-            S_max = cache_layer["k"].shape[1]
-            cols = jnp.clip(
-                cache_index[:, None] + jnp.arange(S)[None, :], 0, S_max - 1
+            ck = jax.lax.dynamic_update_slice(
+                cache_layer["k"],
+                kk.astype(cache_layer["k"].dtype),
+                (0, cache_index, 0, 0),
             )
-            ck = cache_layer["k"].at[rows[:, None], cols].set(
-                kk.astype(cache_layer["k"].dtype)
+            cv = jax.lax.dynamic_update_slice(
+                cache_layer["v"],
+                vv.astype(cache_layer["v"].dtype),
+                (0, cache_index, 0, 0),
             )
-            cv = cache_layer["v"].at[rows[:, None], cols].set(
-                vv.astype(cache_layer["v"].dtype)
-            )
-    else:
-        ck = jax.lax.dynamic_update_slice(
-            cache_layer["k"],
-            kk.astype(cache_layer["k"].dtype),
-            (0, cache_index, 0, 0),
+    with jax.named_scope("kv_cache_read"):
+        attn = dense_attention(
+            q, ck, cv, causal=True, q_offset=cache_index, kv_mask=kv_mask
         )
-        cv = jax.lax.dynamic_update_slice(
-            cache_layer["v"],
-            vv.astype(cache_layer["v"].dtype),
-            (0, cache_index, 0, 0),
-        )
-    attn = dense_attention(
-        q, ck, cv, causal=True, q_offset=cache_index, kv_mask=kv_mask
-    )
     return attn, {"k": ck, "v": cv}
 
 

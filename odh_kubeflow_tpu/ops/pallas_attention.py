@@ -360,6 +360,7 @@ def _fwd(
 
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(B, Hq, L),
@@ -695,6 +696,7 @@ def _bwd(
 
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_bwd_dq",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(B, Hq, dq_tabs[0].shape[0]),
@@ -780,6 +782,7 @@ def _bwd(
 
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_bwd_dkv",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(B, Hkv, dkv_tabs[0].shape[0]),

@@ -286,6 +286,7 @@ def _gmm_a(lhs, rhs, group_of_tile, *, trans_rhs, interpret,
         operands.append(scale)
     return pl.pallas_call(
         kernel,
+        name="gmm_a",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(pref),
             grid=grid,
@@ -448,6 +449,7 @@ def _gmm_b(lhs, rhs, pairs, group_offsets, *, trans_rhs, bm, bk, bn,
         strip(functools.partial(
             _gmm_b_kernel, bm=bm, bn=bn, nk=nk, trans_rhs=trans_rhs
         )),
+        name="gmm_b",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=npref,
             grid=(L, n // bn, nk),
@@ -519,6 +521,7 @@ def _tgmm(lhs, dout, pairs, group_offsets, *, bm, bk, bn, interpret):
     offs = jnp.concatenate([group_offsets, group_offsets[-1:]])
     out = pl.pallas_call(
         functools.partial(_tgmm_kernel, bm=bm),
+        name="tgmm",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,
             grid=(k // bk, n // bn, L),
@@ -756,6 +759,7 @@ def _swiglu_fwd_impl(lhs, wg, wu, sg, su, group_of_tile, base, interpret):
     )
     return pl.pallas_call(
         functools.partial(_swiglu_fwd_kernel, has_base=base is not None),
+        name="gmm_swiglu_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(pref),
             grid=(n // bn, T),
@@ -781,6 +785,7 @@ def _swiglu_bwd_impl(lhs, wu, su, g, dh, group_of_tile, base, interpret):
     )
     return pl.pallas_call(
         functools.partial(_swiglu_bwd_kernel, has_base=base is not None),
+        name="gmm_swiglu_bwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(pref),
             grid=(n // bn, T),

@@ -57,6 +57,7 @@ def int4_dequant(packed, scale, dtype=jnp.bfloat16, *, group=128,
     srows = bk // group
     return pl.pallas_call(
         functools.partial(_dequant_kernel, group=group, bk=bk),
+        name="int4_dequant",
         grid=(2, K2 // bk, N // bn),
         in_specs=[
             pl.BlockSpec((bk, bn), lambda h, i, j: (i, j)),
@@ -174,6 +175,7 @@ def _int4_mm_impl(x, q4, scale4, *, group, interpret):
 
     return pl.pallas_call(
         functools.partial(_int4_mm_kernel, nc=nc, q=q),
+        name="int4_matmul",
         grid=(N // bn, M // bm, nc),
         in_specs=[
             pl.BlockSpec((bm, kb), lambda ni, mi, c: (mi, c)),
@@ -245,6 +247,7 @@ def _int4_dlhs_impl(dout, q4, scale4, *, group, interpret):
         functools.partial(
             _int4_dlhs_kernel, nn=N // bn, nc=nc, q=q
         ),
+        name="int4_matmul_dlhs",
         grid=(nc, M // bm, N // bn),
         in_specs=[
             pl.BlockSpec((bm, bn), lambda c, mi, ni: (mi, ni)),

@@ -26,6 +26,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from odh_kubeflow_tpu.models import llama, lora as lora_lib
 from odh_kubeflow_tpu.parallel.mesh import batch_spec, build_mesh, constrain
 from odh_kubeflow_tpu.utils import prometheus
+from odh_kubeflow_tpu.utils.profiling import hot_span
 from odh_kubeflow_tpu.warmup.compilecache import install_process_cache
 
 Params = dict[str, Any]
@@ -132,8 +133,9 @@ def chunked_cross_entropy(
         m = m.astype(jnp.float32)
         return jnp.sum(nll * m), jnp.sum(m)
 
-    nll_sum, mask_sum = jax.lax.map(one_chunk, jnp.arange(n))
-    return jnp.sum(nll_sum) / jnp.maximum(jnp.sum(mask_sum), 1.0)
+    with jax.named_scope("chunked_cross_entropy"):
+        nll_sum, mask_sum = jax.lax.map(one_chunk, jnp.arange(n))
+        return jnp.sum(nll_sum) / jnp.maximum(jnp.sum(mask_sum), 1.0)
 
 
 def _pipe_shard_layer_specs(spec_tree):
@@ -301,6 +303,11 @@ class Trainer:
             trainable_shapes, self._train_specs
         )
         self.step = 0
+        # which executable the steps ran: the one compiled ahead of
+        # time for the batch's shape (precompile_async), or the lazy
+        # jit's (which compiles on its first call with a shape)
+        self.aot_steps = 0
+        self.lazy_steps = 0
         self._compiled = self._build_step()
         self._aot: dict = {}
         self._aot_threads: dict = {}
@@ -474,10 +481,11 @@ class Trainer:
             loss, grads = jax.value_and_grad(self._loss_fn)(
                 trainable, frozen, batch
             )
-            updates, opt_state = self.optimizer.update(
-                grads, opt_state, params=trainable
-            )
-            trainable = optax.apply_updates(trainable, updates)
+            with jax.named_scope("optimizer_update"):
+                updates, opt_state = self.optimizer.update(
+                    grads, opt_state, params=trainable
+                )
+                trainable = optax.apply_updates(trainable, updates)
             gnorm = optax.global_norm(grads)
             return trainable, opt_state, {"loss": loss, "grad_norm": gnorm}
 
@@ -578,13 +586,16 @@ class Trainer:
         self._aot_threads[akey] = th
         th.start()
 
-    def _aot_executable(self, batch: dict):
-        akey = (
-            *batch["tokens"].shape, tuple(sorted(batch)),
-        )
+    def compiled_step(self, batch_size: int, seq_len: int, keys: tuple):
+        """The ahead-of-time executable (``jax.stages.Compiled``) for
+        this batch shape, joined if it is still compiling; None where
+        ``precompile_async`` was never asked for it. Raises what its
+        compile raised."""
+        akey = (batch_size, seq_len, tuple(sorted(keys)))
         th = self._aot_threads.pop(akey, None)
         if th is not None:
-            th.join()
+            with hot_span("trainer.aot_wait"):
+                th.join()
         exe = self._aot.get(akey)
         if isinstance(exe, Exception):
             # a failed ahead-of-time compile is an error of the step
@@ -594,25 +605,38 @@ class Trainer:
         return exe
 
     def train_step(self, batch: dict) -> dict:
+        """One optimizer step, recorded as ``trainer.step`` (host time
+        inside this call) over ``trainer.aot_wait`` (the join on the
+        compile thread, first step of a shape only), ``trainer.h2d``
+        (the batch's ``device_put``) and ``trainer.dispatch`` (the
+        executable's call; the lazy jit compiles inside it)."""
         t_start = time.perf_counter()
         trainable = self.lora_params if self.lora_cfg is not None else self.params
         frozen = self.params
-        with jax.set_mesh(self.mesh):
-            exe = self._aot_executable(batch)
-            if exe is not None:
-                from odh_kubeflow_tpu.parallel.mesh import batch_spec
-
-                bsh = NamedSharding(self.mesh, batch_spec())
-                batch = {
-                    k: jax.device_put(v, bsh) for k, v in batch.items()
-                }
-                trainable, self.opt_state, metrics = exe(
-                    trainable, frozen, self.opt_state, batch
-                )
+        akey = (*batch["tokens"].shape, tuple(sorted(batch)))
+        aot = akey in self._aot or akey in self._aot_threads
+        with hot_span(
+            "trainer.step", step=self.step,
+            executable="aot" if aot else "lazy",
+        ), jax.set_mesh(self.mesh):
+            if aot:
+                exe = self.compiled_step(*akey)
+                with hot_span("trainer.h2d"):
+                    bsh = NamedSharding(self.mesh, batch_spec())
+                    batch = {
+                        k: jax.device_put(v, bsh) for k, v in batch.items()
+                    }
+                with hot_span("trainer.dispatch"):
+                    trainable, self.opt_state, metrics = exe(
+                        trainable, frozen, self.opt_state, batch
+                    )
+                self.aot_steps += 1
             else:
-                trainable, self.opt_state, metrics = self._compiled(
-                    trainable, frozen, self.opt_state, batch
-                )
+                with hot_span("trainer.dispatch"):
+                    trainable, self.opt_state, metrics = self._compiled(
+                        trainable, frozen, self.opt_state, batch
+                    )
+                self.lazy_steps += 1
         if self.lora_cfg is not None:
             self.lora_params = trainable
         else:

@@ -9,6 +9,12 @@ pods). The layout produced here is exactly TensorBoard's profile
 plugin contract: ``<logdir>/plugins/profile/<session>/<host>.xplane.pb``
 plus ``.trace.json.gz``.
 
+The program's own hot path (the decode engine's loop, the trainer's
+step) marks its phases with :func:`hot_span`: one name, recorded in the
+process's span ring (``utils/tracing.py``, what ``/debug/traces``
+renders) and, while a profile is being captured, on the profiler's host
+plane beside the device's ``XLA Ops``.
+
 ``jupyter-jax-tpu`` images auto-start the profiler server in every
 IPython kernel (images/jupyter/start-jupyter.sh seeds the startup
 file), so TensorBoard's "capture profile" button works against a
@@ -22,7 +28,9 @@ import gzip
 import json
 import os
 from contextlib import contextmanager
-from typing import Any, Optional
+from typing import Any, Iterator, Optional
+
+from odh_kubeflow_tpu.utils import tracing
 
 DEFAULT_PORT = int(os.environ.get("JAX_PROFILER_PORT", "9999"))
 
@@ -57,6 +65,43 @@ def capture_trace(logdir: str):
     with jax.profiler.trace(logdir):
         yield
     # jax writes plugins/profile/<ts>/ under logdir
+
+
+# The hot path's ROOT spans, and the latency over which the collector
+# keeps one (with its children) as ``slow``; under it the span ages out
+# of the ring as healthy. A turn of the decode engine is one decode
+# chunk (0.3 s at 7B on one v5e) plus the prefills it admitted, so 2 s
+# is a stall (or a compile inside the loop). A request lasts seconds to
+# minutes by design (512 tokens at 40 ms are 20 s): only one that
+# outlives a full-length generation is kept, or the kept store (128
+# traces) would fill with healthy traffic. Waiting for work is never
+# slow. A training step's host time converges on the device's step time
+# whatever the model: only one that waits out a long compile is kept.
+HOT_ROOT_SLOW_S = {
+    "engine.turn": 2.0,
+    "engine.request": 120.0,
+    "engine.idle": float("inf"),
+    "trainer.step": 60.0,
+}
+tracing.ROOT_THRESHOLDS.update(HOT_ROOT_SLOW_S)
+
+
+@contextmanager
+def hot_span(name: str, **attrs: Any) -> Iterator[tracing.SpanContext]:
+    """A span of the hot path: ``tracing.span(name, **attrs)`` and a
+    ``jax.profiler.TraceAnnotation(name)`` entered together. The span
+    always lands in the process's ring (bounded, tail-kept like every
+    other trace); the annotation costs a ``TraceMe`` check unless a
+    profile is being captured (:func:`capture_trace`, the profiler
+    server, a benchmark's ``--trace 1``), and then the same name lies on
+    the capture's host plane, on one timeline with ``/device:TPU:0``.
+
+    Granularity is the caller's contract: one per loop turn, per phase
+    of a turn, per training step — never per token, slot or layer."""
+    from jax.profiler import TraceAnnotation
+
+    with tracing.span(name, **attrs) as ctx, TraceAnnotation(name):
+        yield ctx
 
 
 def trace_sessions(logdir: str) -> list[str]:

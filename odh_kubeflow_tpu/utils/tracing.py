@@ -35,10 +35,11 @@ import contextlib
 import dataclasses
 import json
 import logging
+import os
+import random
 import re
 import threading
 import time
-import uuid
 from collections import OrderedDict, deque
 from contextvars import ContextVar
 from typing import Any, Callable, Iterator, Mapping, Optional
@@ -51,12 +52,22 @@ _TRACEPARENT_RE = re.compile(
 )
 
 
+# Ids come from a generator of this process's own, seeded once from the
+# kernel's entropy (and again in a forked child): random as W3C
+# trace-context asks, and made WITHOUT a system call. ``uuid.uuid4()``
+# is a ``getrandom(2)`` per id; the hot path's spans (one per engine
+# turn, phase and request) must not enter the kernel, whose cost is the
+# sandbox's to set (PERF.md, PR 24).
+_ids = random.Random()
+os.register_at_fork(after_in_child=_ids.seed)
+
+
 def new_trace_id() -> str:
-    return uuid.uuid4().hex  # 32 hex chars
+    return f"{_ids.getrandbits(128):032x}"  # 32 hex chars
 
 
 def new_span_id() -> str:
-    return uuid.uuid4().hex[:16]
+    return f"{_ids.getrandbits(64):016x}"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -125,7 +136,12 @@ def parse_traceparent(header: Optional[str]) -> Optional[SpanContext]:
 class SpanRecord:
     """One finished span — what the collector stores and the ingest
     endpoint ships. ``start`` is wall-clock epoch seconds (cross-process
-    assembly orders by it), ``duration`` comes from a monotonic clock."""
+    assembly orders by it), ``duration`` comes from a monotonic clock.
+    ``start_mono`` is the same start on ``time.monotonic()``, the clock
+    a load generator or a benchmark stamps with: inside one process a
+    span can be laid beside such stamps (and, through
+    ``profiling.hot_span``, beside the device trace). It means nothing
+    across processes; 0.0 when the sender did not carry it."""
 
     trace_id: str
     span_id: str
@@ -137,6 +153,7 @@ class SpanRecord:
     error: str = ""
     attrs: dict = dataclasses.field(default_factory=dict)
     events: list = dataclasses.field(default_factory=list)
+    start_mono: float = 0.0
 
     @property
     def end(self) -> float:
@@ -154,6 +171,7 @@ class SpanRecord:
             "error": self.error,
             "attrs": dict(self.attrs),
             "events": [list(e) for e in self.events],
+            "startMono": self.start_mono,
         }
 
     @classmethod
@@ -169,7 +187,17 @@ class SpanRecord:
             error=str(d.get("error", "")),
             attrs=dict(d.get("attrs") or {}),
             events=[list(e) for e in (d.get("events") or [])],
+            start_mono=float(d.get("startMono", 0.0)),
         )
+
+
+# Latency thresholds declared with a ROOT span's name by the module that
+# defines the name (``profiling.HOT_ROOT_SLOW_S`` for the hot path): a
+# collector falls back to them before its default, so they hold for
+# whichever collector is current, also one installed later with
+# ``set_collector``. ``SpanCollector.set_threshold`` overrides per
+# collector.
+ROOT_THRESHOLDS: dict[str, float] = {}
 
 
 class SpanCollector:
@@ -214,7 +242,9 @@ class SpanCollector:
             self._thresholds[root_name] = float(seconds)
 
     def threshold_for(self, name: str) -> float:
-        return self._thresholds.get(name, self.default_threshold_s)
+        return self._thresholds.get(
+            name, ROOT_THRESHOLDS.get(name, self.default_threshold_s)
+        )
 
     def record(self, rec: SpanRecord) -> None:
         if not rec.trace_id:
@@ -262,6 +292,21 @@ class SpanCollector:
             if kept is not None:
                 return list(kept)
             return [r for r in self._ring if r.trace_id == trace_id]
+
+    def spans_named(self, prefix: str) -> list[SpanRecord]:
+        """Every finished span whose name starts with ``prefix``, oldest
+        first, from the ring and the kept store, each span once (a
+        promoted span sits in both). What a reader of the hot path's
+        spans (``engine.``, ``trainer.``) needs; ``recorded_total``
+        against ``capacity`` says whether the ring can have wrapped."""
+        with self._lock:
+            found = {
+                id(r): r
+                for spans in (self._ring, *self._kept.values())
+                for r in spans
+                if r.name.startswith(prefix)
+            }
+        return sorted(found.values(), key=lambda r: r.start)
 
     def keep_reason(self, trace_id: str) -> Optional[str]:
         with self._lock:
@@ -357,7 +402,8 @@ def span(
     (the annotation-carried cross-process hop); attrs merge over the
     parent's when staying in the same trace.
 
-    On exit the span is *recorded*: wall start + monotonic duration,
+    On exit the span is *recorded*: wall start, the same start on
+    ``time.monotonic()``, the duration on that clock,
     status (an escaping exception ⇒ 'error' with the exception
     captured), and any ``add_event`` events flow into the process
     collector and sinks."""
@@ -377,7 +423,7 @@ def span(
     )
     token = _current.set(ctx)
     start_wall = time.time()
-    t0 = time.perf_counter()
+    t0 = time.monotonic()
     status, error = "ok", ""
     try:
         yield ctx
@@ -397,7 +443,7 @@ def span(
                     parent_span_id=ctx.parent_span_id,
                     name=name,
                     start=start_wall,
-                    duration=time.perf_counter() - t0,
+                    duration=time.monotonic() - t0,
                     status=status,
                     error=error,
                     attrs=dict(attrs),
@@ -405,6 +451,7 @@ def span(
                         (ts, ename, dict(eattrs))
                         for ts, ename, eattrs in ctx.events
                     ],
+                    start_mono=t0,
                 )
             )
 
