@@ -161,7 +161,113 @@ def check_kernels(cfg) -> dict:
                     f"flash {name} {part} disagrees with dense_attention: "
                     f"relative error {err}"
                 )
+    errs.update(check_decode_attend(cfg))
     return {"rel_err_vs_dense": errs}
+
+
+def check_decode_attend(cfg) -> dict:
+    """The cached forward's read (``decode_attend``: a layer of the
+    stacked cache, where it lies) against ``dense_attention`` on that
+    layer sliced out, at the serve leg's geometry: the engine's decode
+    (a [B] index, one token, ragged depths), a speculative window, and a
+    prefill (scalar index, a bucket of tokens). Same bound as flash."""
+    from odh_kubeflow_tpu.ops.attention import dense_attention
+    from odh_kubeflow_tpu.ops.pallas_decode_attention import decode_attend
+
+    L, B, S_max = 3, 4, 2048
+    Hq, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    kq, kk, kv, km = jax.random.split(jax.random.key(11), 4)
+    cache_k = jax.random.normal(kk, (L, B, S_max, Hkv * hd), jnp.bfloat16)
+    cache_v = jax.random.normal(kv, (L, B, S_max, Hkv * hd), jnp.bfloat16)
+    kv_mask = (jax.random.uniform(km, (B, S_max)) < 0.9).at[:, 0].set(True)
+    layer = jnp.int32(L - 1)
+
+    @jax.jit
+    def dense(q, cache_k, cache_v, layer, index, kv_mask):
+        k, v = (
+            c[layer].reshape(-1, S_max, Hkv, hd) for c in (cache_k, cache_v)
+        )
+        return dense_attention(
+            q, k, v, causal=True, q_offset=index, kv_mask=kv_mask
+        )
+
+    errs = {}
+    for name, rows, S, index in (
+        ("decode", B, 1, jnp.asarray([0, 300, 1100, S_max - 1], jnp.int32)),
+        ("window", B, 5, jnp.asarray([7, 509, 1020, S_max - 5], jnp.int32)),
+        ("prefill", 1, 256, jnp.int32(384)),
+    ):
+        q = jax.random.normal(kq, (rows, S, Hq, hd), jnp.bfloat16)
+        operands = (cache_k[:, :rows], cache_v[:, :rows], layer, index,
+                    kv_mask[:rows])
+        err = _rel_err(decode_attend(q, *operands), dense(q, *operands))
+        errs[f"decode_attend.{name}"] = round(err, 5)
+        if not err < 5e-2:
+            raise AssertionError(
+                f"decode_attend {name} disagrees with dense_attention: "
+                f"relative error {err}"
+            )
+    return errs
+
+
+def cache_layer_copies(hlo_text: str, cache_leaf) -> list:
+    """The instructions of a compiled module that produce one whole
+    layer of the stacked cache (``[slots, S_max, Hkv * hd]``, with or
+    without a leading 1): what copying a layer out of the stack, or back
+    into it, looks like."""
+    import re
+
+    hlo_type = {"bfloat16": "bf16", "float16": "f16", "float32": "f32"}[
+        cache_leaf.dtype.name
+    ]
+    dims = ",".join(str(d) for d in cache_leaf.shape[1:])
+    a_layer = re.compile(rf"= {hlo_type}\[(?:1,)?{dims}\]")
+    return [
+        line.strip()[:200]
+        for line in hlo_text.splitlines()
+        if a_layer.search(line) and " parameter(" not in line
+    ]
+
+
+def assert_decode_reads_cache_in_place(engine) -> dict:
+    """From the decode chunk's compiled executable: the donated cache is
+    aliased to the cache that comes back, nothing the size of the cache
+    is a temporary, and no instruction produces a whole layer's keys or
+    values. The last is what a relapse looks like: a layer scan that has
+    the cache as a scanned input (or a read that XLA serves by copying
+    the layer out of the stack) materialises ``[slots, S_max, Hkv * hd]``
+    every layer of every step (PERF.md, PR 25). Temporaries as a whole
+    cannot be held under one layer's cache: dequantised weights are
+    temporaries too, and larger."""
+    cache_k = engine._state["cache"]["k"]
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding),
+        ((engine.params, engine.lora), engine._state),
+    )
+    compiled = engine._decode_fn.lower(*shapes).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = 2 * cache_k.nbytes
+    if mem.alias_size_in_bytes < cache_bytes:
+        raise AssertionError(
+            f"the decode chunk aliases {mem.alias_size_in_bytes} bytes of its "
+            f"donated state; the cache alone is {cache_bytes}"
+        )
+    if mem.temp_size_in_bytes >= cache_bytes:
+        raise AssertionError(
+            f"the decode chunk's temporaries ({mem.temp_size_in_bytes} bytes) "
+            f"could hold a second cache ({cache_bytes})"
+        )
+    copies = cache_layer_copies(compiled.as_text(), cache_k)
+    if copies:
+        raise AssertionError(
+            f"the decode chunk materialises a layer of the cache "
+            f"{cache_k.shape}: {copies[:3]}"
+        )
+    return {
+        "decode_chunk_temp_bytes": mem.temp_size_in_bytes,
+        "cache_layer_bytes": cache_bytes // cache_k.shape[0],
+        "cache_aliased_bytes": mem.alias_size_in_bytes,
+    }
 
 
 def _documents(vocab: int, n_tokens: int, seed: int):
@@ -377,6 +483,7 @@ def serve_leg(cfg) -> dict:
             str(d) for d in engine._state["cache"]["k"].devices()
         )
         return {
+            **assert_decode_reads_cache_in_place(engine),
             "requests": len(bodies) + 1,
             "prompt_lengths": [len(p) for p in prompts] + [len(twin)] * 2,
             "tokens_each": max_tokens,

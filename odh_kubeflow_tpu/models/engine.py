@@ -38,6 +38,7 @@ from typing import Any, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from odh_kubeflow_tpu.models.generate import family_forward, init_cache
 from odh_kubeflow_tpu.models.llama import LlamaConfig
@@ -373,18 +374,24 @@ class DecodeEngine:
 
         cache_cfg, self._fwd = family_forward(cfg)
         S = n_slots
+        # the per-slot control vectors are made on the host and put on
+        # the device: a jnp.zeros per shape and dtype is a program of
+        # its own to trace, lower and load before the first request
+        control = {
+            "kv_mask": np.zeros((S, max_len), bool),
+            "cur_token": np.zeros((S,), np.int32),
+            "write_idx": np.zeros((S,), np.int32),
+            "pos": np.zeros((S,), np.int32),
+            "active": np.zeros((S,), bool),
+            "remaining": np.zeros((S,), np.int32),
+            "temp": np.zeros((S,), np.float32),
+            "top_k": np.zeros((S,), np.int32),
+            "top_p": np.zeros((S,), np.float32),
+            "eos": np.full((S,), -1, np.int32),
+        }
         self._state = {
             "cache": init_cache(cache_cfg, S, max_len, cache_dtype),
-            "kv_mask": jnp.zeros((S, max_len), bool),
-            "cur_token": jnp.zeros((S,), jnp.int32),
-            "write_idx": jnp.zeros((S,), jnp.int32),
-            "pos": jnp.zeros((S,), jnp.int32),
-            "active": jnp.zeros((S,), bool),
-            "remaining": jnp.zeros((S,), jnp.int32),
-            "temp": jnp.zeros((S,), jnp.float32),
-            "top_k": jnp.zeros((S,), jnp.int32),
-            "top_p": jnp.zeros((S,), jnp.float32),
-            "eos": jnp.full((S,), -1, jnp.int32),
+            **jax.device_put(control),
             "rng": jax.random.key(seed),
         }
         if draft_params is not None:
@@ -487,7 +494,7 @@ class DecodeEngine:
         st["rng"] = rng
         st["cache"] = {
             kv: jax.lax.dynamic_update_slice(
-                state["cache"][kv], sub_cache[kv], (0, slot, 0, 0, 0)
+                state["cache"][kv], sub_cache[kv], (0, slot, 0, 0)
             )
             for kv in ("k", "v")
         }
@@ -526,8 +533,6 @@ class DecodeEngine:
 
     @staticmethod
     def pack_admission(prompt, pad_id, bucket, req):
-        import numpy as np
-
         meta = np.asarray(
             [
                 len(prompt), 0, req.max_tokens, req.top_k, req.eos_id,
@@ -683,7 +688,7 @@ class DecodeEngine:
         bucket: int,
     ):
         """Prefill with a cached prefix: ``prefix_kv`` (k/v
-        [L, 1, plen, Hkv, hd], a prefix-cache entry) seeds the slot's
+        [L, 1, plen, Hkv * hd], a prefix-cache entry) seeds the slot's
         cache and only the remainder tokens run through the model, at
         positions/cache offset ``plen`` (static — one compile per
         (prefix bucket, remainder bucket))."""
@@ -729,7 +734,7 @@ class DecodeEngine:
         st = dict(state)
         st["dcache"] = {
             kv: jax.lax.dynamic_update_slice(
-                state["dcache"][kv], sub[kv], (0, slot, 0, 0, 0)
+                state["dcache"][kv], sub[kv], (0, slot, 0, 0)
             )
             for kv in ("k", "v")
         }

@@ -7,7 +7,7 @@ it. Design is TPU-first:
 
 - **Two compiles total.** Prefill (S = prompt length) and the decode
   step (S = 1) are the only two traced shapes; the decode loop is a
-  ``lax.scan`` over a preallocated ``[L, B, S_max, Hkv, hd]`` cache, so
+  ``lax.scan`` over a preallocated ``[L, B, S_max, Hkv * hd]`` cache, so
   there are no per-step retraces and no dynamic shapes anywhere.
 - **Physical vs logical positions.** Ragged (right-padded) prompts
   share one physical write index — slot ``prompt_pad + step`` — while
@@ -57,30 +57,29 @@ class GenerateConfig:
 def init_cache(
     cfg: LlamaConfig, batch_size: int, max_len: int, dtype=jnp.bfloat16
 ) -> Params:
-    """Preallocated KV cache: ``{"k","v"}: [L, B, S_max, Hkv, hd]``.
+    """Preallocated KV cache: ``{"k","v"}: [L, B, S_max, Hkv * hd]``.
 
-    The leading layer axis is consumed by the ``lax.scan`` over layers
-    in ``forward_with_cache`` (one slice per step), mirroring the
-    stacked parameter layout.
+    The whole stack is the CARRY of the ``lax.scan`` over layers in
+    ``forward_with_cache`` (``llama.scan_layers_with_cache``): a step
+    writes its tokens at ``[layer, row, index]`` and attention reads the
+    layer where it lies; no layer is ever sliced out. A position's KV
+    heads lie side by side in one row of ``Hkv * hd`` lanes, so a head
+    is a lane slice of a ``[positions, Hkv * hd]`` tile (what
+    ``ops/pallas_decode_attention.py`` walks) and a token's write is one
+    contiguous row.
     """
-    shape = (
-        cfg.num_layers,
-        batch_size,
-        max_len,
-        cfg.num_kv_heads,
-        cfg.head_dim,
-    )
+    shape = (cfg.num_layers, batch_size, max_len, cfg.kv_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 def cache_specs(cfg: LlamaConfig) -> Params:
     """PartitionSpec tree for ``init_cache`` output.
 
-    Batch shards with the data axes; KV heads shard on tensor (they are
-    produced by tensor-sharded wk/wv projections, so the cache write is
-    collective-free).
+    Batch shards with the data axes; KV heads shard on tensor (whole
+    heads: a shard of the ``Hkv * hd`` axis is what the tensor-sharded
+    wk/wv projections produce, so the cache write is collective-free).
     """
-    s = P(None, (AXIS_DATA, AXIS_FSDP), None, AXIS_TENSOR, None)
+    s = P(None, (AXIS_DATA, AXIS_FSDP), None, AXIS_TENSOR)
     return {"k": s, "v": s}
 
 

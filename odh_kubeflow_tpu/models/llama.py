@@ -347,21 +347,24 @@ def _decoder_layer(
     sin: jnp.ndarray,
     cos: jnp.ndarray,
     segment_ids,
-    cache_layer=None,  # {"k","v"}: [B, S_max, Hkv, hd] slices, or None
-    cache_index=None,  # scalar: write offset into the cache
+    cache=None,  # {"k","v"}: the STACKED [L, B, S_max, Hkv * hd], or None
+    layer_index=None,  # scalar: which layer of ``cache`` this is
+    cache_index=None,  # scalar or [B]: write offset into the cache
     kv_mask=None,  # [B, S_max] bool: which cache slots are valid
 ):
-    """Returns ``(x, updated_cache_layer)``.
+    """Returns ``(x, updated_cache)``.
 
-    ``updated_cache_layer`` is None on the training path; on the
-    KV-cache decode path (``models/generate.py``) it is the
-    ``{"k","v"}`` dict with this step's keys/values written at
-    ``cache_index``. The cache path always attends with
-    ``dense_attention`` — decode attention is a bandwidth-bound gather
-    over the cache where a traced ``cache_index``/``q_offset`` is
-    required (the flash kernel needs it static and ring attention has
-    no cache semantics); ``attention_fn`` only selects the *training*
-    (no-cache) implementation.
+    ``updated_cache`` is None on the training path; on the KV-cache
+    decode path (``models/generate.py``) it is the whole stacked
+    ``{"k","v"}`` with this step's keys/values written at
+    ``[layer_index, :, cache_index]`` (``cache_write_and_attend``: the
+    layer is never sliced out). The cache path attends with
+    ``dense_attention``'s semantics (``decode_attend`` on the TPU) —
+    decode attention is a bandwidth-bound gather over the cache where a
+    traced ``cache_index``/``q_offset`` is required (the flash kernel
+    needs it static and ring attention has no cache semantics);
+    ``attention_fn`` only selects the *training* (no-cache)
+    implementation.
     """
     B, S, D = x.shape
     x = constrain(x, _activation_spec())
@@ -373,7 +376,7 @@ def _decoder_layer(
     # This is what lets an 8B QLoRA fine-tune fit a single 16GiB v5e.
     # Under w8a8_decode (cache path only), int8 matmul weights skip
     # dequant entirely — _maybe_lora runs them on the int8 MXU.
-    keep = cache_layer is not None and cfg.w8a8_decode
+    keep = cache is not None and cfg.w8a8_decode
     with jax.named_scope("dequant"):
         layer = _maybe_dequant(layer, cfg.dtype, keep_int8_matmuls=keep)
 
@@ -394,9 +397,9 @@ def _decoder_layer(
     q = _checkpoint_name(q, "q_rope")
     kk = _checkpoint_name(kk, "k_rope")
     vv = _checkpoint_name(vv, "v_proj")
-    if cache_layer is not None:
-        attn, cache_layer = cache_write_and_attend(
-            q, kk, vv, cache_layer, cache_index, kv_mask
+    if cache is not None:
+        attn, cache = cache_write_and_attend(
+            q, kk, vv, cache, layer_index, cache_index, kv_mask
         )
     else:
         with jax.named_scope("attention"):
@@ -421,69 +424,132 @@ def _decoder_layer(
     # the policy exists for (see _make_layer_fn)
     gate = _checkpoint_name(gate, "mlp_g")
     x = x + _maybe_lora("w_down", jax.nn.silu(gate) * up, layer["w_down"], lora_layer)
-    return x, cache_layer
+    return x, cache
 
 
 def cache_write_and_attend(
     q,  # [B, S, Hq, hd]
     kk,  # [B, S, Hkv, hd] this step's keys
     vv,
-    cache_layer,  # {"k","v"}: [B, S_max, Hkv, hd]
+    cache,  # {"k","v"}: the stacked [L, B, S_max, Hkv * hd]
+    layer_index,  # scalar int32: the layer being run
     cache_index,  # scalar int32, or [B] int32 (per-row offsets)
     kv_mask,  # [B, S_max] bool or None
 ):
-    """Append this step's K/V at ``cache_index`` and attend over the
-    whole cache with absolute positions (``kv_mask``/``q_offset`` mask
-    the unwritten tail). Shared by the dense and MoE cached layers.
+    """Append this step's K/V at ``[layer_index, :, cache_index]`` of
+    the stacked cache and attend over that layer with absolute
+    positions (``kv_mask``/``q_offset`` mask the unwritten tail).
+    Shared by the dense and MoE cached layers.
+
+    The stack is the layer scan's CARRY (``scan_layers_with_cache``):
+    the write touches S rows of it in place and the read takes the
+    layer where it lies, so no step copies a layer's cache. Returns
+    ``(attn, cache)``.
 
     A scalar ``cache_index`` is the classic generate() layout: every
     row writes at the same physical offset (ragged prompts pad to a
     shared index). A **[B] vector** is the continuous-batching engine's
     layout (``models/engine.py``): each batch slot sits at its own
-    depth, so writes scatter per-row — S must be 1 on that path.
+    depth, so writes scatter per-row.
     """
+    B, S, Hkv, hd = kk.shape
     with jax.named_scope("kv_cache_write"):
+        new = {"k": kk.reshape(B, S, Hkv * hd), "v": vv.reshape(B, S, Hkv * hd)}
         if getattr(cache_index, "ndim", 0) == 1:
-            B, S = q.shape[0], q.shape[1]
             rows = jnp.arange(B)
             if S == 1:
-                ck = cache_layer["k"].at[rows, cache_index].set(
-                    kk[:, 0].astype(cache_layer["k"].dtype)
-                )
-                cv = cache_layer["v"].at[rows, cache_index].set(
-                    vv[:, 0].astype(cache_layer["v"].dtype)
-                )
+                at = (layer_index, rows, cache_index)
+                new = {kv: x[:, 0] for kv, x in new.items()}
             else:
                 # per-row offsets with a multi-token window — the engine's
                 # speculative verify (k+1 tokens per slot, each slot at its
                 # own depth). Clamp keeps ragged slots in bounds; the
                 # engine's kv_mask excludes anything beyond the real window.
-                S_max = cache_layer["k"].shape[1]
+                S_max = cache["k"].shape[2]
                 cols = jnp.clip(
                     cache_index[:, None] + jnp.arange(S)[None, :], 0, S_max - 1
                 )
-                ck = cache_layer["k"].at[rows[:, None], cols].set(
-                    kk.astype(cache_layer["k"].dtype)
-                )
-                cv = cache_layer["v"].at[rows[:, None], cols].set(
-                    vv.astype(cache_layer["v"].dtype)
-                )
+                at = (layer_index, rows[:, None], cols)
+            cache = {
+                kv: cache[kv].at[at].set(new[kv].astype(cache[kv].dtype))
+                for kv in ("k", "v")
+            }
         else:
-            ck = jax.lax.dynamic_update_slice(
-                cache_layer["k"],
-                kk.astype(cache_layer["k"].dtype),
-                (0, cache_index, 0, 0),
-            )
-            cv = jax.lax.dynamic_update_slice(
-                cache_layer["v"],
-                vv.astype(cache_layer["v"].dtype),
-                (0, cache_index, 0, 0),
-            )
+            cache = {
+                kv: jax.lax.dynamic_update_slice(
+                    cache[kv],
+                    new[kv][None].astype(cache[kv].dtype),
+                    (layer_index, 0, cache_index, 0),
+                )
+                for kv in ("k", "v")
+            }
     with jax.named_scope("kv_cache_read"):
-        attn = dense_attention(
-            q, ck, cv, causal=True, q_offset=cache_index, kv_mask=kv_mask
-        )
-    return attn, {"k": ck, "v": cv}
+        if _reads_cache_in_place(cache["k"], hd):
+            from odh_kubeflow_tpu.ops.pallas_decode_attention import (
+                decode_attend,
+            )
+
+            attn = decode_attend(
+                q, cache["k"], cache["v"], layer_index, cache_index, kv_mask
+            )
+        else:
+            ck, cv = (
+                jax.lax.dynamic_index_in_dim(
+                    cache[kv], layer_index, 0, keepdims=False
+                ).reshape(B, -1, Hkv, hd)
+                for kv in ("k", "v")
+            )
+            attn = dense_attention(
+                q, ck, cv, causal=True, q_offset=cache_index, kv_mask=kv_mask
+            )
+    return attn, cache
+
+
+def _reads_cache_in_place(cache_leaf, head_dim: int) -> bool:
+    """Whether attention reads the layer from the stack through the
+    Pallas kernel (``ops/pallas_decode_attention.py``) or through
+    ``dense_attention`` on a slice of it.
+
+    The kernel is the TPU's read: XLA cannot hand a layer of the carried
+    stack to the attention dots without first copying it out (a
+    ``dynamic-slice`` of the layer's whole K and V; PERF.md, PR 25).
+    The dense read stays where the kernel cannot go, as for flash
+    (``resolved_attention_impl``): off the TPU (interpret mode is slow
+    ordinary ops), under a multi-device mesh (GSPMD cannot partition a
+    Mosaic call, ``_flash_per_shard``; the slice there is a shard's), and
+    for a cache whose shape has no whole tiles."""
+    from odh_kubeflow_tpu.ops import pallas_decode_attention
+
+    am = jax.sharding.get_abstract_mesh()
+    return (
+        jax.default_backend() == "tpu"
+        and (am.empty or am.size == 1)
+        and pallas_decode_attention.supported(cache_leaf, head_dim)
+    )
+
+
+def scan_layers_with_cache(layer_fn, x, layers, lora_layers, cache):
+    """The one scan over layers that has a KV cache (dense and MoE).
+
+    ``layer_fn(x, layer, lora_layer, cache, layer_index) -> (x, cache)``
+    runs one layer; it hands ``cache`` and ``layer_index`` to
+    ``cache_write_and_attend``. The stacked cache rides the scan as its
+    carry beside ``x``, and only the weights, adapters and the layer's
+    index are scanned: as a scanned input and output XLA would slice
+    every layer's whole cache out of the stack and write it back, each
+    layer of each step (PERF.md, PR 25). With the caller's buffer
+    donated the stack is updated in place."""
+
+    def body(carry, scanned):
+        x, cache = carry
+        layer_index, layer, lora_layer = scanned
+        return layer_fn(x, layer, lora_layer, cache, layer_index), None
+
+    layer_ids = jnp.arange(cache["k"].shape[0], dtype=jnp.int32)
+    (x, cache), _ = jax.lax.scan(
+        body, (x, cache), (layer_ids, layers, lora_layers)
+    )
+    return x, cache
 
 
 def resolved_attention_impl(cfg: LlamaConfig) -> str:
@@ -886,8 +952,8 @@ def forward_with_cache(
     params: Params,
     tokens: jnp.ndarray,  # [B, S] int32 (S=prompt len for prefill, 1 for decode)
     cfg: LlamaConfig,
-    cache: Params,  # {"k","v"}: [L, B, S_max, Hkv, hd]
-    cache_index,  # scalar int32: write offset into the cache
+    cache: Params,  # {"k","v"}: [L, B, S_max, Hkv * hd]
+    cache_index,  # scalar int32, or [B] int32: write offset into the cache
     *,
     positions: jnp.ndarray,  # [B, S] absolute positions (rope)
     kv_mask: Optional[jnp.ndarray] = None,  # [B, S_max] valid cache slots
@@ -902,36 +968,37 @@ def forward_with_cache(
     prefill (S = prompt length, cache_index = 0) and autoregressive
     steps (S = 1) go through here, so the layer stack compiles exactly
     twice per shape. No remat (there is no backward pass to trade
-    FLOPs against) and always dense attention over the cache (see
-    ``_decoder_layer``).
+    FLOPs against) and always attention over the cache with a traced
+    offset (``cache_write_and_attend``). The cache is the layer scan's
+    carry (``scan_layers_with_cache``): donate it and it is updated in
+    place.
     """
     sin, cos = rope_angles(positions, cfg.head_dim, cfg.rope_theta)
     x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
     lora_layers = lora["layers"] if lora is not None else None
 
-    def body(x, scanned):
-        layer, lora_layer, cache_layer = scanned
+    def layer_fn(x, layer, lora_layer, cache, layer_index):
         # int8-quantized weights (models/quant.py) dequantize inside
         # _decoder_layer: only the current layer's bf16 copy ever
         # materialises, so an 8B model serves from ~8GB of int8 on one
         # v5e instead of 16GB of bf16 that wouldn't fit.
-        x, new_cache = _decoder_layer(
+        return _decoder_layer(
             cfg,
-            None,  # attention_fn unused: cache path is always dense
+            None,  # attention_fn unused: the cache path has its own read
             x,
             layer,
             lora_layer,
             sin,
             cos,
             None,
-            cache_layer=cache_layer,
+            cache=cache,
+            layer_index=layer_index,
             cache_index=cache_index,
             kv_mask=kv_mask,
         )
-        return x, new_cache
 
-    x, new_cache = jax.lax.scan(
-        body, x, (params["layers"], lora_layers, cache)
+    x, new_cache = scan_layers_with_cache(
+        layer_fn, x, params["layers"], lora_layers, cache
     )
 
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)

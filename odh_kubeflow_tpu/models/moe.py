@@ -1334,8 +1334,8 @@ def forward_with_cache(
     params: Params,
     tokens: jnp.ndarray,  # [B, S] int32
     cfg: MoeConfig,
-    cache: Params,  # {"k","v"}: [L, B, S_max, Hkv, hd]
-    cache_index,  # scalar int32 write offset
+    cache: Params,  # {"k","v"}: [L, B, S_max, Hkv * hd]
+    cache_index,  # scalar int32, or [B] int32: write offset
     *,
     positions: jnp.ndarray,  # [B, S]
     kv_mask: Optional[jnp.ndarray] = None,
@@ -1344,8 +1344,9 @@ def forward_with_cache(
 ) -> tuple[jnp.ndarray, Params]:
     """KV-cached MoE forward (the ``models/generate.py`` decode path).
 
-    Attention is identical to the dense family's cache path (dense
-    attention over the cache with a traced write offset); the MLP is
+    Attention is the dense family's cache path (``llama.
+    cache_write_and_attend`` inside ``llama.scan_layers_with_cache``:
+    the stacked cache is the layer scan's carry); the MLP is
     the router+experts. Routing a 1-token decode step degenerates to
     capacity-1 per expert, which top-k's distinct choices always fit.
     int8-quantized trees (``models/quant.py``) dequantize per layer
@@ -1370,8 +1371,7 @@ def forward_with_cache(
             kv_mask[:, :S] if (kv_mask is not None and S > 1) else None
         )
 
-    def body(x, scanned):
-        layer, lora_layer, cache_layer = scanned
+    def layer_fn(x, layer, lora_layer, cache, layer_index):
         layer = llama._maybe_dequant(layer, b.dtype)
         h = rms_norm(x, layer["attn_norm"], b.rms_norm_eps)
         q = llama._maybe_lora("wq", h, layer["wq"], lora_layer).reshape(
@@ -1385,17 +1385,17 @@ def forward_with_cache(
         )
         q = llama.apply_rope(q, sin, cos)
         k = llama.apply_rope(k, sin, cos)
-        attn, new_cache_layer = llama.cache_write_and_attend(
-            q, k, v, cache_layer, cache_index, kv_mask
+        attn, cache = llama.cache_write_and_attend(
+            q, k, v, cache, layer_index, cache_index, kv_mask
         )
         attn = attn.reshape(B, S, b.q_dim)
         x = x + llama._maybe_lora("wo", attn, layer["wo"], lora_layer)
         h = rms_norm(x, layer["mlp_norm"], b.rms_norm_eps)
         moe_out, _aux = moe_mlp(h, layer, cfg, token_mask=token_mask)
-        return x + moe_out, new_cache_layer
+        return x + moe_out, cache
 
-    x, new_cache = jax.lax.scan(
-        body, x, (params["layers"], lora_layers, cache)
+    x, new_cache = llama.scan_layers_with_cache(
+        layer_fn, x, params["layers"], lora_layers, cache
     )
     x = rms_norm(x, params["final_norm"], b.rms_norm_eps)
     head = llama.lm_head_weight(params, b)  # dequantizes int8 lm_head

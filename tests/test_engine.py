@@ -454,3 +454,27 @@ def test_engine_under_mesh_greedy_exact(model, devices8):
         assert got == want, (got, want)
     finally:
         engine.stop()
+
+
+@pytest.mark.parametrize("greedy", [False, True], ids=["sampling", "greedy"])
+def test_decode_chunk_updates_the_donated_cache_in_place(model, greedy):
+    """The decode chunk's executable aliases the donated cache to the
+    cache it returns: what comes back that is NOT an alias of an input
+    is smaller than one of the cache's two arrays."""
+    cfg, params = model
+    engine = DecodeEngine(
+        params, cfg, n_slots=4, max_len=64, chunk=4,
+        prompt_buckets=(16,), cache_dtype=jnp.float32,
+    )
+    try:
+        fn = engine._decode_greedy_fn if greedy else engine._decode_fn
+        mem = fn.lower(
+            (engine.params, engine.lora), engine._state
+        ).compile().memory_analysis()
+        cache_k = engine._state["cache"]["k"]
+        assert mem.alias_size_in_bytes >= 2 * cache_k.nbytes
+        assert (
+            mem.output_size_in_bytes - mem.alias_size_in_bytes < cache_k.nbytes
+        )
+    finally:
+        engine.stop()

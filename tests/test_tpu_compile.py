@@ -1,0 +1,114 @@
+"""The cached forward's TPU read, compiled here for a described v5e (no
+chip): Mosaic refuses what interpret mode lets through (tiling, scoped
+VMEM), and the compiled decode step shows whether a layer's cache is
+still copied out of the stack. One file, one fixture: only the worker
+that runs it loads the TPU's compiler (on-chip-measurement guide)."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from odh_kubeflow_tpu.models import LlamaConfig, forward_with_cache, init_cache
+from odh_kubeflow_tpu.models import llama
+from odh_kubeflow_tpu.ops.pallas_decode_attention import decode_attend
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no compiler here: nothing to test
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _on(chip, tree):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), tree
+    )
+
+
+# the serving cell (Mistral-7B: 32 / 8 heads x 128, 16 slots x 2048) as
+# the engine calls the read, and Llama-3.2-1B's heads of 64
+@pytest.mark.parametrize(
+    "B,S,hd,vector",
+    [(16, 1, 128, True), (16, 5, 128, True), (1, 1024, 128, False),
+     (4, 1, 64, True)],
+    ids=["decode", "window", "prefill1024", "decode-hd64"],
+)
+def test_decode_attend_compiles_at_real_widths(one_chip, B, S, hd, vector):
+    L, S_max, Hq, Hkv = 32, 2048, 32, 8
+    q = jax.ShapeDtypeStruct((B, S, Hq, hd), jnp.bfloat16)
+    cache = jax.ShapeDtypeStruct((L, B, S_max, Hkv * hd), jnp.bfloat16)
+    index = jax.ShapeDtypeStruct((B,) if vector else (), jnp.int32)
+    compiled = jax.jit(decode_attend).lower(
+        *_on(one_chip, (q, cache, cache, jax.ShapeDtypeStruct((), jnp.int32),
+                        index, jax.ShapeDtypeStruct((B, S_max), jnp.bool_)))
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the stack goes to the kernel as it is: no copy of a layer beside it
+    assert compiled.memory_analysis().temp_size_in_bytes < cache.size // L
+
+
+@pytest.mark.parametrize("in_place", [True, False], ids=["kernel", "dense"])
+def test_decode_step_copies_no_layer_of_the_cache(one_chip, monkeypatch, in_place):
+    """A decode step of two Mistral-wide layers, compiled for the chip
+    with the read the TPU takes: the stack is updated in place (aliased),
+    and no instruction produces a layer's whole keys or values. With the
+    dense read (what the CPU and a mesh take) XLA does copy the layer
+    out, and the smoke's detector has to see it."""
+    # the backend here is the CPU; the test, not the program, steers
+    monkeypatch.setattr(
+        llama, "_reads_cache_in_place", lambda leaf, hd: in_place
+    )
+    cfg = LlamaConfig(
+        vocab_size=32768, hidden_size=4096, intermediate_size=14336,
+        num_layers=2, num_heads=32, num_kv_heads=8, head_dim=128,
+        dtype=jnp.bfloat16, tie_embeddings=False,
+    )
+    B, S_max = 16, 2048
+    params = jax.eval_shape(
+        lambda: llama.init_params(jax.random.key(0), cfg, jnp.bfloat16)
+    )
+    cache = jax.eval_shape(lambda: init_cache(cfg, B, S_max))
+
+    def step(params, cache, tokens, index, kv_mask):
+        return forward_with_cache(
+            params, tokens, cfg, cache, index, positions=index[:, None],
+            kv_mask=kv_mask,
+        )
+
+    compiled = jax.jit(step, donate_argnums=1).lower(
+        *_on(one_chip, (
+            params, cache, jax.ShapeDtypeStruct((B, 1), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.int32),
+            jax.ShapeDtypeStruct((B, S_max), jnp.bool_),
+        ))
+    ).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = 2 * cache["k"].size * 2
+    assert mem.alias_size_in_bytes >= cache_bytes
+    text = compiled.as_text()
+    assert ("decode_attend" in text) is in_place
+    # the detector the chip's smoke holds the engine's decode chunk to
+    copies = _chip_smoke().cache_layer_copies(text, cache["k"])
+    assert (not copies) is in_place, copies[:3]
+
+
+def _chip_smoke():
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(__file__)), "chip_smoke.py")
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
