@@ -162,6 +162,7 @@ def check_kernels(cfg) -> dict:
                     f"relative error {err}"
                 )
     errs.update(check_decode_attend(cfg))
+    errs.update(check_ring_and_held_experts())
     return {"rel_err_vs_dense": errs}
 
 
@@ -207,6 +208,68 @@ def check_decode_attend(cfg) -> dict:
                 f"decode_attend {name} disagrees with dense_attention: "
                 f"relative error {err}"
             )
+    return errs
+
+
+def check_ring_and_held_experts() -> dict:
+    """What a windowed mixture adds to the cached forward (PR 26), each
+    against its plain form: ``decode_attend`` over a RING (a window
+    layer's cache, wrapped) against ``dense_attention`` over the same
+    keys laid out by position, and ``moe_local_ffn`` (held experts read
+    from the stacked int8 banks) against plain dots on the dequantised
+    layer. Same bound as flash."""
+    from odh_kubeflow_tpu.models import moe
+    from odh_kubeflow_tpu.ops.attention import dense_attention
+    from odh_kubeflow_tpu.ops.pallas_decode_attention import (
+        decode_attend,
+        slot_positions,
+    )
+
+    errs = {}
+    L, B, ring, window, Hq, Hkv, hd = 2, 4, 1536, 1024, 32, 8, 128
+    index = jnp.asarray([40, 1500, 4000, 9000], jnp.int32)
+    last = int(index.max()) + 1
+    kq, kk, kv = jax.random.split(jax.random.key(13), 3)
+    q = jax.random.normal(kq, (B, 1, Hq, hd), jnp.bfloat16)
+    by_pos = [
+        jax.random.normal(k, (L, B, last, Hkv * hd), jnp.bfloat16)
+        for k in (kk, kv)
+    ]
+    held = jnp.clip(slot_positions(index, 1, ring), 0, last - 1)
+    stack = [a[:, jnp.arange(B)[:, None], held] for a in by_pos]
+    got = decode_attend(q, *stack, jnp.int32(1), index, None, window=window)
+    want = dense_attention(
+        q, *(a[1].reshape(B, last, Hkv, hd) for a in by_pos), causal=True,
+        q_offset=index, window=window,
+    )
+    errs["decode_attend.ring"] = round(_rel_err(got, want), 5)
+
+    E, D, F, k = 4, 1024, 1024, 2
+    keys = jax.random.split(jax.random.key(17), 6)
+
+    def bank(key, shape, fan_in):
+        w = jax.random.normal(key, shape, jnp.float32) * fan_in**-0.5
+        scale = jnp.max(jnp.abs(w), axis=-2, keepdims=True) / 127.0
+        return {"q": jnp.round(w / scale).astype(jnp.int8), "scale": scale}
+
+    banks = {
+        "moe_gate": bank(keys[0], (L, E, D, F), D),
+        "moe_up": bank(keys[1], (L, E, D, F), D),
+        "moe_down": bank(keys[2], (L, E, F, D), F),
+    }
+    for name, T in (("decode", 16), ("part", 512)):
+        h = jax.random.normal(keys[3], (T, D), jnp.bfloat16)
+        w, idx = moe.route_sigmoid_topk(
+            jax.random.normal(keys[4], (T, 16), jnp.float32), k
+        )
+        args = (h, w, idx, banks, jnp.int32(1), (8, E))
+        kernel, stats = moe.local_expert_ffn(*args, in_place=True)
+        plain, _ = moe.local_expert_ffn(*args, in_place=False)
+        errs[f"moe_local_ffn.{name}"] = round(_rel_err(kernel, plain), 5)
+        assert int(stats[2]) == 0, stats
+    for name, err in errs.items():
+        if not err < 5e-2:
+            raise AssertionError(f"{name}: relative error {err}")
     return errs
 
 

@@ -29,6 +29,7 @@ inference path); the design is the standard TPU serving pattern
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import queue
@@ -40,7 +41,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from odh_kubeflow_tpu.models.generate import family_forward, init_cache
+from odh_kubeflow_tpu.models.generate import (
+    cache_bytes,
+    family_forward,
+    init_cache,
+)
 from odh_kubeflow_tpu.models.llama import LlamaConfig
 from odh_kubeflow_tpu.utils import prometheus, tracing
 from odh_kubeflow_tpu.utils.profiling import hot_span
@@ -202,6 +207,18 @@ class _Request:
             raise self.error
 
 
+def _splice_slot(cache: Params, sub_cache: Params, slot) -> Params:
+    """A batch-1 cache written into row ``slot`` of the slots' cache,
+    every stack of every kind of layer (``generate.init_cache``); what
+    is not a stack (a call's counters) stays the slots' own."""
+    return {
+        name: jax.lax.dynamic_update_slice(
+            leaf, sub_cache[name], (0, slot, 0, 0)
+        ) if leaf.ndim == 4 else leaf
+        for name, leaf in cache.items()
+    }
+
+
 def _program(fn, name: str, **static):
     """``fn`` with ``static`` bound, under ``name``: jax names a jitted
     program after its function, and a bare ``functools.partial`` has no
@@ -333,6 +350,12 @@ class DecodeEngine:
         # in-flight chunked admission (one at a time): dict with req /
         # slot / sub(cache) / consumed / had_prefix
         self._admitting: Optional[dict] = None
+        # prompts that need the part-by-part lane while it is taken, in
+        # arrival order: they hold no slot, and shorter prompts behind
+        # them go on to the free slots meanwhile (a 12288-token prompt
+        # keeps the lane for six loop turns; without this every slot
+        # that frees in that time stands empty; PERF.md, PR 26)
+        self._held: "collections.deque[_Request]" = collections.deque()
         # prompt-prefix KV reuse: entries keyed on the token tuple of a
         # bucketed prefix; admission with a hit prefills only the
         # remainder (a shared system prompt stops being re-prefilled
@@ -373,6 +396,15 @@ class DecodeEngine:
         self._mesh = mesh
 
         cache_cfg, self._fwd = family_forward(cfg)
+        self._cache_dtype = cache_dtype
+        # the most positions one call writes into a slot before it
+        # attends: what a window layer's ring holds beyond its window
+        # (``init_cache``). Parts are written at multiples of their
+        # width from 0, so they never straddle a ring's end as long as
+        # their width divides it
+        self._widest_part = max(
+            self.prompt_buckets + ((prefill_chunk,) if prefill_chunk else ())
+        )
         S = n_slots
         # the per-slot control vectors are made on the host and put on
         # the device: a jnp.zeros per shape and dtype is a program of
@@ -390,15 +422,13 @@ class DecodeEngine:
             "eos": np.full((S,), -1, np.int32),
         }
         self._state = {
-            "cache": init_cache(cache_cfg, S, max_len, cache_dtype),
+            "cache": self._new_cache(cache_cfg, S),
             **jax.device_put(control),
             "rng": jax.random.key(seed),
         }
         if draft_params is not None:
             dcache_cfg, _ = family_forward(draft_cfg)
-            self._state["dcache"] = init_cache(
-                dcache_cfg, S, max_len, cache_dtype
-            )
+            self._state["dcache"] = self._new_cache(dcache_cfg, S)
         if mesh is not None:
             from jax.sharding import NamedSharding
 
@@ -418,6 +448,34 @@ class DecodeEngine:
                 )
                 for k, v in self._state.items()
             }
+        # the cache by kind of layer, and what the kinds ask of the
+        # engine's own indexing
+        self.cache_bytes = cache_bytes(self._state["cache"])
+        rings = {
+            leaf.shape[2] for leaf in self._state["cache"].values()
+            if leaf.ndim == 4 and leaf.shape[2] != max_len
+        }
+        if rings and (
+            prefix_cache_entries or any(r % self._widest_part for r in rings)
+        ):
+            raise NotImplementedError(
+                "a windowed cache keeps rings: a prefix entry cannot be "
+                "cut from one, and every prompt bucket and the prefill "
+                f"chunk must divide the ring ({sorted(rings)})"
+            )
+        # counters of what the cached forward adds for a mixture of
+        # held experts and for window layers (PERF.md section 3): read
+        # with each decode chunk's own fetch
+        self.moe_local_assignments = 0
+        self.moe_experts_hit = 0
+        self.moe_dropped = 0
+        self.window_blocks_skipped = 0
+        windows = getattr(cache_cfg, "layer_windows", (None,))
+        # {window: how many layers of the whole stack have it}
+        self._window_layers = collections.Counter(
+            [w for w in windows if w is not None]
+            * (cache_cfg.num_layers // len(windows))
+        )
         # serving SLO metrics (arXiv:2605.25645's TTFT/TPOT surface):
         # the same registry the platform scrapes at /metrics
         reg = metrics_registry or prometheus.default_registry
@@ -482,6 +540,14 @@ class DecodeEngine:
         self._thread = threading.Thread(target=self._loop, daemon=True)
         self._thread.start()
 
+    def _new_cache(self, cache_cfg, batch: int) -> Params:
+        """The one allocator of KV cache: the slots' and, batch 1, an
+        admission's, of the same kinds and rings."""
+        return init_cache(
+            cache_cfg, batch, self.max_len, self._cache_dtype,
+            widest_part=self._widest_part,
+        )
+
     # -- jitted programs ----------------------------------------------------
 
     def _write_slot_state(self, state, sub_cache, kv_mask1, slot, first,
@@ -492,12 +558,7 @@ class DecodeEngine:
         max_tokens, temp, top_k, top_p, eos = req_vec
         st = dict(state)
         st["rng"] = rng
-        st["cache"] = {
-            kv: jax.lax.dynamic_update_slice(
-                state["cache"][kv], sub_cache[kv], (0, slot, 0, 0)
-            )
-            for kv in ("k", "v")
-        }
+        st["cache"] = _splice_slot(state["cache"], sub_cache, slot)
         st["kv_mask"] = jax.lax.dynamic_update_slice(
             state["kv_mask"], kv_mask1, (slot, 0)
         )
@@ -594,9 +655,7 @@ class DecodeEngine:
         """Prefill one whole prompt (batch 1, ``bucket`` wide) into the
         slot carried in ``packed`` (see ``_unpack_admission``)."""
         cache_cfg, _ = family_forward(self.cfg)
-        sub_cache = init_cache(
-            cache_cfg, 1, self.max_len, state["cache"]["k"].dtype
-        )
+        sub_cache = self._new_cache(cache_cfg, 1)
         return self._prefill_tail(
             params, lora, state, sub_cache, packed, jnp.int32(0),
             bucket=bucket,
@@ -622,6 +681,12 @@ class DecodeEngine:
 
     def _decode_chunk(self, params_lora, state, *, greedy: bool = False):
         params, lora = params_lora
+        if "moe_stats" in state["cache"]:
+            # a chunk's own counters: zeroed here, read with its tokens
+            state = dict(state, cache={
+                **state["cache"],
+                "moe_stats": jnp.zeros_like(state["cache"]["moe_stats"]),
+            })
 
         def step(st, _):
             active = st["active"]
@@ -640,6 +705,9 @@ class DecodeEngine:
                 positions=st["pos"][:, None],
                 kv_mask=kv_mask,
                 lora=lora,
+                # a slot that decodes nothing still rides the batch: a
+                # router must not count it or read experts for it
+                token_mask=active[:, None],
             )
             rng, sub = jax.random.split(st["rng"])
             if greedy:
@@ -693,9 +761,7 @@ class DecodeEngine:
         positions/cache offset ``plen`` (static — one compile per
         (prefix bucket, remainder bucket))."""
         cache_cfg, _ = family_forward(self.cfg)
-        sub_cache = init_cache(
-            cache_cfg, 1, self.max_len, state["cache"]["k"].dtype
-        )
+        sub_cache = self._new_cache(cache_cfg, 1)
         sub_cache = self._prefill_seed(sub_cache, prefix_kv, plen=plen)
         return self._prefill_tail(
             params, lora, state, sub_cache, packed, jnp.int32(plen),
@@ -717,9 +783,7 @@ class DecodeEngine:
         what lets prefix entries stay target-only)."""
         prompt, length, slot, _ = self._unpack_admission(packed, bucket)
         dcache_cfg, _ = family_forward(self.draft_cfg)
-        sub = init_cache(
-            dcache_cfg, 1, self.max_len, state["dcache"]["k"].dtype
-        )
+        sub = self._new_cache(dcache_cfg, 1)
         S_b = prompt.shape[1]
         slots_row = jnp.arange(self.max_len, dtype=jnp.int32)[None, :]
         kv_mask1 = slots_row < length
@@ -732,12 +796,7 @@ class DecodeEngine:
             token_mask=kv_mask1[:, :S_b],
         )
         st = dict(state)
-        st["dcache"] = {
-            kv: jax.lax.dynamic_update_slice(
-                state["dcache"][kv], sub[kv], (0, slot, 0, 0)
-            )
-            for kv in ("k", "v")
-        }
+        st["dcache"] = _splice_slot(state["dcache"], sub, slot)
         return st
 
     def _draft_prefill_runner(self, bucket: int):
@@ -1047,9 +1106,7 @@ class DecodeEngine:
         splices it in, and decode chunks run between parts."""
         slot = self._slot_req.index(None)
         cache_cfg, _ = family_forward(self.cfg)
-        sub_cache = init_cache(
-            cache_cfg, 1, self.max_len, self._state["cache"]["k"].dtype
-        )
+        sub_cache = self._new_cache(cache_cfg, 1)
         start = 0
         plen, entry = self._match_prefix(req.prompt)
         if plen is not None:
@@ -1162,6 +1219,8 @@ class DecodeEngine:
             if req is not None:
                 fail(req)
                 self._slot_req[slot] = None
+        while self._held:
+            fail(self._held.popleft())
         while True:
             try:
                 req = self._queue.get_nowait()
@@ -1198,6 +1257,7 @@ class DecodeEngine:
         while not self._stopped:
             if (
                 self._admitting is None
+                and not self._held
                 and all(r is None for r in self._slot_req)
                 and self._queue.empty()
             ):
@@ -1235,33 +1295,40 @@ class DecodeEngine:
                 except Exception as e:  # noqa: BLE001 — state integrity unknown
                     self._fail_admission(req, e)
                     return False
-            while self._admitting is None and None in self._slot_req:
-                try:
-                    req = self._queue.get_nowait()
-                except queue.Empty:
-                    break
-                if req is None:
-                    return False
+            while None in self._slot_req:
+                if self._admitting is None and self._held:
+                    req = self._held.popleft()
+                else:
+                    try:
+                        req = self._queue.get_nowait()
+                    except queue.Empty:
+                        break
+                    if req is None:
+                        return False
                 if req.cancelled:
                     # client left while the request was still queued:
                     # don't spend a prefill (possibly a fresh compile)
                     # on it
                     req._finish()
                     continue
+                in_parts = (
+                    self.prefill_chunk is not None
+                    and len(req.prompt) > self.prefill_chunk
+                )
+                if in_parts and self._admitting is not None:
+                    self._held.append(req)  # the lane is taken
+                    continue
                 req.admit_t = time.monotonic()
                 self.m_queue_wait.observe(req.admit_t - req.submit_t)
                 try:
-                    if (
-                        self.prefill_chunk is not None
-                        and len(req.prompt) > self.prefill_chunk
-                    ):
+                    if in_parts:
                         self._begin_chunked_admit(req)
                     else:
                         self._admit(req)
                 except Exception as e:  # noqa: BLE001 — state integrity unknown
                     self._fail_admission(req, e)
                     return False
-            self.m_queue_depth.set(self._queue.qsize())
+            self.m_queue_depth.set(self._queue.qsize() + len(self._held))
         adm_slot = (
             self._admitting["slot"] if self._admitting is not None else -1
         )
@@ -1299,15 +1366,44 @@ class DecodeEngine:
             # the host waiting for the device: the chunk's tokens and
             # the first tokens of the prefills dispatched before it
             with hot_span("engine.fetch", first_tokens=len(pending)):
-                toks, mask, firsts = jax.device_get(
-                    (toks, mask, [f for (_r, f, _s) in pending])
-                )
+                toks, mask, firsts, moe_stats = jax.device_get((
+                    toks, mask, [f for (_r, f, _s) in pending],
+                    self._state["cache"].get("moe_stats"),
+                ))
         except Exception as e:  # noqa: BLE001 — state integrity unknown
             self._fail_engine(e)
             return False
         with hot_span("engine.emit"):
+            if moe_stats is not None:
+                self.moe_local_assignments += int(moe_stats[0])
+                self.moe_experts_hit += int(moe_stats[1])
+                self.moe_dropped += int(moe_stats[2])
+            if self._window_layers and self._spec_fn is None:
+                self._count_window_blocks_skipped(mask)
             self._emit_chunk(pending, firsts, toks, mask)
         return True
+
+    def _count_window_blocks_skipped(self, mask) -> None:
+        """kv-blocks that lie wholly before a window layer's window and
+        are therefore neither fetched nor computed (``live_range`` of
+        ``ops/pallas_decode_attention.py``), over the chunk's steps, the
+        slots that decoded in them and the window layers: from the
+        positions the host already knows."""
+        from odh_kubeflow_tpu.ops import pallas_decode_attention as pda
+
+        cache = self._state["cache"]
+        block_k = pda.block_k_for(cache["wk"])
+        for slot, req in enumerate(self._slot_req):
+            if req is None or not mask[slot].any():
+                continue
+            # the first step's query: the newest token (a prefill's
+            # first token is still on its way here with this chunk)
+            pos = len(req.prompt) + max(len(req.tokens), 1) - 1
+            for step in range(int(mask[slot].sum())):
+                for window, layers in self._window_layers.items():
+                    self.window_blocks_skipped += layers * (
+                        max(pos + step - window + 1, 0) // block_k
+                    )
 
     def _emit_chunk(self, pending, firsts, toks, mask) -> None:
         for (preq, _f, pslot), tok in zip(pending, firsts):

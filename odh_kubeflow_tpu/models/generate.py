@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from odh_kubeflow_tpu.models.llama import (
+    FULL_STACKS,
+    WINDOW_STACKS,
     LlamaConfig,
     Params,
     forward_with_cache,
@@ -55,21 +57,64 @@ class GenerateConfig:
 
 
 def init_cache(
-    cfg: LlamaConfig, batch_size: int, max_len: int, dtype=jnp.bfloat16
+    cfg: LlamaConfig, batch_size: int, max_len: int, dtype=jnp.bfloat16,
+    widest_part: Optional[int] = None,
 ) -> Params:
-    """Preallocated KV cache: ``{"k","v"}: [L, B, S_max, Hkv * hd]``.
+    """Preallocated KV cache, one pair of stacks a KIND of layer:
+    ``{"k","v"}: [L_full, B, max_len, Hkv * hd]`` for the layers that
+    see every position and, where ``cfg.layer_windows`` has window
+    layers, ``{"wk","wv"}: [L_window, B, ring, Hkv * hd]`` for those.
 
-    The whole stack is the CARRY of the ``lax.scan`` over layers in
-    ``forward_with_cache`` (``llama.scan_layers_with_cache``): a step
-    writes its tokens at ``[layer, row, index]`` and attention reads the
-    layer where it lies; no layer is ever sliced out. A position's KV
-    heads lie side by side in one row of ``Hkv * hd`` lanes, so a head
-    is a lane slice of a ``[positions, Hkv * hd]`` tile (what
-    ``ops/pallas_decode_attention.py`` walks) and a token's write is one
-    contiguous row.
+    A window layer can only ever be asked for the ``window`` positions
+    that end at a query, so it keeps a RING: position ``p`` in slot ``p
+    % ring``, ``ring`` = the window plus ``widest_part`` (the most
+    positions one call writes before it attends: the engine's prefill
+    part, ``generate``'s prompt), rounded up to a multiple of the part
+    so that aligned parts never straddle its end. Without
+    ``widest_part``, or where that is no shorter, it is ``max_len``
+    long and never wraps.
+
+    The stacks are the CARRY of the ``lax.scan`` over the stack's
+    periods in ``forward_with_cache`` (``llama.scan_layers_with_cache``):
+    a step writes its tokens at ``[layer, row, index]`` and attention
+    reads the layer where it lies; no layer is ever sliced out. A
+    position's KV heads lie side by side in one row of ``Hkv * hd``
+    lanes, so a head is a lane slice of a ``[positions, Hkv * hd]`` tile
+    (what ``ops/pallas_decode_attention.py`` walks) and a token's write
+    is one contiguous row.
     """
-    shape = (cfg.num_layers, batch_size, max_len, cfg.kv_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    windows = getattr(cfg, "layer_windows", (None,))
+    periods = cfg.num_layers // len(windows)
+    n_window = sum(w is not None for w in windows)
+
+    def stacks(names, layers, length):
+        shape = (layers, batch_size, length, cfg.kv_dim)
+        return {n: jnp.zeros(shape, dtype) for n in names}
+
+    cache = {}
+    if n_window < len(windows):
+        cache.update(
+            stacks(FULL_STACKS, periods * (len(windows) - n_window), max_len)
+        )
+    if n_window:
+        ring = max_len
+        if widest_part is not None:
+            widest = max(w for w in windows if w is not None)
+            ring = min(max_len, -(-(widest + widest_part) // widest_part) * widest_part)
+        cache.update(stacks(WINDOW_STACKS, periods * n_window, ring))
+    if hasattr(cfg, "experts_held"):
+        # a call's expert counters (``moe.local_expert_ffn``): the
+        # engine zeroes them before a decode chunk and reads them with it
+        cache["moe_stats"] = jnp.zeros((3,), jnp.int32)
+    return cache
+
+
+def cache_bytes(cache: Params) -> dict[str, int]:
+    """Bytes the cache holds, by kind of layer."""
+    size = lambda names: sum(  # noqa: E731
+        cache[n].size * cache[n].dtype.itemsize for n in names if n in cache
+    )
+    return {"full": size(FULL_STACKS), "window": size(WINDOW_STACKS)}
 
 
 def cache_specs(cfg: LlamaConfig) -> Params:
@@ -80,7 +125,10 @@ def cache_specs(cfg: LlamaConfig) -> Params:
     wk/wv projections produce, so the cache write is collective-free).
     """
     s = P(None, (AXIS_DATA, AXIS_FSDP), None, AXIS_TENSOR)
-    return {"k": s, "v": s}
+    return {
+        name: s if leaf.ndim == 4 else P()
+        for name, leaf in jax.eval_shape(lambda: init_cache(cfg, 1, 128)).items()
+    }
 
 
 def sample_logits(
@@ -113,11 +161,18 @@ def sample_logits(
 
 
 def family_forward(cfg):
-    """(cache-shape config, cached-forward fn) for a dense or MoE
-    config — the single model-family dispatch point shared by
-    ``generate`` and ``models/spec_decode.py``. A MoeConfig wraps a
-    dense backbone whose shapes drive the cache; its own cached
-    forward routes the MLP through the experts."""
+    """(cache-shape config, cached-forward fn) for a config of any
+    family — the single model-family dispatch point shared by
+    ``generate``, the engine and ``models/spec_decode.py``. A family
+    other than the dense one names its module in ``cfg.family_module``
+    (imported when first asked for: a replica that serves one family
+    loads no other) and its config shapes its own cache; a MoeConfig
+    wraps a dense backbone whose shapes drive the cache, and its own
+    cached forward routes the MLP through the experts."""
+    if hasattr(cfg, "family_module"):
+        import importlib
+
+        return cfg, importlib.import_module(cfg.family_module).forward_with_cache
     if hasattr(cfg, "base"):
         from odh_kubeflow_tpu.models import moe as _moe
 
@@ -153,7 +208,9 @@ def generate(
 
     cache_cfg, fwd = family_forward(cfg)
 
-    cache = init_cache(cache_cfg, B, max_len, gen_cfg.cache_dtype)
+    cache = init_cache(
+        cache_cfg, B, max_len, gen_cfg.cache_dtype, widest_part=S_prompt
+    )
     slots = jnp.arange(max_len, dtype=jnp.int32)[None, :]  # [1, S_max]
     kv_mask = slots < prompt_lengths[:, None]  # prompt region valid
 

@@ -25,7 +25,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from functools import partial
-from typing import Any, Callable, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -105,6 +105,14 @@ class LlamaConfig:
     # removes the widening entirely. Opt-in: activation quantization
     # perturbs logits (rare greedy tie flips).
     w8a8_decode: bool = False
+    # The stack's PERIOD of attention kinds, one entry a layer and
+    # repeated down the depth: an int is a layer that sees only that
+    # many positions (its own included) and keeps a ring of them in the
+    # cache, None one that sees them all. ``(None,)`` is no window
+    # anywhere; ``(4096, 4096, 4096, None)`` three window layers to a
+    # global one. The cached forward honours it (``init_cache``,
+    # ``scan_layers_with_cache``); training attends in full.
+    layer_windows: tuple = (None,)
 
     @staticmethod
     def llama3_8b(**kw) -> "LlamaConfig":
@@ -347,8 +355,8 @@ def _decoder_layer(
     sin: jnp.ndarray,
     cos: jnp.ndarray,
     segment_ids,
-    cache=None,  # {"k","v"}: the STACKED [L, B, S_max, Hkv * hd], or None
-    layer_index=None,  # scalar: which layer of ``cache`` this is
+    cache=None,  # ``init_cache``'s stacks [L_kind, B, S_kind, Hkv * hd], or None
+    layer_index=None,  # CacheLayer: where this layer lies in ``cache``
     cache_index=None,  # scalar or [B]: write offset into the cache
     kv_mask=None,  # [B, S_max] bool: which cache slots are valid
 ):
@@ -427,24 +435,44 @@ def _decoder_layer(
     return x, cache
 
 
+class CacheLayer(NamedTuple):
+    """Where one layer's keys and values lie in the cache: the names of
+    its K and V stacks (``init_cache``: a kind of layer has a stack of
+    its own), its index in them, how far back it sees, and its depth in
+    the whole stack of layers."""
+
+    index: Any  # scalar int32
+    names: tuple = ("k", "v")
+    window: Optional[int] = None
+    depth: Any = None  # scalar int32
+
+
 def cache_write_and_attend(
     q,  # [B, S, Hq, hd]
     kk,  # [B, S, Hkv, hd] this step's keys
     vv,
-    cache,  # {"k","v"}: the stacked [L, B, S_max, Hkv * hd]
-    layer_index,  # scalar int32: the layer being run
+    cache,  # ``init_cache``: stacks [L_kind, B, S_kind, Hkv * hd]
+    layer,  # CacheLayer, or a scalar int32 index into {"k","v"}
     cache_index,  # scalar int32, or [B] int32 (per-row offsets)
     kv_mask,  # [B, S_max] bool or None
 ):
-    """Append this step's K/V at ``[layer_index, :, cache_index]`` of
-    the stacked cache and attend over that layer with absolute
-    positions (``kv_mask``/``q_offset`` mask the unwritten tail).
-    Shared by the dense and MoE cached layers.
+    """Append this step's K/V at ``[layer, :, cache_index]`` of the
+    layer's stacks and attend over that layer with absolute positions
+    (``kv_mask``/``q_offset`` mask the unwritten tail, ``layer.window``
+    what lies too far back). Shared by every cached layer.
 
-    The stack is the layer scan's CARRY (``scan_layers_with_cache``):
-    the write touches S rows of it in place and the read takes the
-    layer where it lies, so no step copies a layer's cache. Returns
+    The stacks are the layer scan's CARRY (``scan_layers_with_cache``):
+    the write touches S rows in place and the read takes the layer
+    where it lies, so no step copies a layer's cache. Returns
     ``(attn, cache)``.
+
+    Position ``p`` is written to slot ``p % S_kind``. A stack as long
+    as ``max_len`` never wraps; a window layer's is a RING of ``window
+    + the widest part written at once`` slots, which is all that its
+    queries can see. What that asks of the caller: a part of S > 1
+    positions written at a scalar ``cache_index`` must not straddle the
+    ring's end (parts whose width divides the ring and that start at
+    multiples of it never do; the engine checks its own).
 
     A scalar ``cache_index`` is the classic generate() layout: every
     row writes at the same physical offset (ragged prompts pad to a
@@ -452,56 +480,79 @@ def cache_write_and_attend(
     layout (``models/engine.py``): each batch slot sits at its own
     depth, so writes scatter per-row.
     """
+    if not isinstance(layer, CacheLayer):
+        layer = CacheLayer(layer)
     B, S, Hkv, hd = kk.shape
+    layer_index, window = layer.index, layer.window
+    stacks = dict(zip(("k", "v"), (cache[n] for n in layer.names)))
+    S_kind = stacks["k"].shape[2]
+
+    def slot(positions):
+        # a window layer's stack is a ring; a full one holds every
+        # position the caller may ask for, and a window of tokens that
+        # runs past its end (ragged speculative verify) is clamped to
+        # its last slot, which the engine's kv_mask excludes
+        if window is None:
+            return jnp.clip(positions, 0, S_kind - 1)
+        return positions % S_kind
+
     with jax.named_scope("kv_cache_write"):
         new = {"k": kk.reshape(B, S, Hkv * hd), "v": vv.reshape(B, S, Hkv * hd)}
         if getattr(cache_index, "ndim", 0) == 1:
             rows = jnp.arange(B)
             if S == 1:
-                at = (layer_index, rows, cache_index)
+                at = (layer_index, rows, slot(cache_index))
                 new = {kv: x[:, 0] for kv, x in new.items()}
             else:
                 # per-row offsets with a multi-token window — the engine's
                 # speculative verify (k+1 tokens per slot, each slot at its
-                # own depth). Clamp keeps ragged slots in bounds; the
-                # engine's kv_mask excludes anything beyond the real window.
-                S_max = cache["k"].shape[2]
-                cols = jnp.clip(
-                    cache_index[:, None] + jnp.arange(S)[None, :], 0, S_max - 1
-                )
+                # own depth)
+                cols = slot(cache_index[:, None] + jnp.arange(S)[None, :])
                 at = (layer_index, rows[:, None], cols)
-            cache = {
-                kv: cache[kv].at[at].set(new[kv].astype(cache[kv].dtype))
+            stacks = {
+                kv: stacks[kv].at[at].set(new[kv].astype(stacks[kv].dtype))
                 for kv in ("k", "v")
             }
         else:
-            cache = {
+            stacks = {
                 kv: jax.lax.dynamic_update_slice(
-                    cache[kv],
-                    new[kv][None].astype(cache[kv].dtype),
-                    (layer_index, 0, cache_index, 0),
+                    stacks[kv],
+                    new[kv][None].astype(stacks[kv].dtype),
+                    (layer_index, 0, slot(cache_index), 0),
                 )
                 for kv in ("k", "v")
             }
     with jax.named_scope("kv_cache_read"):
-        if _reads_cache_in_place(cache["k"], hd):
-            from odh_kubeflow_tpu.ops.pallas_decode_attention import (
-                decode_attend,
-            )
+        from odh_kubeflow_tpu.ops import pallas_decode_attention as pda
 
-            attn = decode_attend(
-                q, cache["k"], cache["v"], layer_index, cache_index, kv_mask
+        slot_mask, held = kv_mask, None
+        if window is not None:
+            # which position each slot of the ring holds; the mask is by
+            # position, the read by slot
+            held = pda.slot_positions(
+                jnp.broadcast_to(cache_index, (B,)), S, S_kind
+            )
+            if kv_mask is not None and kv_mask.shape[1] != S_kind:
+                slot_mask = jnp.take_along_axis(
+                    kv_mask, jnp.clip(held, 0, kv_mask.shape[1] - 1), axis=1
+                )
+        if _reads_cache_in_place(stacks["k"], hd):
+            attn = pda.decode_attend(
+                q, stacks["k"], stacks["v"], layer_index, cache_index,
+                slot_mask, window=window,
             )
         else:
             ck, cv = (
                 jax.lax.dynamic_index_in_dim(
-                    cache[kv], layer_index, 0, keepdims=False
+                    stacks[kv], layer_index, 0, keepdims=False
                 ).reshape(B, -1, Hkv, hd)
                 for kv in ("k", "v")
             )
             attn = dense_attention(
-                q, ck, cv, causal=True, q_offset=cache_index, kv_mask=kv_mask
+                q, ck, cv, causal=True, q_offset=cache_index,
+                kv_mask=slot_mask, k_positions=held, window=window,
             )
+    cache = {**cache, layer.names[0]: stacks["k"], layer.names[1]: stacks["v"]}
     return attn, cache
 
 
@@ -528,26 +579,81 @@ def _reads_cache_in_place(cache_leaf, head_dim: int) -> bool:
     )
 
 
-def scan_layers_with_cache(layer_fn, x, layers, lora_layers, cache):
-    """The one scan over layers that has a KV cache (dense and MoE).
+# the cache's stacks by kind of layer: (K, V) names in ``init_cache``
+FULL_STACKS = ("k", "v")
+WINDOW_STACKS = ("wk", "wv")
 
-    ``layer_fn(x, layer, lora_layer, cache, layer_index) -> (x, cache)``
-    runs one layer; it hands ``cache`` and ``layer_index`` to
-    ``cache_write_and_attend``. The stacked cache rides the scan as its
-    carry beside ``x``, and only the weights, adapters and the layer's
-    index are scanned: as a scanned input and output XLA would slice
-    every layer's whole cache out of the stack and write it back, each
-    layer of each step (PERF.md, PR 25). With the caller's buffer
-    donated the stack is updated in place."""
+
+def cache_layers(windows: tuple, period_index) -> list:
+    """The ``CacheLayer`` of each layer of period ``period_index`` of a
+    stack whose period of kinds is ``windows``: a kind's layers are
+    numbered down the depth within their own stacks."""
+    out, seen = [], {FULL_STACKS: 0, WINDOW_STACKS: 0}
+    per_period = {
+        FULL_STACKS: sum(w is None for w in windows),
+        WINDOW_STACKS: sum(w is not None for w in windows),
+    }
+    for j, w in enumerate(windows):
+        names = FULL_STACKS if w is None else WINDOW_STACKS
+        out.append(CacheLayer(
+            period_index * per_period[names] + seen[names], names, w,
+            period_index * len(windows) + j,
+        ))
+        seen[names] += 1
+    return out
+
+
+def scan_layers_with_cache(layer_fn, x, layers, lora_layers, cache,
+                           windows: tuple = (None,)):
+    """The one scan over layers that has a KV cache (every family).
+
+    ``layer_fn(x, layer, lora_layer, cache, cache_layer) -> (x, cache)``
+    runs one layer; it hands ``cache`` and ``cache_layer`` (a
+    ``CacheLayer``) to ``cache_write_and_attend``. The scan runs over
+    the stack's PERIODS (``windows``: ``LlamaConfig.layer_windows``):
+    its body holds one layer of each kind in the period's order, so a
+    stack of one kind is a scan over its layers. The cache's stacks,
+    one pair a kind, ride the scan as its carry beside ``x``, and only
+    the weights, adapters and the period's index are scanned: as a
+    scanned input and output XLA would slice every layer's whole cache
+    out of the stack and write it back, each layer of each step
+    (PERF.md, PR 25). With the caller's buffer donated the stacks are
+    updated in place."""
+    p = len(windows)
+    depth = jax.tree_util.tree_leaves(layers)[0].shape[0]
+    assert depth % p == 0, (depth, windows)
+
+    def take(tree, layer_index):
+        # a period's layers are taken from the stacks one by one, each
+        # slice with the one matmul that consumes it: scanned as a
+        # [periods, p, ...] input, XLA copies the whole period's weights
+        # out of the stack on every turn of the scan (PERF.md, PR 26)
+        if tree is None:
+            return None
+        return jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, layer_index, 0, False),
+            tree,
+        )
 
     def body(carry, scanned):
         x, cache = carry
-        layer_index, layer, lora_layer = scanned
-        return layer_fn(x, layer, lora_layer, cache, layer_index), None
+        period_index, layer, lora_layer = scanned
+        for cache_layer in cache_layers(windows, period_index):
+            if p > 1:
+                layer = take(layers, cache_layer.depth)
+                lora_layer = take(lora_layers, cache_layer.depth)
+            x, cache = layer_fn(x, layer, lora_layer, cache, cache_layer)
+        return (x, cache), None
 
-    layer_ids = jnp.arange(cache["k"].shape[0], dtype=jnp.int32)
+    # a stack of one kind is scanned as it lies
+    one_kind = p == 1
     (x, cache), _ = jax.lax.scan(
-        body, (x, cache), (layer_ids, layers, lora_layers)
+        body, (x, cache),
+        (
+            jnp.arange(depth // p, dtype=jnp.int32),
+            layers if one_kind else None,
+            lora_layers if one_kind else None,
+        ),
     )
     return x, cache
 
@@ -998,7 +1104,7 @@ def forward_with_cache(
         )
 
     x, new_cache = scan_layers_with_cache(
-        layer_fn, x, params["layers"], lora_layers, cache
+        layer_fn, x, params["layers"], lora_layers, cache, cfg.layer_windows
     )
 
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)

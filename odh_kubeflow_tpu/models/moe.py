@@ -1732,3 +1732,163 @@ def _apply_layers_pipelined(
         num_microbatches,
         accumulate_aux=True,
     )
+
+
+# ---------------------------------------------------------------------------
+# an expert-parallel share's expert layer: told which experts it holds
+
+
+def route_sigmoid_topk(
+    router_logits: jnp.ndarray,  # [..., E] float32
+    k: int,
+    normalise: bool = True,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The other published selection beside ``_routing_stats``'s softmax:
+    each expert's score is the SIGMOID of its own logit, the ``k``
+    largest are chosen and (``norm_topk_prob``) their scores divided by
+    their sum. Returns ``(weights [..., k] float32, ids [..., k])``."""
+    top_s, top_idx = jax.lax.top_k(jax.nn.sigmoid(router_logits), k)
+    if normalise:
+        top_s = top_s / jnp.maximum(top_s.sum(-1, keepdims=True), 1e-20)
+    return top_s, top_idx
+
+
+def local_dispatch(top_idx, token_mask, experts_held, block_m: int):
+    """The sorted layout ``ops/pallas_moe_local.py`` walks, for the
+    assignments that fall on the experts held here.
+
+    ``top_idx`` [T, k] are ids among ALL the layer's experts;
+    ``experts_held = (first, count)``. Rows are the local assignments
+    sorted by expert, each expert's group padded to ``block_m``; nothing
+    is dropped: there is room for every token choosing held experts
+    only. Returns ``token_of_row`` [M], ``row_of`` [T, k] (M where the
+    assignment is not local), ``local`` [T, k] bool, ``tile_expert``
+    [M // block_m], ``n_live`` [1], and ``sizes`` [count]."""
+    first, count = experts_held
+    T, k = top_idx.shape
+    A = T * k
+    M = -(-T * min(k, count) // block_m) * block_m + count * block_m
+    le = top_idx - first
+    local = (le >= 0) & (le < count)
+    if token_mask is not None:
+        local = local & token_mask.reshape(T, 1)
+    flat = jnp.where(local, le, count).reshape(A)  # count: not here
+    sizes_all = jnp.sum(
+        jax.nn.one_hot(flat, count + 1, dtype=jnp.int32), axis=0
+    )
+    sizes = sizes_all[:count]
+    padded = -(-sizes // block_m) * block_m
+    ends = jnp.cumsum(padded)
+    starts = ends - padded
+    order = jnp.argsort(flat, stable=True)
+    sorted_le = flat[order]
+    group_first = jnp.cumsum(sizes_all) - sizes_all  # in sorted order
+    rank = jnp.arange(A, dtype=jnp.int32) - group_first[sorted_le]
+    row_sorted = jnp.where(
+        sorted_le < count, starts[jnp.minimum(sorted_le, count - 1)] + rank, M
+    ).astype(jnp.int32)
+    token_of_row = (
+        jnp.zeros((M + 1,), jnp.int32).at[row_sorted].set(order // k)[:M]
+    )
+    row_of = jnp.zeros((A,), jnp.int32).at[order].set(row_sorted).reshape(T, k)
+    n_live = ends[-1] // block_m
+    tiles = jnp.minimum(
+        jnp.arange(M // block_m, dtype=jnp.int32), jnp.maximum(n_live - 1, 0)
+    )
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(ends, tiles * block_m, side="right"), count - 1
+    ).astype(jnp.int32)
+    return dict(
+        token_of_row=token_of_row, row_of=row_of, local=local,
+        tile_expert=tile_expert, n_live=n_live.reshape(1).astype(jnp.int32),
+        sizes=sizes,
+    )
+
+
+def _bank_layer(bank, layer, dtype):
+    """One layer of a stacked expert bank, dequantised: the plain read
+    (off the TPU, or for banks that are not int8)."""
+    if isinstance(bank, dict):
+        take = lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, False)  # noqa: E731
+        return (
+            take(bank["q"]).astype(jnp.float32) * take(bank["scale"])
+        ).astype(dtype)
+    return jax.lax.dynamic_index_in_dim(bank, layer, 0, False).astype(dtype)
+
+
+def reads_banks_in_place(banks: dict) -> bool:
+    """Whether the held experts go through ``moe_local_ffn`` on the
+    stacked int8 banks (one TPU chip) or through plain dots on a
+    dequantised copy of the layer's banks."""
+    am = jax.sharding.get_abstract_mesh()
+    return (
+        jax.default_backend() == "tpu"
+        and (am.empty or am.size == 1)
+        and all(isinstance(b, dict) and set(b) == {"q", "scale"}
+                for b in banks.values())
+    )
+
+
+def local_expert_ffn(
+    h: jnp.ndarray,  # [T, D]
+    top_w: jnp.ndarray,  # [T, k] float32 combine weights
+    top_idx: jnp.ndarray,  # [T, k] ids among ALL the layer's experts
+    banks: dict,  # "moe_gate"/"moe_up" [L, E_held, D, F], "moe_down" [L, E_held, F, D]
+    layer,  # scalar int32: the layer of the stacked banks
+    experts_held: tuple,  # (first, count) among the layer's experts
+    token_mask: Optional[jnp.ndarray] = None,  # [T] bool; False = not a token
+    in_place: Optional[bool] = None,
+    interpret: bool = False,
+):
+    """The part of a mixture layer's output that the experts HELD HERE
+    give: ``sum over a token's chosen experts e that are held of w_e *
+    (silu(h G_e) * (h U_e)) D_e``. The router ran over all the layer's
+    experts; what the experts held elsewhere would add is their chips'
+    to compute and is not stood in for. Dropless. Returns ``(out [T, D]
+    in h's dtype, stats int32 [3])``: local assignments, distinct held
+    experts hit, assignments dropped (always 0: counted, not assumed).
+    """
+    from odh_kubeflow_tpu.ops import pallas_moe_local as pml
+
+    T, D = h.shape
+    first, count = experts_held
+    if in_place is None:
+        in_place = reads_banks_in_place(banks)
+    block_m = pml.block_m_for(T)
+    d = local_dispatch(top_idx, token_mask, experts_held, block_m)
+    M = d["token_of_row"].shape[0]
+    w = jnp.where(d["local"], top_w, 0.0)
+    stats = jnp.stack([
+        jnp.sum(d["local"]), jnp.sum(d["sizes"] > 0),
+        jnp.sum(d["local"] & (d["row_of"] >= M)),
+    ]).astype(jnp.int32)
+    if in_place:
+        with jax.named_scope("moe_local_ffn"):
+            y = pml.moe_local_ffn(
+                h[d["token_of_row"]], d["tile_expert"], d["n_live"], layer,
+                banks["moe_gate"], banks["moe_up"], banks["moe_down"],
+                block_m=block_m, interpret=interpret,
+            )
+        picked = y[jnp.minimum(d["row_of"], M - 1)]  # [T, k, D]
+        # a row of a tile that was never computed holds anything
+        picked = jnp.where(d["local"][..., None], picked, 0).astype(jnp.float32)
+        out = jnp.einsum("tk,tkd->td", w, picked)
+    else:
+        gate, up, down = (
+            _bank_layer(banks[n], layer, h.dtype)
+            for n in ("moe_gate", "moe_up", "moe_down")
+        )
+        # [T, count] combine weights over the held experts
+        combine = jnp.einsum(
+            "tk,tke->te", w,
+            jax.nn.one_hot(top_idx - first, count, dtype=jnp.float32),
+        )
+        act = jax.nn.silu(
+            jnp.einsum("td,edf->etf", h, gate, preferred_element_type=jnp.float32)
+        ) * jnp.einsum("td,edf->etf", h, up, preferred_element_type=jnp.float32)
+        y = jnp.einsum(
+            "etf,efd->etd", act.astype(h.dtype), down,
+            preferred_element_type=jnp.float32,
+        )
+        out = jnp.einsum("te,etd->td", combine, y)
+    return out.astype(h.dtype), stats

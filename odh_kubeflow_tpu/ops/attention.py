@@ -29,6 +29,8 @@ def dense_attention(
     q_offset: int | jnp.ndarray = 0,
     segment_ids: Optional[jnp.ndarray] = None,  # [B, S] same-id attends
     kv_mask: Optional[jnp.ndarray] = None,  # [B, Sk] bool, True = attend
+    k_positions: Optional[jnp.ndarray] = None,  # [B, Sk] int32
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Returns [B, Sq, Hq, hd]. Scores accumulate in float32.
 
@@ -37,6 +39,11 @@ def dense_attention(
     ``kv_mask`` marks which cache slots hold real tokens (the KV-cache
     decode path with ragged right-padded prompts leaves invalid slots
     between each prompt's end and the shared write index).
+    ``k_positions`` gives the absolute position each key holds where
+    that is not its index (a ring: ``slot_positions`` of
+    ``ops/pallas_decode_attention.py``; a negative one was never
+    written), and ``window`` lets a query see only the ``window``
+    positions that end at its own.
     """
     B, Sq, Hq, hd = q.shape
     _, Sk, Hkv, _ = k.shape
@@ -52,7 +59,20 @@ def dense_attention(
     scores = scores * scale
 
     mask = None
-    if causal:
+    if causal and (k_positions is not None or window is not None):
+        q_pos = (
+            jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32), (B,))[:, None]
+            + jnp.arange(Sq)[None, :]
+        )[:, :, None]  # [B, Sq, 1]
+        k_pos = (
+            jnp.arange(Sk)[None, None, :] if k_positions is None
+            else k_positions[:, None, :]
+        )
+        mask = (k_pos <= q_pos) & (k_pos >= 0)
+        if window is not None:
+            mask = mask & (k_pos > q_pos - window)
+        mask = mask[:, None, None, :, :]
+    elif causal:
         if getattr(q_offset, "ndim", 0) == 1:
             # per-row offsets ([B] vector — the continuous-batching
             # engine's slots each sit at their own position)

@@ -18,3 +18,13 @@ def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-5) -> jnp.ndar
     var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
     normed = x32 * jax.lax.rsqrt(var + eps)
     return (normed * weight.astype(jnp.float32)).astype(dtype)
+
+
+def layer_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
+    """Mean-subtracting LayerNorm with a scale and no bias (Cohere's)."""
+    dtype = x.dtype
+    x32 = x.astype(jnp.float32)
+    x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+    normed = x32 * jax.lax.rsqrt(var + eps)
+    return (normed * weight.astype(jnp.float32)).astype(dtype)

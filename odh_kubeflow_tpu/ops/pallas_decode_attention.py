@@ -11,12 +11,23 @@ operand and the layer index by scalar prefetch: the K/V ``BlockSpec``
 index maps pick ``[layer, row, kv-block]`` of the array in HBM, so the
 only cache bytes moved are the blocks attended over.
 
-Per row the causal bound is known before the blocks are fetched (each
-row's first query position is scalar-prefetched too), so kv-blocks
-wholly past a row's last query are neither fetched nor computed: their
-softmax weights are exactly 0 in the dense form. The grid still visits
-them; the index map repeats the last live block (no new DMA) and the
-body is predicated off.
+Per row the bounds are known before the blocks are fetched (each row's
+first query position is scalar-prefetched too), so kv-blocks wholly
+past a row's last query, or wholly before the ``window`` positions its
+first query can see, are neither fetched nor computed: their softmax
+weights are exactly 0 in the dense form. The grid still visits them;
+the index map repeats the last live block (no new DMA) and the body is
+predicated off.
+
+A layer whose ``S_max`` is shorter than the positions it is asked about
+is a RING: position ``p`` lies in slot ``p % S_max`` (a window layer
+keeps only ``window + the widest part written at once`` positions;
+``models/generate.py`` ``init_cache``). The live positions of a row
+block are contiguous, so their blocks are contiguous modulo the number
+of blocks: the walk starts at the block of the oldest live position and
+wraps. Which position a slot holds comes as an operand
+(``slot_positions``), so the mask is one comparison whatever the layout;
+"no window" is a window wider than any position.
 
 Numerics are ``dense_attention``'s: float32 scores (``q @ k^T`` at
 float32 accumulation, times ``hd ** -0.5``), masked with -1e30,
@@ -54,6 +65,9 @@ _ROW_ALIGN = 16  # bf16 sublane tile
 # a K or V tile of at most 1 MB, float32 state for at most 2048 rows
 _MAX_TILE_BYTES = 1 << 20
 _MAX_STATE_ROWS = 2048
+# "no window": wider than any position, and far from int32's edge
+NO_WINDOW = 1 << 30
+_NEVER = 1 << 30  # the position of a slot that holds nothing to attend
 
 
 def supported(cache_leaf, head_dim: int) -> bool:
@@ -64,8 +78,35 @@ def supported(cache_leaf, head_dim: int) -> bool:
     return s_max % 128 == 0 and width % 128 == 0 and head_dim % 64 == 0
 
 
-def _kernel(layer_ref, qoff_ref, qpos_ref, q_ref, k_ref, v_ref, ok_ref,
-            o_ref, acc, m, l, *, heads, head_dim, block_k, live, scale):
+def slot_positions(q_off, steps: int, s_max: int):
+    """[B, s_max] int32: the absolute position each slot of a row holds
+    once ``steps`` positions from ``q_off`` on are written, position
+    ``p`` in slot ``p % s_max``: the newest position that is congruent
+    to the slot. Negative: the slot was never written. Where nothing
+    wrapped (every position < s_max) a written slot holds its index."""
+    last = (jnp.asarray(q_off, jnp.int32) + (steps - 1))[:, None]
+    slot = jnp.arange(s_max, dtype=jnp.int32)[None, :]
+    return last - jnp.mod(last - slot, s_max)
+
+
+def live_range(first_pos, last_pos, *, window, block_k, num_k):
+    """(first block, number of blocks) a query span ``first_pos ..
+    last_pos`` can see: from the block of the oldest position inside
+    the first query's window to the block of the last query's own."""
+    lo = jnp.maximum(first_pos - window + 1, 0) // block_k
+    return lo, jnp.clip(last_pos // block_k - lo + 1, 1, num_k)
+
+
+def block_k_for(cache_leaf, block_k: int = DEFAULT_BLOCK_K) -> int:
+    """kv positions a grid step reads of this stack: ``block_k``, cut to
+    a tile of at most 1 MB, in whole lane tiles that divide the stack."""
+    _, _, s_max, width = cache_leaf.shape
+    tile_k = _MAX_TILE_BYTES // (width * cache_leaf.dtype.itemsize) // 128 * 128
+    return math.gcd(s_max, min(block_k, max(tile_k, 128)))
+
+
+def _kernel(layer_ref, qoff_ref, qpos_ref, q_ref, k_ref, v_ref, kpos_ref,
+            o_ref, acc, m, l, *, heads, head_dim, window, live, scale):
     del layer_ref
     b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     block_rows = q_ref.shape[1]
@@ -76,12 +117,10 @@ def _kernel(layer_ref, qoff_ref, qpos_ref, q_ref, k_ref, v_ref, ok_ref,
         l[...] = jnp.zeros(l.shape, l.dtype)
         acc[...] = jnp.zeros(acc.shape, acc.dtype)
 
-    @pl.when(j < live(qoff_ref[b], i))
+    @pl.when(j < live(qoff_ref[b], i)[1])
     def _block():
-        k_pos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_rows, block_k), 1
-        )
-        mask = (k_pos <= qpos_ref[...]) & (ok_ref[...] > 0)
+        k_pos, q_pos = kpos_ref[...], qpos_ref[...]
+        mask = (k_pos <= q_pos) & (k_pos > q_pos - window)
         # a bf16 operand has one pass to offer: a process-wide
         # jax_default_matmul_precision of "highest" must not reach
         # Mosaic with it (float32 operands follow the configuration)
@@ -116,16 +155,17 @@ def _kernel(layer_ref, qoff_ref, qpos_ref, q_ref, k_ref, v_ref, ok_ref,
         o_ref[...] = (acc[...] / l[...]).astype(o_ref.dtype)
 
 
-def _live_blocks(q_off, i, *, block_rows, block_k, rows_last, group, num_k):
-    """kv-blocks that row-block ``i`` of a row starting at ``q_off`` can
-    see: up to the block holding its last query's position."""
+def _live_blocks(q_off, i, *, block_rows, rows_last, group, **geometry):
+    """``live_range`` of row-block ``i`` of a row starting at ``q_off``."""
     last_row = jnp.minimum((i + 1) * block_rows - 1, rows_last)
-    last_pos = q_off + last_row // group
-    return jnp.clip(last_pos // block_k + 1, 1, num_k)
+    return live_range(
+        q_off + (i * block_rows) // group, q_off + last_row // group,
+        **geometry,
+    )
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_k", "block_rows", "interpret")
+    jax.jit, static_argnames=("window", "block_k", "block_rows", "interpret")
 )
 def decode_attend(
     q: jnp.ndarray,  # [B, S, Hq, hd]
@@ -135,20 +175,22 @@ def decode_attend(
     q_offset,  # scalar or [B] int32: absolute position of q[:, 0]
     kv_mask=None,  # [B, S_max] bool, True = attend
     *,
+    window=None,  # positions a query sees, its own included; None: all
     block_k: int = DEFAULT_BLOCK_K,
     block_rows: int = DEFAULT_BLOCK_ROWS,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """``dense_attention(q, k[layer], v[layer], causal=True,
-    q_offset=q_offset, kv_mask=kv_mask)`` without taking the layer out
-    of the stack. Returns [B, S, Hq, hd]."""
+    q_offset=q_offset, kv_mask=kv_mask, window=window)`` without taking
+    the layer out of the stack. With a ``window`` the layer is a ring:
+    position ``p`` is read from slot ``p % S_max`` (``kv_mask`` is by
+    slot). Returns [B, S, Hq, hd]."""
     B, S, Hq, hd = q.shape
     _, _, S_max, width = cache_k.shape
     heads = width // hd
     group = Hq // heads
     assert heads * hd == width and group * heads == Hq, (q.shape, cache_k.shape)
-    tile_k = _MAX_TILE_BYTES // (width * cache_k.dtype.itemsize) // 128 * 128
-    block_k = math.gcd(S_max, min(block_k, max(tile_k, 128)))
+    block_k = block_k_for(cache_k, block_k)
     block_rows = min(
         block_rows,
         max(_MAX_STATE_ROWS // heads // _ROW_ALIGN * _ROW_ALIGN, _ROW_ALIGN),
@@ -166,20 +208,25 @@ def decode_attend(
     qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows_p - rows), (0, 0)))
     q_off = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32), (B,))
     q_pos = q_off[:, None] + (jnp.arange(rows_p, dtype=jnp.int32) // group)
-    ok = (
-        jnp.ones((B, 1, S_max), jnp.int32) if kv_mask is None
-        else kv_mask.astype(jnp.int32)[:, None, :]
-    )
+    if window is None:
+        # a layer that sees everything holds position p in slot p
+        k_pos = jnp.broadcast_to(jnp.arange(S_max, dtype=jnp.int32), (B, S_max))
+        window = NO_WINDOW
+    else:
+        k_pos = slot_positions(q_off, S, S_max)
+    held = k_pos >= 0 if kv_mask is None else (k_pos >= 0) & kv_mask
+    k_pos = jnp.where(held, k_pos, _NEVER)[:, None, :]
     layer = jnp.asarray(layer_index, jnp.int32).reshape(1)
     num_q, num_k = rows_p // block_rows, S_max // block_k
     live = functools.partial(
-        _live_blocks, block_rows=block_rows, block_k=block_k,
-        rows_last=rows - 1, group=group, num_k=num_k,
+        _live_blocks, block_rows=block_rows, rows_last=rows - 1, group=group,
+        window=window, block_k=block_k, num_k=num_k,
     )
 
     def kv_block(b, i, j, q_off):
         # past the last live block the index stands still: no new DMA
-        return jnp.minimum(j, live(q_off[b], i) - 1)
+        first, count = live(q_off[b], i)
+        return (first + jnp.minimum(j, count - 1)) % num_k
 
     kv_spec = pl.BlockSpec(
         (None, None, block_k, width),
@@ -187,7 +234,7 @@ def decode_attend(
     )
     out = pl.pallas_call(
         functools.partial(
-            _kernel, heads=heads, head_dim=hd, block_k=block_k, live=live,
+            _kernel, heads=heads, head_dim=hd, window=window, live=live,
             scale=hd**-0.5,
         ),
         name="decode_attend",
@@ -223,6 +270,6 @@ def decode_attend(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(layer, q_off, q_pos[:, :, None], qg, cache_k, cache_v, ok)
+    )(layer, q_off, q_pos[:, :, None], qg, cache_k, cache_v, k_pos)
     out = out[:, :, :rows].reshape(B, heads, S, group, hd)
     return out.transpose(0, 2, 1, 3, 4).reshape(B, S, Hq, hd)

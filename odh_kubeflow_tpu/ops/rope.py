@@ -1,4 +1,9 @@
-"""Rotary position embeddings (plain RoPE with configurable theta).
+"""Rotary position embeddings (plain RoPE with configurable theta), in
+the two pairings models are published with: the half-split one
+(``apply_rope``: pairs ``(i, i + hd/2)``, Llama / Mistral) and the
+interleaved one (``apply_rope_interleaved``: pairs ``(2i, 2i + 1)``,
+GPT-J's, which Cohere's ``rope_gptj`` names). Both take the same
+``rope_angles``.
 
 Angles are precomputed once per forward *outside* the layer scan so the
 sin/cos tables are computed a single time and live in registers/VMEM
@@ -36,3 +41,28 @@ def apply_rope(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
     )
     return out.astype(dtype)
+
+
+def apply_rope_interleaved(
+    x: jnp.ndarray,  # [B, S, H, hd]
+    sin: jnp.ndarray,  # [B, S, hd/2]
+    cos: jnp.ndarray,  # [B, S, hd/2]
+) -> jnp.ndarray:
+    """Rotate the ADJACENT pairs ``(x[2i], x[2i+1])`` by angle ``i``.
+
+    Written as ``x * cos + partner(x) * sin`` with each lane's partner
+    fetched by a rotation of the lanes, not as a reshape to ``[..., hd/2,
+    2]``: XLA carries that reshape through the projection that made
+    ``x`` and onto its WEIGHT, which it then dequantises, reshapes and
+    writes out in full on every call (134 MB a layer at 128 heads;
+    PERF.md, PR 26)."""
+    dtype = x.dtype
+    x = x.astype(jnp.float32)
+    even = (jnp.arange(x.shape[-1]) % 2 == 0)
+    # lane 2i's partner is -x[2i+1], lane 2i+1's is x[2i]
+    partner = jnp.where(
+        even, -jnp.roll(x, -1, axis=-1), jnp.roll(x, 1, axis=-1)
+    )
+    sin = jnp.repeat(sin, 2, axis=-1)[:, :, None, :]
+    cos = jnp.repeat(cos, 2, axis=-1)[:, :, None, :]
+    return (x * cos + partner * sin).astype(dtype)

@@ -11,6 +11,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from odh_kubeflow_tpu.models import LlamaConfig, init_params
@@ -476,5 +477,38 @@ def test_decode_chunk_updates_the_donated_cache_in_place(model, greedy):
         assert (
             mem.output_size_in_bytes - mem.alias_size_in_bytes < cache_k.nbytes
         )
+    finally:
+        engine.stop()
+
+
+def test_short_prompts_pass_a_long_one_waiting_for_the_lane(model):
+    """One prompt at a time is admitted in parts. A second long prompt
+    waits for that lane without holding a slot, and the short prompts
+    queued behind it go on to the free slots meanwhile; every request
+    still gets the tokens it would have got alone."""
+    cfg, params = model
+    rng = np.random.default_rng(5)
+    long_a, long_b = (rng.integers(1, 200, size=n).tolist() for n in (100, 90))
+    shorts = [rng.integers(1, 200, size=6).tolist() for _ in range(3)]
+    # slow the lane down: a part of 8, so long_a takes 13 loop turns
+    engine = DecodeEngine(
+        params, cfg, n_slots=4, max_len=128, chunk=2, prompt_buckets=(8,),
+        prefill_chunk=8, cache_dtype=jnp.float32,
+    )
+    try:
+        # every program compiled before the order of first tokens is read
+        engine.submit(long_a[:20], max_tokens=2).result(timeout=300)
+        engine.submit(shorts[0], max_tokens=2).result(timeout=300)
+        reqs = [engine.submit(p, max_tokens=4) for p in [long_a, long_b] + shorts]
+        got = [r.result(timeout=300) for r in reqs]
+        first = [r.times[0] for r in reqs]
+        # the shorts got their first token while long_b was still waiting
+        # for long_a's parts, and long_b was not starved: it follows long_a
+        assert max(first[2:]) < first[1]
+        assert first[0] < first[1]
+        assert not engine._held
+        for prompt, toks in zip([long_a, long_b] + shorts, got):
+            alone = engine.submit(prompt, max_tokens=4).result(timeout=300)
+            assert toks == alone
     finally:
         engine.stop()

@@ -112,3 +112,128 @@ def _chip_smoke():
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+# ---- Command A+'s share (cohere2_moe): 128 / 8 heads x 128, window 4096,
+# a ring of 6144 (window + a part of 2048) beside 13312 full positions, 16
+# held experts of width 4096
+
+
+@pytest.mark.parametrize(
+    "B,S,S_kind,window,vector",
+    [(16, 1, 6144, 4096, True), (16, 1, 13312, None, True),
+     (1, 2048, 6144, 4096, False), (1, 2048, 13312, None, False)],
+    ids=["decode-ring", "decode-full", "part-ring", "part-full"],
+)
+def test_decode_attend_compiles_for_windows_and_rings(
+    one_chip, B, S, S_kind, window, vector
+):
+    L, Hq, Hkv, hd = 6, 128, 8, 128
+    q = jax.ShapeDtypeStruct((B, S, Hq, hd), jnp.bfloat16)
+    cache = jax.ShapeDtypeStruct((L, B, S_kind, Hkv * hd), jnp.bfloat16)
+    index = jax.ShapeDtypeStruct((B,) if vector else (), jnp.int32)
+    compiled = jax.jit(
+        lambda *a: decode_attend(*a, window=window)
+    ).lower(
+        *_on(one_chip, (q, cache, cache, jax.ShapeDtypeStruct((), jnp.int32),
+                        index, jax.ShapeDtypeStruct((B, S_kind), jnp.bool_)))
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < cache.size // L
+
+
+def _int8_bank(shape):
+    return {
+        "q": jax.ShapeDtypeStruct(shape, jnp.int8),
+        "scale": jax.ShapeDtypeStruct(shape[:-2] + (1, shape[-1]), jnp.float32),
+    }
+
+
+@pytest.mark.parametrize("T", [16, 2048], ids=["decode", "part"])
+def test_moe_local_ffn_compiles_at_real_widths(one_chip, T):
+    """The held experts' kernel on the stacked int8 banks: compiled, and
+    no bank (0.8 GB a layer) is copied to feed it."""
+    from odh_kubeflow_tpu.models import moe
+
+    L, E, D, F, k = 2, 16, 4096, 4096, 8
+    banks = {
+        "moe_gate": _int8_bank((L, E, D, F)), "moe_up": _int8_bank((L, E, D, F)),
+        "moe_down": _int8_bank((L, E, F, D)),
+    }
+
+    def fn(h, w, idx, banks, layer):
+        return moe.local_expert_ffn(
+            h, w, idx, banks, layer, (32, E), in_place=True
+        )
+
+    compiled = jax.jit(fn).lower(*_on(one_chip, (
+        jax.ShapeDtypeStruct((T, D), jnp.bfloat16),
+        jax.ShapeDtypeStruct((T, k), jnp.float32),
+        jax.ShapeDtypeStruct((T, k), jnp.int32),
+        banks, jax.ShapeDtypeStruct((), jnp.int32),
+    ))).compile()
+    text = compiled.as_text()
+    assert "moe_local_ffn" in text
+    assert not [
+        line for line in text.splitlines()
+        if f"= s8[{E},{D},{F}]" in line or f"= s8[{L},{E},{D},{F}]" in line
+        if " parameter(" not in line
+    ]
+    # the sorted rows and their outputs (2048 tokens x 8 choices), never
+    # a layer's three banks
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * E * D * F
+
+
+def test_cohere2_decode_step_reads_banks_and_both_caches_in_place(
+    one_chip, monkeypatch
+):
+    """One period of the served share at its real widths, a decode step
+    compiled for the chip: both kinds of cache stack are aliased, the
+    attention and expert kernels are there, and the step's temporaries
+    stay under one layer's expert banks."""
+    from odh_kubeflow_tpu.models import cohere2, moe
+
+    monkeypatch.setattr(llama, "_reads_cache_in_place", lambda leaf, hd: True)
+    monkeypatch.setattr(moe, "reads_banks_in_place", lambda banks: True)
+    cfg = cohere2.Cohere2MoeConfig(
+        vocab_size=32768, num_layers=4, experts_held=(32, 16)
+    )
+    B, max_len = 16, 13312
+    params = jax.eval_shape(
+        lambda: cohere2.init_params(jax.random.key(0), cfg, jnp.bfloat16)
+    )
+    for name, leaf in params["layers"].items():
+        if name not in ("norm", "router"):
+            params["layers"][name] = _int8_bank(leaf.shape)
+    cache = jax.eval_shape(
+        lambda: init_cache(cfg, B, max_len, widest_part=2048)
+    )
+    assert cache["wk"].shape == (3, B, 6144, 1024)
+    assert cache["k"].shape == (1, B, max_len, 1024)
+
+    def step(params, cache, tokens, index, kv_mask):
+        return cohere2.forward_with_cache(
+            params, tokens, cfg, cache, index, positions=index[:, None],
+            kv_mask=kv_mask, token_mask=kv_mask[:, :1],
+        )
+
+    compiled = jax.jit(step, donate_argnums=1).lower(*_on(one_chip, (
+        params, cache, jax.ShapeDtypeStruct((B, 1), jnp.int32),
+        jax.ShapeDtypeStruct((B,), jnp.int32),
+        jax.ShapeDtypeStruct((B, max_len), jnp.bool_),
+    ))).compile()
+    mem = compiled.memory_analysis()
+    stacks = sum(v.size * 2 for n, v in cache.items() if n != "moe_stats")
+    assert mem.alias_size_in_bytes >= stacks
+    text = compiled.as_text()
+    assert "decode_attend" in text and "moe_local_ffn" in text
+    # no instruction produces a layer's bank (or all of them)
+    import re
+
+    made = [
+        line[:120] for line in text.splitlines()
+        if re.search(r"= s8\[(?:\d+,)?16,4096,4096\]", line)
+        and " parameter(" not in line
+    ]
+    assert not made, made[:3]
+    assert mem.temp_size_in_bytes < 3 * 16 * 4096 * 4096, mem.temp_size_in_bytes
