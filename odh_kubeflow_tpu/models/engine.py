@@ -15,6 +15,9 @@ into the running decode loop**:
 - the engine thread alternates *admit* (a prefill program per prompt
   bucket writes one prompt's KV into a free slot) and *decode chunks*
   (one jitted program advancing ALL active slots ``chunk`` tokens);
+- a prefill's first token is streamed when the prefill ends: the turn
+  dispatches the chunk behind the prefills, fetches and emits each
+  first token as its prefill ends, and only then blocks on the chunk;
 - static shapes throughout: compile count = #prompt_buckets + 1,
   independent of request mix (XLA discipline — no shape depends on
   arrival order or request params);
@@ -234,7 +237,7 @@ def _record_request(req: _Request) -> None:
     ``engine.request`` (submit to finish) over ``engine.request.queued``
     (submit to the loop taking it with a slot free),
     ``engine.request.first_token`` (from there to the emit of its first
-    token: the prefill and the fetch deferred to the next chunk) and
+    token: the turn's prefills up to its own, and its fetch) and
     ``engine.request.decode`` (first token to finish). A request that
     ends early has the phases it reached, the last one cut at the end.
     Children first: the root's outcome decides whether the collector
@@ -511,12 +514,15 @@ class DecodeEngine:
         # (whole prompts, parts of a chunked admission, prefix seeding)
         self.turns = 0
         self.prefill_calls = 0
+        # first tokens emitted ahead of their turn's chunk fetch: every
+        # request that reached a slot with max_tokens > 1
+        self.first_tokens_early = 0
         # set on unrecoverable device failure; submit() then raises
         self.failure: Optional[Exception] = None
         self._slot_req: list[Optional[_Request]] = [None] * S
-        # (req, device-scalar first token, slot): fetched alongside the
-        # next chunk's outputs — the prefill's first token costs no
-        # dedicated sync
+        # (req, device-scalar first token, slot) of the prefills this
+        # turn dispatched: fetched and emitted one by one once the chunk
+        # has been dispatched behind them, before the chunk's own fetch
         self._pending_first: list = []
         self._queue: "queue.Queue[Optional[_Request]]" = queue.Queue()
         self._wake = threading.Event()
@@ -1068,12 +1074,13 @@ class DecodeEngine:
                 self.params, self.lora, self._state, packed,
             )
             self._maybe_insert_prefix(req.prompt, slot)
-        # defer the first-token fetch: the device value is collected
-        # with the NEXT chunk's device_get (one round-trip for both)
-        # unless the request can't enter a slot at all. Checked BEFORE
-        # the draft prefill — a max_tokens<=1 request never decodes, so
-        # filling a draft cache for it (plus possibly a fresh bucket
-        # compile) would be pure waste.
+        # the first token stays on the device until this turn's chunk
+        # has been dispatched behind the prefill (``_turn`` fetches it
+        # then, ahead of the chunk), unless the request can't enter a
+        # slot at all. Checked BEFORE the draft prefill — a
+        # max_tokens<=1 request never decodes, so filling a draft cache
+        # for it (plus possibly a fresh bucket compile) would be pure
+        # waste.
         if req.max_tokens <= 1:
             tok = int(first)
             req._emit(tok)
@@ -1281,10 +1288,11 @@ class DecodeEngine:
 
     def _turn(self) -> bool:
         """One turn of the loop: admit, then (if anything decodes)
-        dispatch a chunk, fetch it, emit. Each phase is a child span of
-        the caller's ``engine.turn`` and they tile it; a phase that
-        fails closes with status ``error``. False: the loop must exit
-        (stop sentinel, or the engine failed)."""
+        dispatch a chunk, fetch and emit the first token of each
+        prefill this turn admitted, fetch the chunk, emit. Each phase
+        is a child span of the caller's ``engine.turn`` and they tile
+        it; a phase that fails closes with status ``error``. False: the
+        loop must exit (stop sentinel, or the engine failed)."""
         with hot_span("engine.admit"):
             if self._admitting is not None:
                 # one prefill part per loop turn: active slots get a
@@ -1356,19 +1364,26 @@ class DecodeEngine:
                 self._decode_greedy_fn if all_greedy else self._decode_fn
             )
             weights = (self.params, self.lora)
+        pending, self._pending_first = self._pending_first, []
         try:
             with hot_span("engine.dispatch", program=program):
                 self._state, (toks, mask) = chunk_fn(weights, self._state)
             if self._spec_fn is not None:
                 self.spec_rounds += self.spec_rounds_per_call
-            pending = self._pending_first
-            self._pending_first = []
-            # the host waiting for the device: the chunk's tokens and
-            # the first tokens of the prefills dispatched before it
-            with hot_span("engine.fetch", first_tokens=len(pending)):
-                toks, mask, firsts, moe_stats = jax.device_get((
-                    toks, mask, [f for (_r, f, _s) in pending],
-                    self._state["cache"].get("moe_stats"),
+            for req, first, slot in pending:
+                # the device runs its programs in order: this token
+                # exists when the request's own prefill ends, with the
+                # turn's later prefills and the chunk running behind
+                # it. The host waits for that and no longer, and
+                # streams it
+                with hot_span("engine.fetch", first_tokens=1):
+                    tok = int(jax.device_get(first))
+                with hot_span("engine.emit", first_tokens=1):
+                    self._emit_first(req, tok, slot)
+            # the host waiting for the device: the chunk's tokens
+            with hot_span("engine.fetch"):
+                toks, mask, moe_stats = jax.device_get((
+                    toks, mask, self._state["cache"].get("moe_stats"),
                 ))
         except Exception as e:  # noqa: BLE001 — state integrity unknown
             self._fail_engine(e)
@@ -1380,7 +1395,7 @@ class DecodeEngine:
                 self.moe_dropped += int(moe_stats[2])
             if self._window_layers and self._spec_fn is None:
                 self._count_window_blocks_skipped(mask)
-            self._emit_chunk(pending, firsts, toks, mask)
+            self._emit_chunk(toks, mask)
         return True
 
     def _count_window_blocks_skipped(self, mask) -> None:
@@ -1397,28 +1412,29 @@ class DecodeEngine:
             if req is None or not mask[slot].any():
                 continue
             # the first step's query: the newest token (a prefill's
-            # first token is still on its way here with this chunk)
-            pos = len(req.prompt) + max(len(req.tokens), 1) - 1
+            # first token was emitted ahead of this chunk's fetch)
+            pos = len(req.prompt) + len(req.tokens) - 1
             for step in range(int(mask[slot].sum())):
                 for window, layers in self._window_layers.items():
                     self.window_blocks_skipped += layers * (
                         max(pos + step - window + 1, 0) // block_k
                     )
 
-    def _emit_chunk(self, pending, firsts, toks, mask) -> None:
-        for (preq, _f, pslot), tok in zip(pending, firsts):
-            tok = int(tok)
-            preq._emit(tok)
-            self._observe_emit(preq)
-            self.tokens_emitted += 1
-            if tok == preq.eos_id:
-                preq._finish()
-                # free the slot on device: its chunk emissions are
-                # masked off by the active flag at the next update
-                self._state["active"] = (
-                    self._state["active"].at[pslot].set(False)
-                )
-                self._slot_req[pslot] = None
+    def _emit_first(self, req: _Request, tok: int, slot: int) -> None:
+        """Stream the first token of a prefill this turn admitted,
+        ahead of the fetch of the chunk dispatched behind it."""
+        req._emit(tok)
+        self._observe_emit(req)
+        self.tokens_emitted += 1
+        self.first_tokens_early += 1
+        if tok == req.eos_id:
+            # the prefill itself left the slot inactive on the device
+            # (``_write_slot_state``): the chunk behind it emits
+            # nothing for it, and the host frees it here
+            req._finish()
+            self._slot_req[slot] = None
+
+    def _emit_chunk(self, toks, mask) -> None:
         self.decode_steps += (
             self.spec_rounds_per_call
             if self._spec_fn is not None

@@ -17,6 +17,7 @@ import pytest
 from odh_kubeflow_tpu.models import LlamaConfig, init_params
 from odh_kubeflow_tpu.models.engine import DecodeEngine
 from odh_kubeflow_tpu.models.generate import GenerateConfig, generate
+from odh_kubeflow_tpu.utils import tracing
 
 
 @pytest.fixture(scope="module")
@@ -510,5 +511,221 @@ def test_short_prompts_pass_a_long_one_waiting_for_the_lane(model):
         for prompt, toks in zip([long_a, long_b] + shorts, got):
             alone = engine.submit(prompt, max_tokens=4).result(timeout=300)
             assert toks == alone
+    finally:
+        engine.stop()
+
+
+class _Late:
+    """A device value that reaches the host ``delay`` seconds after it
+    is asked for: what ``jax.device_get`` sees of a slow decode chunk."""
+
+    def __init__(self, value, delay):
+        self.value, self.delay = value, delay
+
+    def __array__(self, *args, **kwargs):
+        time.sleep(self.delay)
+        return np.asarray(self.value)
+
+
+def test_first_token_is_streamed_before_its_turns_chunk_is_fetched(model):
+    """A prefill's first token exists when the prefill ends. With the
+    chunk dispatched behind it made slow on purpose, the token is
+    stamped before the host starts waiting for that chunk, and the
+    chunk's tokens follow a whole chunk later."""
+    cfg, params = model
+    delay = 0.4
+    engine = DecodeEngine(
+        params, cfg, n_slots=2, max_len=128, chunk=4,
+        prompt_buckets=(16,), cache_dtype=jnp.float32,
+    )
+    ring = tracing.SpanCollector()
+    try:
+        engine.submit([5, 9, 13], max_tokens=6).result(timeout=300)  # compiles
+        fast = engine._decode_greedy_fn
+
+        def slow_chunk(*a):
+            state, (toks, mask) = fast(*a)
+            return state, (_Late(toks, delay), mask)
+
+        engine._decode_greedy_fn = slow_chunk
+        old = tracing.set_collector(ring)
+        try:
+            req = engine.submit([5, 9, 13], max_tokens=6, stream=True)
+            stream = req.iter_tokens(timeout=300)
+            first = next(stream)
+            got_first_at = time.monotonic()
+            rest = list(stream)
+        finally:
+            tracing.set_collector(old)
+            engine._decode_greedy_fn = fast
+    finally:
+        engine.stop()
+    assert [first] + rest == _reference_greedy(params, cfg, [5, 9, 13], 6)
+    # the turn that admitted it: admit, dispatch, then the first tokens'
+    # fetch and emit, then the chunk's fetch
+    early, chunk = sorted(
+        ring.spans_named("engine.fetch"), key=lambda s: s.start_mono
+    )[:2]
+    assert early.attrs == {"first_tokens": 1} and not chunk.attrs
+    assert early.trace_id == chunk.trace_id
+    assert early.start_mono <= req.times[0] <= chunk.start_mono
+    assert chunk.duration >= delay
+    # the client had the token while the chunk was still on its way
+    assert got_first_at < chunk.start_mono + delay
+    assert req.times[1] - req.times[0] >= delay
+    assert engine.first_tokens_early == 2
+
+
+def test_first_token_does_not_wait_for_a_later_prefill_of_its_turn(model):
+    """Two requests admitted in one turn: the first one's token is
+    streamed when ITS prefill ends, not when the prefill dispatched
+    after it (here made slow on purpose) does."""
+    cfg, params = model
+    delay = 0.4
+    engine = DecodeEngine(
+        params, cfg, n_slots=3, max_len=128, chunk=4,
+        prompt_buckets=(16,), cache_dtype=jnp.float32,
+    )
+    try:
+        engine.submit([5, 9, 13], max_tokens=6).result(timeout=300)  # compiles
+        chunk_fn, prefill_fn = engine._decode_greedy_fn, engine._prefill_fns[16]
+        chunks, prefills = [], []
+
+        def held_chunk(*a):
+            # the loop stays in this dispatch while both requests queue up
+            chunks.append(None)
+            time.sleep(0.3 if len(chunks) == 1 else 0.0)
+            return chunk_fn(*a)
+
+        def third_prefill_is_slow(*a):  # running's, a's, then b's
+            state, first = prefill_fn(*a)
+            prefills.append(None)
+            return state, (_Late(first, delay) if len(prefills) == 3 else first)
+
+        engine._decode_greedy_fn = held_chunk
+        engine._prefill_fns[16] = third_prefill_is_slow
+        running = engine.submit([7, 7, 7], max_tokens=30)
+        while not chunks:
+            time.sleep(0.002)
+        a = engine.submit([5, 9, 13], max_tokens=5)
+        b = engine.submit([4, 4, 4], max_tokens=5)
+        got = [r.result(timeout=300) for r in (running, a, b)]
+    finally:
+        engine.stop()
+    assert got[1] == _reference_greedy(params, cfg, [5, 9, 13], 5)
+    assert got[2] == _reference_greedy(params, cfg, [4, 4, 4], 5)
+    # one turn admitted both (their prefills follow each other) ...
+    assert a.admit_t < b.admit_t < a.times[0]
+    # ... and a's token did not wait for b's
+    assert b.times[0] - a.times[0] >= delay
+    assert engine.first_tokens_early == 4
+
+
+@pytest.mark.parametrize(
+    "kind", ["whole", "prefix_hit", "in_parts", "draft", "eos_first"]
+)
+def test_streamed_tokens_are_generates_for_every_admission(model, kind):
+    """Whatever way a request reaches its slot, the tokens its client
+    streams are ``generate()``'s, in order, the first among them; a
+    first token that is the request's eos ends the stream there and
+    frees the slot."""
+    cfg, params = model
+    system = [3 + (i % 11) for i in range(16)]
+    prompts = {
+        "whole": [[5, 9, 13], list(range(3, 40))],
+        "prefix_hit": [system + [7, 9, 2], system + [5, 1]],
+        "in_parts": [list(range(3, 53)), [5, 9, 13]],
+        "draft": [list(range(3, 40)), [7] * 10],
+        "eos_first": [[5, 6, 7], [5, 6, 7, 8]],
+    }[kind]
+    n = 10
+    want = [_reference_greedy(params, cfg, p, n) for p in prompts]
+    eos = [None, None]
+    if kind == "eos_first":
+        eos[0] = want[0][0]
+        want[0] = want[0][:1]
+        want[1] = _reference_greedy(params, cfg, prompts[1], n, eos_id=eos[0])
+    engine = DecodeEngine(
+        params, cfg, n_slots=2, max_len=256, chunk=4,
+        prompt_buckets=(16, 64), cache_dtype=jnp.float32,
+        **{
+            "prefix_hit": dict(prefix_cache_entries=2, prefix_buckets=(16,)),
+            "in_parts": dict(prefill_chunk=16),
+            "draft": dict(draft_params=params, draft_cfg=cfg, spec_k=3),
+        }.get(kind, {}),
+    )
+    try:
+        if kind == "prefix_hit":
+            # one after the other: the second finds the first's prefix
+            got, reqs = [], []
+            for p in prompts:
+                reqs.append(engine.submit(p, max_tokens=n, stream=True))
+                got.append(list(reqs[-1].iter_tokens(timeout=300)))
+            assert engine.prefix_hits == 1
+        else:
+            reqs = [
+                engine.submit(p, max_tokens=n, eos_id=e, stream=True)
+                for p, e in zip(prompts, eos)
+            ]
+            got = [list(r.iter_tokens(timeout=300)) for r in reqs]
+        assert got == want, (got, want)
+        for r, toks in zip(reqs, got):
+            assert r.tokens == toks and len(r.times) == len(toks)
+            assert r.times == sorted(r.times)
+        assert engine.first_tokens_early == len(prompts)
+        if kind == "eos_first":
+            # nothing after the eos, and the slot serves the next request
+            assert reqs[0].done.wait(timeout=300)
+            assert reqs[0].token_q.empty() and reqs[0].finish_t is not None
+            deadline = time.monotonic() + 300
+            while any(engine._slot_req) and time.monotonic() < deadline:
+                time.sleep(0.005)
+            assert engine._slot_req == [None, None]
+            for _ in range(2):  # both slots, the freed one among them
+                again = engine.submit(prompts[1], max_tokens=n)
+                assert again.result(timeout=300) == _reference_greedy(
+                    params, cfg, prompts[1], n
+                )
+        if kind == "in_parts":
+            assert engine.prefill_calls == 4 + 1  # 16+16+16+2, and the short
+    finally:
+        engine.stop()
+
+
+def test_first_tokens_early_counts_the_requests_that_reached_a_slot(model):
+    """``first_tokens_early`` is the requests admitted to a slot with
+    more than one token to make: not one that asks for a single token
+    (fetched at its admission), not one cancelled in the queue."""
+    cfg, params = model
+    engine = DecodeEngine(
+        params, cfg, n_slots=2, max_len=128, chunk=4,
+        prompt_buckets=(16, 64), cache_dtype=jnp.float32, prefill_chunk=32,
+    )
+    try:
+        asked = [1, 2, 5, 1, 9, 3]
+        reqs = [
+            engine.submit([4 + i, 5, 6], max_tokens=n)
+            for i, n in enumerate(asked)
+        ]
+        reqs.append(engine.submit(list(range(3, 48)), max_tokens=1))
+        reqs.append(engine.submit(list(range(3, 48)), max_tokens=4))
+        for r in reqs:
+            r.result(timeout=300)
+        # both slots busy: the next request waits in the queue, and is
+        # cancelled there
+        busy = [engine.submit([11 + i] * 5, max_tokens=30) for i in range(2)]
+        while sum(r is not None for r in engine._slot_req) < 2:
+            time.sleep(0.005)
+        gone = engine.submit([2, 4, 6], max_tokens=8)
+        gone.cancel()
+        for r in busy:
+            r.result(timeout=300)
+        assert gone.done.wait(timeout=300) and not gone.tokens
+        early = sum(n > 1 for n in asked) + 1 + len(busy)
+        assert engine.first_tokens_early == early
+        assert [len(r.tokens) for r in reqs] == asked + [1, 4]
+        assert engine.tokens_emitted == sum(
+            len(r.tokens) for r in reqs + busy if r.max_tokens > 1
+        )
     finally:
         engine.stop()
