@@ -7,7 +7,7 @@
 # the cwd lands on sys.path instead.
 PYTHON ?= python
 
-.PHONY: all test test-unit test-manifests lint sanitize chaos durability explore fleetbench replicabench partitionbench overloadbench zonedrill usagebench warmbench obs loadtest images bench chip-smoke dryrun platform serve spawn-latency suspend-bench webbench native kind-smoke conformance
+.PHONY: all test test-unit test-manifests lint sanitize chaos durability explore fleetbench replicabench partitionbench overloadbench zonedrill usagebench warmbench obs loadtest images chip-smoke dryrun platform serve spawn-latency suspend-bench webbench native kind-smoke conformance
 
 all: lint test
 
@@ -35,7 +35,7 @@ conformance:
 # cross-checks every os.environ knob against analysis/knobs.json,
 # GUIDE.md, and manifest env stanzas.
 lint:
-	$(PYTHON) -m compileall -q odh_kubeflow_tpu tests loadtest bench.py chip_smoke.py __graft_entry__.py
+	$(PYTHON) -m compileall -q odh_kubeflow_tpu tests loadtest chip_smoke.py __graft_entry__.py
 	$(PYTHON) -m odh_kubeflow_tpu.analysis
 	$(PYTHON) -m odh_kubeflow_tpu.analysis.knobs
 	$(PYTHON) -m odh_kubeflow_tpu.analysis.protocol
@@ -230,9 +230,6 @@ native:
 
 images:
 	$(MAKE) -C images build
-
-bench:
-	$(PYTHON) bench.py
 
 # the quickest proof that the system still starts on the chip: trainer
 # + completion server at full Llama-3.2-1B size, one process, one

@@ -582,7 +582,7 @@ def main() -> int:
     import jaxlib
 
     from odh_kubeflow_tpu.models import LlamaConfig
-    from odh_kubeflow_tpu.warmup.compilecache import install_process_cache
+    from odh_kubeflow_tpu.utils.compile_cache import install_process_cache
 
     assert_kernels_compile()
     # the kernels phase compiles before any Trainer or engine exists:

@@ -51,6 +51,7 @@ from odh_kubeflow_tpu.models.generate import (
 )
 from odh_kubeflow_tpu.models.llama import LlamaConfig
 from odh_kubeflow_tpu.utils import prometheus, tracing
+from odh_kubeflow_tpu.utils.compile_cache import install_process_cache
 from odh_kubeflow_tpu.utils.profiling import hot_span
 
 Params = dict[str, Any]
@@ -331,8 +332,6 @@ class DecodeEngine:
         # compiles after the train step. The directory is placed from
         # outside (JAX_COMPILATION_CACHE_DIR) or is the fixed
         # in-checkout one — never chosen here.
-        from odh_kubeflow_tpu.warmup.compilecache import install_process_cache
-
         install_process_cache()
 
         self.params = params

@@ -191,16 +191,6 @@ class LlamaConfig:
         embed = 0  # lookup, not a matmul
         return L * (proj + attn) + head + embed
 
-    def attn_flops_per_token(self, seq_len: int) -> float:
-        """The quadratic (qk^T + av) share of ``flops_per_token`` —
-        split out so training-FLOPs accounting can treat weight matmuls
-        (whose dW is skipped when the base is frozen) differently from
-        attention (whose backward is required work regardless)."""
-        return (
-            self.num_layers
-            * 2 * 2 * self.num_heads * self.head_dim * (seq_len / 2)
-        )
-
 
 # ---------------------------------------------------------------------------
 # init

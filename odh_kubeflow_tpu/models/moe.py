@@ -147,12 +147,6 @@ class MoeConfig:
             (self.num_experts_per_tok - 1) * mlp + router
         )
 
-    def attn_flops_per_token(self, seq_len: int) -> float:
-        """Quadratic attention share — identical to the backbone's
-        (experts replace only the MLP); used by the strict LoRA MFU
-        accounting in ``Trainer.benchmark``."""
-        return self.base.attn_flops_per_token(seq_len)
-
 
 # ---------------------------------------------------------------------------
 # params

@@ -88,7 +88,8 @@ class CompletionService:
         self.max_compiled = 32
         # continuous batching (models/engine.py): concurrent requests
         # join a persistent slot-batched decode loop instead of
-        # serialising behind the lock (loadtest/continuous_batching.py).
+        # serialising behind the lock (measured by the benchmark's
+        # serving cells, which build the engine themselves).
         # Off (0) takes the one-shot bucketed path for every request.
         self.engine = None
         if engine_slots > 0:

@@ -119,8 +119,8 @@ def span_pairs(group_offsets: jnp.ndarray, m: int, bm: int,
     issues no DMA for them, and the kernels' ``pl.when(live)`` guard
     skips their dots — before this, every pad burned a full fetch plus
     a masked dot, E/(T+E) ≈ 19% of the grid at the 8×1B kernel-B shape
-    (measured: the bulk of kernel B's gap to the dense padded-dot
-    bound, ``loadtest/gmm_microbench.py``). With ``include_empty``,
+    (a count from the shapes; no cell times this kernel: PERF.md
+    section 7). With ``include_empty``,
     zero-size groups still get a live pair (tgmm must write zeros to
     their gradient block); without it they are skipped (kernel B
     writes rows, and empty groups own none).

@@ -81,7 +81,7 @@ def int4_dequant(packed, scale, dtype=jnp.bfloat16, *, group=128,
 # ---------------------------------------------------------------------------
 #
 # x[M, K] · W[K, N] where W lives as {q4 [K/2, N] uint8 (split-halves),
-# scale4 [K/group, N] f32}. The r4 finding (BASELINE.md wall list):
+# scale4 [K/group, N] f32}. A pre-round finding (round 4, not re-measured):
 # int4-with-in-graph-dequant frees 4GB of HBM but materialising the
 # bf16 weight per consumer eats the win. Here the unpack + group scale
 # happen on the accumulator in VMEM — weights cross HBM packed (0.5

@@ -8,7 +8,8 @@ One jitted ``train_step`` compiled against a ``jax.sharding.Mesh``:
   hand-written pmap/all-reduce anywhere.
 
 This is the workload behind BASELINE.json's north-star metric (Llama-3-8B
-LoRA on a v5p-8 notebook at >=50% MFU) and is what ``bench.py`` times.
+LoRA on a v5p-8 notebook at >=50% MFU); ``benchmark/run.py`` times it
+in the training cells of ``BENCHMARK.json``.
 """
 
 from __future__ import annotations
@@ -26,8 +27,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from odh_kubeflow_tpu.models import llama, lora as lora_lib
 from odh_kubeflow_tpu.parallel.mesh import batch_spec, build_mesh, constrain
 from odh_kubeflow_tpu.utils import prometheus
+from odh_kubeflow_tpu.utils.compile_cache import install_process_cache
 from odh_kubeflow_tpu.utils.profiling import hot_span
-from odh_kubeflow_tpu.warmup.compilecache import install_process_cache
 
 Params = dict[str, Any]
 
@@ -702,44 +703,4 @@ class Trainer:
         return {
             "tokens": jax.device_put(tokens, sharding),
             "targets": jax.device_put(targets, sharding),
-        }
-
-    def benchmark(
-        self, batch_size: int, seq_len: int, steps: int = 10, warmup: int = 2
-    ) -> dict:
-        batch = self.make_fake_batch(batch_size, seq_len)
-        # float(loss) fetches the last step's loss to the host, which
-        # waits for every step queued before it: the timed window
-        # starts and ends on a drained device.
-        for _ in range(max(warmup, 1)):  # >=1: keep compile out of timing
-            metrics = self.train_step(batch)
-        float(metrics["loss"])
-        t0 = time.perf_counter()
-        for _ in range(steps):
-            metrics = self.train_step(batch)
-        loss = float(metrics["loss"])
-        dt = (time.perf_counter() - t0) / steps
-        tokens = batch_size * seq_len
-        # Useful-FLOPs accounting (strict MFU, the PaLM-paper sense):
-        # - full fine-tune: fwd + bwd ≈ 3× forward (dx + dW per matmul);
-        # - LoRA / frozen base: dW of every frozen matmul is *not*
-        #   computed, so weight matmuls cost 2× (fwd + dx) — but the
-        #   attention backward (dQ/dK/dV) is required to reach the
-        #   adapters upstream, so the quadratic term still counts 3×.
-        # Rematerialisation recompute is never credited; the 3×-based
-        # figure is additionally reported as train_equiv_flops_per_s
-        # (the 6ND convention most cited "LoRA MFU" numbers use).
-        fpt = self.model_cfg.flops_per_token(seq_len)
-        if self.lora_cfg is not None:
-            attn_fpt = self.model_cfg.attn_flops_per_token(seq_len)
-            flops = (2 * fpt + attn_fpt) * tokens
-        else:
-            flops = 3 * fpt * tokens
-        return {
-            "step_time_s": dt,
-            "tokens_per_s": tokens / dt,
-            "model_flops_per_step": flops,
-            "flops_per_s": flops / dt,
-            "train_equiv_flops_per_s": 3 * fpt * tokens / dt,
-            "loss": loss,
         }

@@ -1,6 +1,6 @@
 """TPU device introspection: peak-FLOPs table for MFU accounting and
-generation→topology metadata used by both bench.py and the platform's
-spawner config (``web/jwa``: accelerator type + topology dropdowns)."""
+generation→topology metadata used by the platform's spawner config
+(``web/jwa``: accelerator type + topology dropdowns)."""
 
 from __future__ import annotations
 

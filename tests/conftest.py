@@ -16,7 +16,7 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 # Tests get NO persistent compilation cache — not this process, not a
 # child it starts (jax reads the variable at import). Trainer and
 # DecodeEngine would otherwise join the checkout's .jax_compile_cache
-# (warmup.compilecache.install_process_cache), and a test run must not
+# (utils.compile_cache.install_process_cache), and a test run must not
 # read what an earlier run wrote there, nor pay disk writes tier-1 has
 # no time for. tests/test_warmup.py checks the directory rule itself,
 # which needs no live cache.

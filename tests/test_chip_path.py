@@ -1,8 +1,9 @@
 """The chip path has no fallback that hides the device (PR 21).
 
-CPU checks of what only matters on a machine with a TPU: the launchers
-refuse to run without one, an unknown device has no peak, and the
-control plane leaves the accelerator to the process that needs it.
+CPU checks of what only matters on a machine with a TPU: the launcher
+refuses to run without one, an unknown device has no peak, the control
+plane leaves the accelerator to the process that needs it, and the
+chip half does not import the control plane.
 (The AOT contract is in tests/test_trainer.py, the dead-engine one in
 tests/test_serve.py, the cache-directory rule in tests/test_warmup.py.)
 """
@@ -36,18 +37,6 @@ def test_chip_smoke_refuses_to_run_without_a_tpu():
     assert "no chip found" in out.stderr
     assert "JAX_PLATFORMS='cpu'" in out.stderr
     assert out.stdout.strip() == ""
-
-
-def test_bench_refuses_to_run_without_a_tpu(capsys):
-    """No CPU row under a device metric's name: non-zero, no JSON."""
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.remove(REPO)
-    assert bench.main() == 1
-    captured = capsys.readouterr()
-    assert "no TPU" in captured.err and captured.out.strip() == ""
 
 
 def test_peak_flops_raises_on_unknown_device_kind():
@@ -92,3 +81,32 @@ def test_control_plane_initialises_no_jax_backend():
     assert out.returncode == 0, out.stderr[-3000:]
     assert out.stdout.strip().endswith("clean")
 
+
+_CONTROL_PLANE = tuple(
+    f"odh_kubeflow_tpu.{name}."
+    for name in (
+        "machinery", "analysis", "controllers", "sessions", "scheduling",
+        "web", "webhooks", "warmup",
+    )
+)
+
+
+@pytest.mark.parametrize(
+    "module",
+    ["odh_kubeflow_tpu.models.engine", "odh_kubeflow_tpu.train.trainer"],
+)
+def test_chip_half_imports_no_control_plane(module):
+    """The other direction: a serving or training process joins the
+    compile cache through a leaf (``utils/compile_cache.py``) and loads
+    neither the store, the WAL's neighbours nor the linter."""
+    probe = (
+        "import importlib, sys\n"
+        f"importlib.import_module({module!r})\n"
+        "loaded = sorted(m for m in sys.modules\n"
+        f"                if (m + '.').startswith({_CONTROL_PLANE!r}))\n"
+        "assert not loaded, loaded\n"
+        "print('clean')\n"
+    )
+    out = _run(["-c", probe], JAX_PLATFORMS="cpu")
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert out.stdout.strip().endswith("clean")

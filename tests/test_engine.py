@@ -3,8 +3,8 @@
 Greedy output must equal the one-shot ``generate()`` path token for
 token (same model, same cache semantics, different batching), mixed
 sampling params must coexist in one decode program, and staggered
-arrivals must beat serial request handling by the VERDICT criterion
-(>1.5× aggregate tok/s).
+arrivals must share decode steps (the rate that buys is a cell's to
+measure, not a CPU test's).
 """
 
 import time
@@ -120,10 +120,10 @@ def test_staggered_arrivals_share_decode_steps(model):
     provable: with staggered overlapping arrivals, the engine must
     spend far fewer decode steps than serial handling (which pays
     max_tokens steps PER request) — ≥2 tokens per decode step here.
-    The wall-clock >1.5× tok/s half is decode-cost-model dependent
-    (weight-streaming-bound on TPU, compute-bound on this CPU tiny
-    model) and is measured on the real chip by
-    ``loadtest/continuous_batching.py`` (recorded in BASELINE.md)."""
+    The wall-clock half is decode-cost-model dependent (weight-
+    streaming-bound on TPU, compute-bound on this CPU tiny model): on
+    the chip the cell ``mistral7b-chat-saturated`` measures it
+    (``serve_tokens_per_s``, ``slot_occupancy.saturated``)."""
     cfg, params = model
     N_REQ, MAX_TOK = 6, 32
     prompts = [[3 + i, 8, 2] for i in range(N_REQ)]
@@ -152,9 +152,9 @@ def test_staggered_arrivals_share_decode_steps(model):
     # steps across runs for the 192-step serial equivalent; a 0.8×
     # steps ceiling — and a 1.2 tokens/step floor — both flaked under
     # full-suite load at the 176-step worst case). Any tokens/step > 1
-    # proves the slots share decode steps; the tight quantitative
-    # claim (5.3 tokens/step, 3.59x tok/s at 8 slots) is measured on
-    # the real chip by loadtest/continuous_batching.py → BASELINE.md.
+    # proves the slots share decode steps; how many tokens a step
+    # carries on the chip is the cell mistral7b-chat-saturated's
+    # slot_occupancy.saturated (PERF_LEDGER.jsonl).
     assert engine.tokens_emitted / steps > 1.0, (
         engine.tokens_emitted, steps, serial_steps
     )
