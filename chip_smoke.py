@@ -8,9 +8,10 @@ bf16; random weights from a seed), in ONE process on ONE device:
 
 1. *kernels* — the three flash-attention kernels (forward, dq, dkv) at
    the model's head shape (32 q / 8 kv heads × 64), with and without
-   ``segment_ids``, compiled by Mosaic and compared with the XLA
-   ``dense_attention`` reference (the comparison tests/ makes in
-   interpret mode on the CPU);
+   ``segment_ids`` and on a 4096-long row of ``pack_documents`` (the
+   walk built from the row's ids), compiled by Mosaic and compared with
+   the XLA ``dense_attention`` reference (the comparison tests/ makes
+   in interpret mode on the CPU);
 2. *train* — ``Trainer`` with LoRA r16 at batch 8 × seq 1024: steps on
    one repeated ``make_fake_batch`` (loss finite and falling), then on
    batches from ``train.data.pack_documents`` (``segment_ids`` and
@@ -110,27 +111,13 @@ def assert_kernels_compile() -> None:
             )
 
 
-def check_kernels(cfg) -> dict:
-    """Flash fwd + bwd against the dense reference at the training
-    leg's geometry (one 1024 block), with and without segment walls.
-    bf16 operands, so agreement is norm-wise: about 1e-2 on the chip,
-    4e-3 in interpret mode, O(1) when a kernel is wrong — bound 5e-2."""
+def _flash_against_dense(q, k, v, tangent, cases: dict) -> dict:
+    """Flash forward and all three gradients against ``dense_attention``
+    for each named ``segment_ids`` (None: a plain causal call). bf16
+    operands, so agreement is norm-wise: about 1e-2 on the chip, 4e-3 in
+    interpret mode, O(1) when a kernel is wrong — bound 5e-2."""
     from odh_kubeflow_tpu.ops import pallas_attention
     from odh_kubeflow_tpu.ops.attention import dense_attention
-
-    B, S = 2, SEQ
-    kq, kk, kv, kt = jax.random.split(jax.random.key(7), 4)
-    shape_q = (B, S, cfg.num_heads, cfg.head_dim)
-    shape_kv = (B, S, cfg.num_kv_heads, cfg.head_dim)
-    q = jax.random.normal(kq, shape_q, jnp.bfloat16)
-    k = jax.random.normal(kk, shape_kv, jnp.bfloat16)
-    v = jax.random.normal(kv, shape_kv, jnp.bfloat16)
-    tangent = jax.random.normal(kt, shape_q, jnp.bfloat16)
-    # three documents a row, walls off any block grid
-    pos = jnp.arange(S)[None, :]
-    seg = (
-        1 + (pos >= (S * 3) // 10) + (pos >= (S * 3) // 4 + 9)
-    ).astype(jnp.int32) * jnp.ones((B, 1), jnp.int32)
 
     # operands travel as arguments: an array closed over by a jitted
     # function is lowered as a literal constant of the program
@@ -149,7 +136,7 @@ def check_kernels(cfg) -> dict:
     )
     dense = jax.jit(functools.partial(fwd_and_grads, dense_attention))
     errs = {}
-    for name, segment_ids in (("plain", None), ("segments", seg)):
+    for name, segment_ids in cases.items():
         got = flash(q, k, v, tangent, segment_ids)
         with jax.default_matmul_precision("highest"):
             ref = dense(q, k, v, tangent, segment_ids)
@@ -161,9 +148,63 @@ def check_kernels(cfg) -> dict:
                     f"flash {name} {part} disagrees with dense_attention: "
                     f"relative error {err}"
                 )
+    return errs
+
+
+def _qkv_tangent(cfg, B: int, S: int):
+    kq, kk, kv, kt = jax.random.split(jax.random.key(7), 4)
+    shape_q = (B, S, cfg.num_heads, cfg.head_dim)
+    shape_kv = (B, S, cfg.num_kv_heads, cfg.head_dim)
+    return (
+        jax.random.normal(kq, shape_q, jnp.bfloat16),
+        jax.random.normal(kk, shape_kv, jnp.bfloat16),
+        jax.random.normal(kv, shape_kv, jnp.bfloat16),
+        jax.random.normal(kt, shape_q, jnp.bfloat16),
+    )
+
+
+def check_kernels(cfg) -> dict:
+    """Flash fwd + bwd against the dense reference: at the training
+    leg's geometry (one 1024 block), with and without segment walls, and
+    on one 4096-long row of ``pack_documents`` (four 1024 blocks walked
+    in tiles from the row's own tables: a document over two block edges,
+    walls inside blocks, one wall on a tile's edge), whose live tile
+    count must come out below its causal count."""
+    import numpy as np
+
+    from odh_kubeflow_tpu.ops import pallas_attention
+    from odh_kubeflow_tpu.train.data import pack_documents
+
+    B, S = 2, SEQ
+    # three documents a row, walls off any block grid
+    pos = jnp.arange(S)[None, :]
+    seg = (
+        1 + (pos >= (S * 3) // 10) + (pos >= (S * 3) // 4 + 9)
+    ).astype(jnp.int32) * jnp.ones((B, 1), jnp.int32)
+    errs = _flash_against_dense(
+        *_qkv_tangent(cfg, B, S), {"plain": None, "segments": seg}
+    )
+
+    long_s = 4 * pallas_attention.DEFAULT_BLOCK_Q
+    lengths = (700, 1500, 360, 1024, long_s - 3584)
+    docs = [np.full(n, 1 + i, np.int32) for i, n in enumerate(lengths)]
+    (row,) = pack_documents(docs, 1, long_s)
+    packed = jnp.asarray(row["segment_ids"])
+    errs.update(_flash_against_dense(
+        *_qkv_tangent(cfg, 1, long_s), {"packed4k": packed}
+    ))
+    live, causal = pallas_attention.live_block_counts(packed)
+    if not 0 < int(live) < causal:
+        raise AssertionError(
+            f"a packed row's flash walk is not below the causal one: "
+            f"{int(live)} live tiles of {causal}"
+        )
     errs.update(check_decode_attend(cfg))
     errs.update(check_ring_and_held_experts())
-    return {"rel_err_vs_dense": errs}
+    return {
+        "rel_err_vs_dense": errs,
+        "packed4k_flash_tiles": {"live": int(live), "causal": causal},
+    }
 
 
 def check_decode_attend(cfg) -> dict:

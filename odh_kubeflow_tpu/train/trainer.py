@@ -14,6 +14,7 @@ in the training cells of ``BENCHMARK.json``.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import time
 from functools import partial
@@ -21,6 +22,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -309,6 +311,11 @@ class Trainer:
         # jit's (which compiles on its first call with a shape)
         self.aot_steps = 0
         self.lazy_steps = 0
+        # what the segmented flash kernels walked, over all steps whose
+        # results have arrived: [tiles run, tiles the causal geometry
+        # alone would run] (ops/pallas_attention.live_block_counts)
+        self._flash_blocks = [0, 0]
+        self._flash_pending: collections.deque = collections.deque()
         self._compiled = self._build_step()
         self._aot: dict = {}
         self._aot_threads: dict = {}
@@ -488,7 +495,21 @@ class Trainer:
                 )
                 trainable = optax.apply_updates(trainable, updates)
             gnorm = optax.global_norm(grads)
-            return trainable, opt_state, {"loss": loss, "grad_norm": gnorm}
+            metrics = {"loss": loss, "grad_norm": gnorm}
+            cfg = self.model_cfg.base if self.is_moe else self.model_cfg
+            if (
+                "segment_ids" in batch
+                and llama.resolved_attention_impl(cfg) == "flash"
+            ):
+                # from the same function that builds the kernels' tables;
+                # rides home beside the loss
+                from odh_kubeflow_tpu.ops.pallas_attention import (
+                    live_block_counts,
+                )
+
+                live, causal = live_block_counts(batch["segment_ids"])
+                metrics["flash_blocks"] = jnp.stack([live, jnp.int32(causal)])
+            return trainable, opt_state, metrics
 
         train_sh = self._sh(self._train_specs)
         # frozen tree shards as initialised (quantized or not); on the
@@ -605,20 +626,46 @@ class Trainer:
             raise exe
         return exe
 
+    def _fold_flash_blocks(self) -> list:
+        """Add the counts of the steps that have ended to the totals.
+        Never waits: a step in flight is folded in by a later call."""
+        pending = self._flash_pending
+        while pending and pending[0].is_ready():
+            for i, n in enumerate(np.asarray(pending.popleft())):
+                self._flash_blocks[i] += int(n)
+        return self._flash_blocks
+
+    @property
+    def flash_blocks_live(self) -> int:
+        """Tiles the flash kernels ran, a head and a layer, over the
+        steps on packed rows that have ended."""
+        return self._fold_flash_blocks()[0]
+
+    @property
+    def flash_blocks_walked(self) -> int:
+        """Tiles the causal walk alone would have run for them."""
+        return self._fold_flash_blocks()[1]
+
     def train_step(self, batch: dict) -> dict:
         """One optimizer step, recorded as ``trainer.step`` (host time
         inside this call) over ``trainer.aot_wait`` (the join on the
         compile thread, first step of a shape only), ``trainer.h2d``
         (the batch's ``device_put``) and ``trainer.dispatch`` (the
-        executable's call; the lazy jit compiles inside it)."""
+        executable's call; the lazy jit compiles inside it). On packed
+        rows under flash the span carries ``flash_live_share``:
+        ``flash_blocks_live / flash_blocks_walked`` so far."""
         t_start = time.perf_counter()
         trainable = self.lora_params if self.lora_cfg is not None else self.params
         frozen = self.params
         akey = (*batch["tokens"].shape, tuple(sorted(batch)))
         aot = akey in self._aot or akey in self._aot_threads
+        attrs = {}
+        live, walked = self._fold_flash_blocks()
+        if walked:
+            attrs["flash_live_share"] = round(live / walked, 4)
         with hot_span(
             "trainer.step", step=self.step,
-            executable="aot" if aot else "lazy",
+            executable="aot" if aot else "lazy", **attrs,
         ), jax.set_mesh(self.mesh):
             if aot:
                 exe = self.compiled_step(*akey)
@@ -638,6 +685,8 @@ class Trainer:
                         trainable, frozen, self.opt_state, batch
                     )
                 self.lazy_steps += 1
+        if "flash_blocks" in metrics:
+            self._flash_pending.append(metrics["flash_blocks"])
         if self.lora_cfg is not None:
             self.lora_params = trainable
         else:

@@ -528,3 +528,35 @@ def test_spans_named_reads_ring_and_kept_store_once(collector):
     assert [s.name for s in got] == ["engine.unit", "engine.unit.child"]
     assert collector.spans_named("nothing.") == []
     assert collector.recorded_total == 3
+
+
+def test_trainer_counts_the_blocks_flash_walked(collector):
+    """On packed rows under flash the step returns the live and the
+    causal tile counts beside the loss; the trainer folds them in once
+    a step has ended and puts the share on the next ``trainer.step``."""
+    import numpy as np
+
+    from odh_kubeflow_tpu.ops import pallas_attention as pa
+
+    S = 2 * pa.SEGMENT_TILE
+    trainer = Trainer(
+        LlamaConfig.tiny(dtype=jnp.float32, attention_impl="flash"),
+        TrainConfig(learning_rate=1e-2, warmup_steps=1, total_steps=20),
+        lora_cfg=LoraConfig(rank=4),
+        mesh=build_mesh(MeshConfig(), jax.devices()[:1]),
+    )
+    batch = trainer.make_fake_batch(2, S)
+    plain = trainer.train_step(batch)
+    assert "flash_blocks" not in plain
+    assert (trainer.flash_blocks_live, trainer.flash_blocks_walked) == (0, 0)
+    # row 0 one document, row 1 two with the wall on the tile's edge
+    seg = np.ones((2, S), np.int32)
+    seg[1, pa.SEGMENT_TILE:] = 2
+    packed = dict(batch, segment_ids=seg, loss_mask=np.ones((2, S), np.float32))
+    for _ in range(2):
+        metrics = trainer.train_step(packed)
+        float(metrics["loss"])  # the step has ended
+    assert metrics["flash_blocks"].tolist() == [5, 6]
+    assert (trainer.flash_blocks_live, trainer.flash_blocks_walked) == (10, 12)
+    steps = collector.spans_named("trainer.step")
+    assert [s.attrs.get("flash_live_share") for s in steps] == [None, None, 0.8333]

@@ -262,3 +262,263 @@ def test_multiblock_non_causal_full_blocks():
         float(jnp.abs(got - ref).max())
     )
 
+
+
+# ---------------------------------------------------------------------------
+# segmented calls: live-pair tables built on the device from the ids
+# ---------------------------------------------------------------------------
+
+import numpy as np
+
+from odh_kubeflow_tpu.ops import pallas_attention as pa
+
+
+def _ids(*lengths, start=1):
+    """Ascending ids as ``pack_documents`` gives them: one id a piece."""
+    return np.concatenate(
+        [np.full(n, start + i, np.int32) for i, n in enumerate(lengths)]
+    )
+
+
+S_SEG = 512
+SEGMENT_CASES = {
+    # name: ([B, S] ids, Hq, Hkv)
+    "wall_on_block_edge": (_ids(256, 256)[None], 2, 2),
+    "document_over_blocks": (_ids(40, 400, 72)[None], 2, 2),
+    "one_token_documents": (
+        np.concatenate([_ids(200), _ids(*[1] * 112, start=2), _ids(200, start=200)])[None],
+        2, 2,
+    ),
+    "padded_tail": (np.concatenate([_ids(130, 190), np.zeros(192, np.int32)])[None], 2, 2),
+    "two_layouts": (np.stack([_ids(512), _ids(100, 28, 256, 128)]), 2, 2),
+    "gqa_group4": (np.stack([_ids(300, 212), _ids(128, 384)]), 4, 1),
+    # ids that fall and repeat: the id ranges overlap where no pair
+    # matches, so the table is a superset and the in-tile mask decides
+    "non_ascending": (
+        np.concatenate([_ids(64, start=9), _ids(64), _ids(128, start=5), _ids(256, start=20)])[None],
+        2, 2,
+    ),
+}
+SEGMENT_BLOCKS = {"block128": (128, 128), "block256_tile128": (256, 128)}
+
+
+def _brute_tables(seg, *, block, tile, order, group):
+    """The walk ``_segment_tables`` should give, enumerated pair by
+    pair in numpy, and the tiles in which some pair is really alive."""
+    B, S = seg.shape
+    nb, sub = S // block, block // tile
+    rows, exact_tiles, walked_tiles = [], 0, 0
+    for b in range(B):
+        run = np.zeros((nb, nb), np.int64)
+        full = np.zeros((nb, nb), np.int64)
+        for qt in range(S // tile):
+            for kt in range(S // tile):
+                q = seg[b, qt * tile:(qt + 1) * tile]
+                k = seg[b, kt * tile:(kt + 1) * tile]
+                causal = (
+                    np.arange(tile)[:, None] + qt * tile
+                    >= np.arange(tile)[None, :] + kt * tile
+                )
+                same = q[:, None] == k[None, :]
+                overlap = q.min() <= k.max() and k.min() <= q.max()
+                assert not ((causal & same).any() and not overlap)
+                exact_tiles += int((causal & same).any())
+                if not (causal.any() and overlap):
+                    continue
+                walked_tiles += 1
+                bit = 1 << ((qt % sub) * sub + kt % sub)
+                run[qt // sub, kt // sub] |= bit
+                if causal.all() and same.all():
+                    full[qt // sub, kt // sub] |= bit
+        entries = []
+        if order == "row":
+            for qi in range(nb):
+                ks = [ki for ki in range(nb) if run[qi, ki]] or [0]
+                for j, ki in enumerate(ks):
+                    entries.append(
+                        (qi, ki, 0, j == 0, j == len(ks) - 1, run[qi, ki], full[qi, ki])
+                    )
+        else:
+            for ki in range(nb):
+                qs = [qj for qj in range(nb) if run[qj, ki]]
+                items = [(qj, g) for g in range(group) for qj in qs] or [(0, 0)]
+                for j, (qj, g) in enumerate(items):
+                    entries.append(
+                        (qj, ki, g, j == 0, j == len(items) - 1, run[qj, ki], full[qj, ki])
+                    )
+        rows.append(entries)
+    return rows, walked_tiles, exact_tiles
+
+
+@pytest.mark.parametrize("order", ["row", "col"])
+@pytest.mark.parametrize("blocks", SEGMENT_BLOCKS)
+@pytest.mark.parametrize("case", SEGMENT_CASES)
+def test_segment_tables_match_brute_force(case, blocks, order):
+    seg, Hq, Hkv = SEGMENT_CASES[case]
+    block, tile = SEGMENT_BLOCKS[blocks]
+    group = Hq // Hkv
+    qseg, kseg = pa._prep_segments(jnp.asarray(seg), S_SEG, S_SEG, S_SEG, S_SEG)
+    geom = dict(causal=True, q_offset=0, sk=S_SEG, block_q=block, block_k=block)
+    tabs, live, causal = pa._segment_tables(
+        qseg, kseg, tile_q=tile, tile_k=tile, order=order, group=group, **geom
+    )
+    tabs = [np.asarray(t) for t in tabs]
+    static = pa._pair_tables(
+        num_q=S_SEG // block, num_k=S_SEG // block, order=order, group=group,
+        **geom,
+    )
+    assert all(t.shape == (seg.shape[0], static[0].shape[0]) for t in tabs)
+
+    want, walked, exact = _brute_tables(
+        seg, block=block, tile=tile, order=order, group=group
+    )
+    assert int(live) == walked
+    n_tiles = S_SEG // tile
+    assert causal == seg.shape[0] * n_tiles * (n_tiles + 1) // 2
+    if case in ("non_ascending", "padded_tail"):
+        # the ids fall (padding is id 0, after the last document): a
+        # superset of the tiles that hold a live pair, and still a skip
+        assert exact < walked < causal
+    else:
+        assert exact == walked  # ascending ids: the range test is exact
+    for b, entries in enumerate(want):
+        n = len(entries)
+        got = list(zip(*(t[b, :n].tolist() for t in tabs)))
+        assert got == [tuple(int(x) for x in e) for e in entries], (b, got)
+        # past the live count: the last live pair again, every flag 0
+        for t, last in zip(tabs[:3], entries[-1][:3]):
+            assert (t[b, n:] == int(last)).all()
+        for t in tabs[3:]:
+            assert (t[b, n:] == 0).all()
+
+
+@pytest.mark.parametrize("blocks", SEGMENT_BLOCKS)
+@pytest.mark.parametrize("case", SEGMENT_CASES)
+def test_segmented_forward_and_grads_match_dense(case, blocks):
+    seg, Hq, Hkv = SEGMENT_CASES[case]
+    seg = jnp.asarray(seg)
+    block, tile = SEGMENT_BLOCKS[blocks]
+    B = seg.shape[0]
+    q, k, v = _qkv(jax.random.key(30), B, S_SEG, S_SEG, Hq, Hkv, 64)
+    tangent = jax.random.normal(jax.random.key(31), (B, S_SEG, Hq, 64))
+
+    def flash(q, k, v, **kw):
+        return flash_attention(q, k, v, block_q=block, block_k=block, tile=tile, **kw)
+
+    def out_and_grads(fn):
+        def loss(q, k, v):
+            out = fn(q, k, v, causal=True, segment_ids=seg)
+            return jnp.sum(out * tangent), out
+
+        (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return out, grads
+
+    ref_out, ref_grads = out_and_grads(dense_attention)
+    got_out, got_grads = jax.jit(lambda: out_and_grads(flash))()
+    assert jnp.allclose(got_out, ref_out, atol=2e-5, rtol=2e-5), (
+        float(jnp.abs(got_out - ref_out).max())
+    )
+    for name, r, g in zip("qkv", ref_grads, got_grads):
+        assert jnp.allclose(r, g, atol=5e-4, rtol=1e-3), (
+            name, float(jnp.abs(r - g).max())
+        )
+
+
+def test_segment_tables_keep_an_owner_with_no_live_partner():
+    """Keys that hold no id of the queries: every Q block (and every K
+    block) still walks one pair, with no tile to run, and the output is
+    zero as the dense semantics have it."""
+    S = 256
+    seg_q = jnp.asarray(_ids(S)[None])
+    qseg, _ = pa._prep_segments(seg_q, S, S, S, S)
+    _, kseg = pa._prep_segments(seg_q + 7, S, S, S, S)
+    geom = dict(causal=True, q_offset=0, sk=S, block_q=128, block_k=128,
+                tile_q=128, tile_k=128)
+    for order, owners in (("row", 0), ("col", 1)):
+        tabs, live, _ = pa._segment_tables(qseg, kseg, order=order, **geom)
+        tabs = [np.asarray(t)[0] for t in tabs]
+        assert int(live) == 0
+        assert tabs[owners][:2].tolist() == [0, 1]
+        assert tabs[3][:2].tolist() == [1, 1] and tabs[4][:2].tolist() == [1, 1]
+        assert not tabs[5].any() and not tabs[6].any()
+        assert not tabs[3][2:].any() and not tabs[4][2:].any()
+
+
+def _brute_pair_tables(num_q, num_k, *, causal, q_offset, sk, block, order, group):
+    def live(qb, kb):
+        return kb * block < sk and (
+            not causal or kb * block <= qb * block + block - 1 + q_offset
+        )
+
+    out = []
+    if order == "row":
+        for qi in range(num_q):
+            ks = [kb for kb in range(num_k) if live(qi, kb)] or [0]
+            out += [(qi, kb, 0, j == 0, j == len(ks) - 1) for j, kb in enumerate(ks)]
+    else:
+        for kb in range(num_k):
+            items = [
+                (qj, kb, g) for g in range(group)
+                for qj in range(num_q) if live(qj, kb)
+            ] or [(0, kb, 0)]
+            out += [(*it, j == 0, j == len(items) - 1) for j, it in enumerate(items)]
+    return [tuple(int(x) for x in e) for e in out]
+
+
+@pytest.mark.parametrize(
+    "num_q,num_k,causal,q_offset,sk,order,group",
+    [
+        (4, 4, True, 0, 4096, "row", 1),     # the training shape, forward/dq
+        (4, 4, True, 0, 4096, "col", 4),     # and its dk/dv walk, GQA 4
+        (16, 16, True, 0, 16384, "row", 1),  # the 16k geometry
+        (2, 4, True, 2048, 4000, "col", 2),  # an offset shard, padded keys
+        (3, 3, False, 0, 3072, "row", 1),
+    ],
+)
+def test_unsegmented_tables_are_the_static_walk(
+    num_q, num_k, causal, q_offset, sk, order, group, monkeypatch
+):
+    """Without segment ids nothing changed: the tables are the host's
+    enumeration of the causal geometry at the 1024 blocks, and a call
+    asks for exactly those."""
+    geom = dict(causal=causal, q_offset=q_offset, sk=sk, block_q=1024, block_k=1024)
+    tabs = pa._pair_tables(num_q=num_q, num_k=num_k, order=order, group=group, **geom)
+    got = list(zip(*(np.asarray(t).tolist() for t in tabs)))
+    assert got == _brute_pair_tables(
+        num_q, num_k, causal=causal, q_offset=q_offset, sk=sk, block=1024,
+        order=order, group=group,
+    )
+
+
+def test_unsegmented_call_asks_for_the_static_tables(monkeypatch):
+    asked = []
+    real = pa._pair_tables
+
+    def spy(**kw):
+        asked.append(kw)
+        return real(**kw)
+
+    monkeypatch.setattr(pa, "_pair_tables", spy)
+    monkeypatch.setattr(
+        pa, "_segment_tables", lambda *a, **kw: pytest.fail("segment tables built")
+    )
+    q, k, v = _qkv(jax.random.key(50), 1, 2048, 2048, 2, 1, 64)
+    jax.eval_shape(jax.grad(lambda q: flash_attention(q, k, v).sum()), q)
+    assert [a["order"] for a in asked] == ["row", "row", "col"]
+    assert asked[2]["group"] == 2
+    for a in asked:
+        assert (a["block_q"], a["block_k"], a["num_q"], a["num_k"]) == (1024, 1024, 2, 2)
+        assert (a["causal"], a["q_offset"], a["sk"]) == (True, 0, 2048)
+
+
+def test_live_block_counts_of_packed_rows():
+    """The counter the trainer reports: tiles run against tiles the
+    causal walk alone would run, at the default blocks."""
+    S, tile = 2048, pa.SEGMENT_TILE
+    one = jnp.asarray(_ids(S)[None])
+    live, causal = pa.live_block_counts(one)
+    n = S // tile
+    assert (int(live), causal) == (n * (n + 1) // 2, n * (n + 1) // 2)
+    walls = jnp.asarray(np.stack([_ids(*[tile] * n), _ids(S)]))
+    live, causal = pa.live_block_counts(walls)
+    assert (int(live), causal) == (n + n * (n + 1) // 2, n * (n + 1))

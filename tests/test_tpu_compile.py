@@ -11,6 +11,7 @@ from jax.sharding import SingleDeviceSharding
 
 from odh_kubeflow_tpu.models import LlamaConfig, forward_with_cache, init_cache
 from odh_kubeflow_tpu.models import llama
+from odh_kubeflow_tpu.ops.pallas_attention import flash_attention
 from odh_kubeflow_tpu.ops.pallas_decode_attention import decode_attend
 
 
@@ -56,6 +57,39 @@ def test_decode_attend_compiles_at_real_widths(one_chip, B, S, hd, vector):
     assert "tpu_custom_call" in compiled.as_text()
     # the stack goes to the kernel as it is: no copy of a layer beside it
     assert compiled.memory_analysis().temp_size_in_bytes < cache.size // L
+
+
+# the training cell (Mistral-7B: 2 x 4096 packed, 32 / 8 heads x 128) and
+# Llama-3.2-1B's heads of 64 (the augmented operands), each with the
+# walk built from segment ids (1024 blocks in 512 tiles, addressed by a
+# loop: aligned dynamic slices on both axes) and with the static one
+@pytest.mark.parametrize("segmented", [True, False], ids=["packed", "plain"])
+@pytest.mark.parametrize("hd", [128, 64])
+def test_flash_compiles_at_real_widths(one_chip, hd, segmented):
+    B, S, Hq, Hkv = 2, 4096, 32, 8
+    q = jax.ShapeDtypeStruct((B, S, Hq, hd), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((B, S, Hkv, hd), jnp.bfloat16)
+    seg = jax.ShapeDtypeStruct((B, S), jnp.int32)
+
+    def grads(q, k, v, tangent, seg):
+        def loss(q, k, v):
+            out = flash_attention(
+                q, k, v, segment_ids=seg if segmented else None,
+                interpret=False,  # the backend here is the CPU
+            )
+            return jnp.sum(out.astype(jnp.float32) * tangent)
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    # conftest asks for float32 matmuls on the CPU; the chip runs the
+    # default, and Mosaic refuses a bf16 dot at float32 precision
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(grads).lower(
+            *_on(one_chip, (q, kv, kv, q, seg))
+        ).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    # what the benchmark's flash_roofline finds the three calls by
+    assert text.count(f"f32[{B},{Hq},{S},1]") >= 3
 
 
 @pytest.mark.parametrize("in_place", [True, False], ids=["kernel", "dense"])
