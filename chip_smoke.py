@@ -204,6 +204,7 @@ def check_kernels(cfg) -> dict:
     return {
         "rel_err_vs_dense": errs,
         "packed4k_flash_tiles": {"live": int(live), "causal": causal},
+        "state_space": check_state_space_kernels(),
     }
 
 
@@ -312,6 +313,75 @@ def check_ring_and_held_experts() -> dict:
         if not err < 5e-2:
             raise AssertionError(f"{name}: relative error {err}")
     return errs
+
+
+def check_state_space_kernels() -> dict:
+    """What a stack with recurrent layers adds (PR 31), each kernel
+    Mosaic-compiled at Granite 4.0-H's published widths (128 heads x 64,
+    state 128, chunks of 256) against its plain ``jax.numpy`` form:
+    ``ssd_chunk_scan`` over a part of 2048 positions with a state in, a
+    padded tail and the final state out, against the recurrence token by
+    token; ``ssm_decode_update`` for 32 slots on a stacked state against
+    the one step written out. Same bound as flash. Also times the decode
+    kernel beside its plain form (XLA's own fusion), on the stack a
+    pipeline stage holds."""
+    from odh_kubeflow_tpu.ops import pallas_ssm as ps
+
+    H, P, N, S, slots, L = 128, 64, 128, 2048, 32, 9
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    k = jax.random.split(jax.random.key(31), 8)
+    dt = jax.nn.softplus(jax.random.normal(k[1], (1, S, H), f32) - 4.0)
+    dt = dt * (jnp.arange(S) < S - 300)[None, :, None]  # a padded tail
+    A = -jax.random.uniform(k[2], (H,), f32, 1.0, 16.0)
+    x = jax.random.normal(k[0], (1, S, H, P), bf16)
+    Bm, Cm = (jax.random.normal(kk, (1, S, N), bf16) for kk in k[3:5])
+    init = ps.to_state(jax.random.normal(k[5], (1, H, P, N), f32))
+    y, fin = ps.ssd_chunk_scan(x, dt, A, Bm, Cm, init)
+    y0, fin0 = jax.jit(ps.ssm_scan_plain)(x, dt, A, Bm, Cm, init)
+    errs = {
+        "ssd_chunk_scan.y": round(_rel_err(y, y0), 5),
+        "ssd_chunk_scan.state": round(_rel_err(fin, fin0), 5),
+    }
+
+    state = jax.random.normal(k[6], (L, slots) + init.shape[1:], f32)
+    xs = jax.random.normal(k[7], (slots, H, P), bf16)
+    dts = jnp.broadcast_to(dt[0, :slots], (slots, H)).at[3].set(0.0)
+    args = (xs, dts, A, Bm[0, :slots], Cm[0, :slots])
+    y1, s1 = ps.ssm_decode_update(*args, state, 4)
+    y2, s2 = jax.jit(ps.ssm_step_plain)(*args, state, 4)
+    errs["ssm_decode_update.y"] = round(_rel_err(y1, y2), 6)
+    errs["ssm_decode_update.state"] = round(_rel_err(s1, s2), 6)
+    if not bool(jnp.all(s1[4, 3] == state[4, 3])):
+        raise AssertionError("a row with dt = 0 moved its state")
+    for name, err in errs.items():
+        if not err < 5e-2:
+            raise AssertionError(f"{name}: relative error {err}")
+
+    def all_layers(step):
+        def run(state):
+            def body(i, carry):
+                y, state = carry
+                y_i, state = step(*args, state, i)
+                return y + y_i, state
+
+            return jax.lax.fori_loop(
+                0, L, body, (jnp.zeros((slots, H, P), f32), state)
+            )
+
+        return jax.jit(run, donate_argnums=0)
+
+    ms = {}
+    for name, step in (
+        ("kernel", ps.ssm_decode_update), ("plain", ps.ssm_step_plain),
+    ):
+        fn, st = all_layers(step), state + 0
+        _, st = jax.block_until_ready(fn(st))
+        t = time.monotonic()
+        for _ in range(10):
+            _, st = fn(st)
+        jax.block_until_ready(st)
+        ms[name] = round((time.monotonic() - t) * 100, 3)
+    return {"rel_err": errs, "ssm_decode_ms_a_step_of_9_layers": ms}
 
 
 def cache_layer_copies(hlo_text: str, cache_leaf) -> list:

@@ -49,7 +49,13 @@ from odh_kubeflow_tpu.models.generate import (
     family_forward,
     init_cache,
 )
-from odh_kubeflow_tpu.models.llama import LlamaConfig
+from odh_kubeflow_tpu.models.llama import (
+    STATE,
+    LlamaConfig,
+    kind_of,
+    layer_kinds,
+    stack_kind,
+)
 from odh_kubeflow_tpu.utils import prometheus, tracing
 from odh_kubeflow_tpu.utils.compile_cache import install_process_cache
 from odh_kubeflow_tpu.utils.profiling import hot_span
@@ -212,13 +218,16 @@ class _Request:
 
 
 def _splice_slot(cache: Params, sub_cache: Params, slot) -> Params:
-    """A batch-1 cache written into row ``slot`` of the slots' cache,
-    every stack of every kind of layer (``generate.init_cache``); what
-    is not a stack (a call's counters) stays the slots' own."""
+    """A batch-1 cache written into row ``slot`` of the slots' cache:
+    every stack of every kind of layer (``llama.CACHE_KINDS``: keys and
+    values, rings, a recurrent layer's state), each of which has the
+    slots behind its layers; what is not a stack (a call's counters)
+    stays the slots' own. The whole row is replaced, so nothing of the
+    slot's last request is left in it."""
     return {
         name: jax.lax.dynamic_update_slice(
-            leaf, sub_cache[name], (0, slot, 0, 0)
-        ) if leaf.ndim == 4 else leaf
+            leaf, sub_cache[name], (0, slot) + (0,) * (leaf.ndim - 2)
+        ) if stack_kind(name) else leaf
         for name, leaf in cache.items()
     }
 
@@ -454,16 +463,23 @@ class DecodeEngine:
         # engine's own indexing
         self.cache_bytes = cache_bytes(self._state["cache"])
         rings = {
-            leaf.shape[2] for leaf in self._state["cache"].values()
-            if leaf.ndim == 4 and leaf.shape[2] != max_len
+            leaf.shape[2] for name, leaf in self._state["cache"].items()
+            if stack_kind(name) == "window" and leaf.shape[2] != max_len
         }
-        if rings and (
+        stateful = self.cache_bytes[STATE] > 0
+        if (rings or stateful) and (
             prefix_cache_entries or any(r % self._widest_part for r in rings)
         ):
             raise NotImplementedError(
-                "a windowed cache keeps rings: a prefix entry cannot be "
-                "cut from one, and every prompt bucket and the prefill "
-                f"chunk must divide the ring ({sorted(rings)})"
+                "a windowed cache keeps rings and a recurrent layer one "
+                "state a slot: a prefix entry cannot be cut from either, "
+                "and every prompt bucket and the prefill chunk must "
+                f"divide the ring ({sorted(rings)})"
+            )
+        if stateful and draft_params is not None:
+            raise NotImplementedError(
+                "speculative verify writes positions it may take back; a "
+                "recurrent layer's state has no position to take back to"
             )
         # counters of what the cached forward adds for a mixture of
         # held experts and for window layers (PERF.md section 3): read
@@ -472,11 +488,11 @@ class DecodeEngine:
         self.moe_experts_hit = 0
         self.moe_dropped = 0
         self.window_blocks_skipped = 0
-        windows = getattr(cache_cfg, "layer_windows", (None,))
+        kinds = layer_kinds(cache_cfg)
         # {window: how many layers of the whole stack have it}
         self._window_layers = collections.Counter(
-            [w for w in windows if w is not None]
-            * (cache_cfg.num_layers // len(windows))
+            [k for k in kinds if kind_of(k) == "window"]
+            * (cache_cfg.num_layers // len(kinds))
         )
         # serving SLO metrics (arXiv:2605.25645's TTFT/TPOT surface):
         # the same registry the platform scrapes at /metrics
@@ -507,12 +523,17 @@ class DecodeEngine:
         # server would have spent per-request; the ratio
         # tokens_emitted / decode_steps is the batching efficiency
         self.decode_steps = 0
+        self.decode_calls = 0  # chunk programs those steps came in
         self.tokens_emitted = 0
         self.spec_rounds = 0
         # loop turns that had work, and prefill programs dispatched
         # (whole prompts, parts of a chunked admission, prefix seeding)
         self.turns = 0
         self.prefill_calls = 0
+        # prompt tokens those programs ran, and the positions they ran
+        # them in (a bucket's or a part's width)
+        self.prefill_tokens = 0
+        self.prefill_positions = 0
         # first tokens emitted ahead of their turn's chunk fetch: every
         # request that reached a slot with max_tokens > 1
         self.first_tokens_early = 0
@@ -1036,11 +1057,16 @@ class DecodeEngine:
             return
 
     def _note_prefill(self, req: _Request, slot: int, bucket: int,
-                      prefix_hit: bool, part: str) -> None:
+                      prefix_hit: bool, part: str, tokens: int) -> None:
         """One prefill program is about to be dispatched for ``req``:
-        counted, stamped on the request (for its ``engine.request``
-        span) and noted as an event on the turn's ``engine.admit``."""
+        counted (the call, the ``tokens`` of the prompt it runs and the
+        ``bucket`` positions it runs them in: what lies between is
+        padding, which a recurrent layer's scan walks too), stamped on
+        the request (for its ``engine.request`` span) and noted as an
+        event on the turn's ``engine.admit``."""
         self.prefill_calls += 1
+        self.prefill_tokens += tokens
+        self.prefill_positions += bucket if tokens else 0
         req.slot, req.bucket, req.prefix_hit = slot, bucket, prefix_hit
         tracing.add_event(
             "prefill", request=req.request_id, slot=slot, bucket=bucket,
@@ -1058,7 +1084,7 @@ class DecodeEngine:
             row[0, bucket + 1] = slot
             packed = jnp.asarray(row)
             self.prefix_hits += 1
-            self._note_prefill(req, slot, bucket, True, "whole")
+            self._note_prefill(req, slot, bucket, True, "whole", len(rem))
             self._state, first = self._prefill_ext_runner(plen, bucket)(
                 self.params, self.lora, self._state, entry, packed,
             )
@@ -1068,7 +1094,7 @@ class DecodeEngine:
             row = self.pack_admission(req.prompt, self.pad_id, bucket, req)
             row[0, bucket + 1] = slot
             packed = jnp.asarray(row)
-            self._note_prefill(req, slot, bucket, False, "whole")
+            self._note_prefill(req, slot, bucket, False, "whole", L)
             self._state, first = self._prefill_runner(bucket)(
                 self.params, self.lora, self._state, packed,
             )
@@ -1117,7 +1143,7 @@ class DecodeEngine:
         plen, entry = self._match_prefix(req.prompt)
         if plen is not None:
             self.prefix_hits += 1
-            self._note_prefill(req, slot, plen, True, "seed")
+            self._note_prefill(req, slot, plen, True, "seed", 0)
             sub_cache = self._prefill_seed_runner(plen)(sub_cache, entry)
             start = plen
         else:
@@ -1147,7 +1173,7 @@ class DecodeEngine:
                 [req.prompt[consumed:consumed + C]], jnp.int32
             )
             self._note_prefill(
-                req, slot, C, adm["had_prefix"], f"part@{consumed}"
+                req, slot, C, adm["had_prefix"], f"part@{consumed}", C
             )
             adm["sub"] = self._prefill_part_runner(C)(
                 self.params, self.lora, adm["sub"], seg,
@@ -1160,7 +1186,9 @@ class DecodeEngine:
         row = self.pack_admission(rem, self.pad_id, C, req)
         row[0, C + 1] = slot
         packed = jnp.asarray(row)
-        self._note_prefill(req, slot, C, adm["had_prefix"], "final")
+        self._note_prefill(
+            req, slot, C, adm["had_prefix"], "final", len(rem)
+        )
         self._state, first = self._prefill_final_runner(C)(
             self.params, self.lora, self._state, adm["sub"], packed,
             jnp.int32(consumed),
@@ -1292,7 +1320,11 @@ class DecodeEngine:
         is a child span of the caller's ``engine.turn`` and they tile
         it; a phase that fails closes with status ``error``. False: the
         loop must exit (stop sentinel, or the engine failed)."""
-        with hot_span("engine.admit"):
+        adm = self._admitting
+        with hot_span("engine.admit", **({} if adm is None else {
+            # an admission in parts: how many it takes in all
+            "parts": -(-len(adm["req"].prompt) // self.prefill_chunk),
+        })):
             if self._admitting is not None:
                 # one prefill part per loop turn: active slots get a
                 # decode chunk below before the next part runs
@@ -1434,6 +1466,7 @@ class DecodeEngine:
             self._slot_req[slot] = None
 
     def _emit_chunk(self, toks, mask) -> None:
+        self.decode_calls += 1
         self.decode_steps += (
             self.spec_rounds_per_call
             if self._spec_fn is not None
@@ -1574,3 +1607,17 @@ class DecodeEngine:
         self._queue.put(None)
         self._wake.set()
         self._thread.join(timeout=60)
+
+    def slot_state(self, slot: int) -> dict:
+        """Row ``slot`` of every recurrent-state stack (``STATE``), on
+        the host: ``{name: [layers, ...]}``. Of a STOPPED engine only
+        (while the loop runs it owns the buffers, which its programs
+        donate): what the slot's stream has left behind it, its prompt
+        and every token emitted but the last, which the next step would
+        have taken in."""
+        assert not self._thread.is_alive(), "stop the engine first"
+        return {
+            name: np.asarray(leaf[:, slot])
+            for name, leaf in self._state["cache"].items()
+            if stack_kind(name) == STATE
+        }

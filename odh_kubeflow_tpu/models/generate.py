@@ -24,6 +24,7 @@ that order, matching the semantics of the usual HF ``generate`` knobs.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Any, Optional
 
@@ -32,11 +33,14 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from odh_kubeflow_tpu.models.llama import (
-    FULL_STACKS,
-    WINDOW_STACKS,
+    CACHE_KINDS,
+    STATE,
     LlamaConfig,
     Params,
     forward_with_cache,
+    kind_of,
+    layer_kinds,
+    stack_kind,
 )
 from odh_kubeflow_tpu.parallel.mesh import (
     AXIS_DATA,
@@ -60,10 +64,14 @@ def init_cache(
     cfg: LlamaConfig, batch_size: int, max_len: int, dtype=jnp.bfloat16,
     widest_part: Optional[int] = None,
 ) -> Params:
-    """Preallocated KV cache, one pair of stacks a KIND of layer:
+    """Preallocated cache, a few stacks a KIND of layer
+    (``llama.CACHE_KINDS``, from ``llama.layer_kinds(cfg)``):
     ``{"k","v"}: [L_full, B, max_len, Hkv * hd]`` for the layers that
-    see every position and, where ``cfg.layer_windows`` has window
-    layers, ``{"wk","wv"}: [L_window, B, ring, Hkv * hd]`` for those.
+    see every position, ``{"wk","wv"}: [L_window, B, ring, Hkv * hd]``
+    for window layers, and for recurrent layers, which keep NO keys and
+    values, their state: ``cfg.state_leaves(dtype)`` names each leaf's
+    shape behind ``[L_state, B]`` and its dtype (a Mamba-2 layer: the
+    float32 SSM state and the last inputs of its convolution).
 
     A window layer can only ever be asked for the ``window`` positions
     that end at a query, so it keeps a RING: position ``p`` in slot ``p
@@ -83,25 +91,29 @@ def init_cache(
     (what ``ops/pallas_decode_attention.py`` walks) and a token's write
     is one contiguous row.
     """
-    windows = getattr(cfg, "layer_windows", (None,))
-    periods = cfg.num_layers // len(windows)
-    n_window = sum(w is not None for w in windows)
+    kinds = layer_kinds(cfg)
+    periods = cfg.num_layers // len(kinds)
+    layers = collections.Counter(kind_of(k) for k in kinds)
 
-    def stacks(names, layers, length):
-        shape = (layers, batch_size, length, cfg.kv_dim)
-        return {n: jnp.zeros(shape, dtype) for n in names}
+    def stacks(kind, length):
+        shape = (periods * layers[kind], batch_size, length, cfg.kv_dim)
+        return {n: jnp.zeros(shape, dtype) for n in CACHE_KINDS[kind]}
 
     cache = {}
-    if n_window < len(windows):
-        cache.update(
-            stacks(FULL_STACKS, periods * (len(windows) - n_window), max_len)
-        )
-    if n_window:
+    if layers["full"]:
+        cache.update(stacks("full", max_len))
+    if layers["window"]:
         ring = max_len
         if widest_part is not None:
-            widest = max(w for w in windows if w is not None)
+            widest = max(k for k in kinds if kind_of(k) == "window")
             ring = min(max_len, -(-(widest + widest_part) // widest_part) * widest_part)
-        cache.update(stacks(WINDOW_STACKS, periods * n_window, ring))
+        cache.update(stacks("window", ring))
+    if layers[STATE]:
+        lead = (periods * layers[STATE], batch_size)
+        cache.update({
+            name: jnp.zeros(lead + shape, dt)
+            for name, (shape, dt) in cfg.state_leaves(dtype).items()
+        })
     if hasattr(cfg, "experts_held"):
         # a call's expert counters (``moe.local_expert_ffn``): the
         # engine zeroes them before a decode chunk and reads them with it
@@ -111,10 +123,12 @@ def init_cache(
 
 def cache_bytes(cache: Params) -> dict[str, int]:
     """Bytes the cache holds, by kind of layer."""
-    size = lambda names: sum(  # noqa: E731
-        cache[n].size * cache[n].dtype.itemsize for n in names if n in cache
-    )
-    return {"full": size(FULL_STACKS), "window": size(WINDOW_STACKS)}
+    return {
+        kind: sum(
+            cache[n].size * cache[n].dtype.itemsize for n in names if n in cache
+        )
+        for kind, names in CACHE_KINDS.items()
+    }
 
 
 def cache_specs(cfg: LlamaConfig) -> Params:
@@ -123,11 +137,18 @@ def cache_specs(cfg: LlamaConfig) -> Params:
     Batch shards with the data axes; KV heads shard on tensor (whole
     heads: a shard of the ``Hkv * hd`` axis is what the tensor-sharded
     wk/wv projections produce, so the cache write is collective-free).
+    A recurrent layer's state shards over the batch alone.
     """
-    s = P(None, (AXIS_DATA, AXIS_FSDP), None, AXIS_TENSOR)
+    batch = (AXIS_DATA, AXIS_FSDP)
+    by_kind = {
+        "full": P(None, batch, None, AXIS_TENSOR),
+        "window": P(None, batch, None, AXIS_TENSOR),
+        STATE: P(None, batch),
+        None: P(),
+    }
     return {
-        name: s if leaf.ndim == 4 else P()
-        for name, leaf in jax.eval_shape(lambda: init_cache(cfg, 1, 128)).items()
+        name: by_kind[stack_kind(name)]
+        for name in jax.eval_shape(lambda: init_cache(cfg, 1, 128))
     }
 
 

@@ -22,6 +22,7 @@ This model is the flagship workload for the platform's north star
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import math
 from functools import partial
@@ -426,10 +427,10 @@ def _decoder_layer(
 
 
 class CacheLayer(NamedTuple):
-    """Where one layer's keys and values lie in the cache: the names of
-    its K and V stacks (``init_cache``: a kind of layer has a stack of
-    its own), its index in them, how far back it sees, and its depth in
-    the whole stack of layers."""
+    """Where one layer's part of the cache lies: the names of its stacks
+    (``init_cache``: a kind of layer has stacks of its own; keys and
+    values, or a recurrent layer's state), its index in them, how far
+    back it sees, and its depth in the whole stack of layers."""
 
     index: Any  # scalar int32
     names: tuple = ("k", "v")
@@ -569,69 +570,101 @@ def _reads_cache_in_place(cache_leaf, head_dim: int) -> bool:
     )
 
 
-# the cache's stacks by kind of layer: (K, V) names in ``init_cache``
+# The ONE table of the cache's kinds (``generate.init_cache``,
+# ``cache_bytes``, ``cache_specs``, the engine's splice and this file's
+# scan all read it): a kind of layer has stacks of its own, each with a
+# row per layer of the kind and, behind it, a row per slot. An entry of
+# a config's period of kinds is ``None`` (every position: keys and
+# values ``max_len`` long), an int (a window: a ring of keys and
+# values) or ``STATE`` (a recurrent layer: no keys and values, a state
+# of the shapes ``cfg.state_leaves`` gives).
 FULL_STACKS = ("k", "v")
 WINDOW_STACKS = ("wk", "wv")
+STATE_STACKS = ("ssm", "conv")
+STATE = "state"
+CACHE_KINDS = {"full": FULL_STACKS, "window": WINDOW_STACKS, STATE: STATE_STACKS}
 
 
-def cache_layers(windows: tuple, period_index) -> list:
+def kind_of(entry) -> str:
+    """The kind of cache an entry of a period of kinds asks for."""
+    if entry is None:
+        return "full"
+    return STATE if entry == STATE else "window"
+
+
+def layer_kinds(cfg) -> tuple:
+    """A config's period of kinds: ``layer_kinds`` where it has layers
+    that keep no keys and values, else its ``layer_windows``."""
+    return getattr(cfg, "layer_kinds", None) or getattr(
+        cfg, "layer_windows", (None,)
+    )
+
+
+def stack_kind(name: str) -> Optional[str]:
+    """The kind whose stack a cache leaf is; None for what is not a
+    stack (a call's counters)."""
+    return next((k for k, names in CACHE_KINDS.items() if name in names), None)
+
+
+def cache_layers(kinds: tuple, period_index) -> list:
     """The ``CacheLayer`` of each layer of period ``period_index`` of a
-    stack whose period of kinds is ``windows``: a kind's layers are
+    stack whose period of kinds is ``kinds``: a kind's layers are
     numbered down the depth within their own stacks."""
-    out, seen = [], {FULL_STACKS: 0, WINDOW_STACKS: 0}
-    per_period = {
-        FULL_STACKS: sum(w is None for w in windows),
-        WINDOW_STACKS: sum(w is not None for w in windows),
-    }
-    for j, w in enumerate(windows):
-        names = FULL_STACKS if w is None else WINDOW_STACKS
+    per_period = collections.Counter(kind_of(k) for k in kinds)
+    out, seen = [], collections.Counter()
+    for j, entry in enumerate(kinds):
+        kind = kind_of(entry)
         out.append(CacheLayer(
-            period_index * per_period[names] + seen[names], names, w,
-            period_index * len(windows) + j,
+            period_index * per_period[kind] + seen[kind], CACHE_KINDS[kind],
+            entry if kind == "window" else None,
+            period_index * len(kinds) + j,
         ))
-        seen[names] += 1
+        seen[kind] += 1
     return out
 
 
+def take_layer(tree, layer_index):
+    """One layer of a stack of layers ``[L, ...]`` (None stays None). A
+    period's layers are taken from the stacks one by one, each slice
+    with the one matmul that consumes it: scanned as a ``[periods, p,
+    ...]`` input, XLA copies the whole period's weights out of the stack
+    on every turn of the scan (PERF.md, PR 26)."""
+    if tree is None:
+        return None
+    return jax.tree_util.tree_map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer_index, 0, False), tree
+    )
+
+
 def scan_layers_with_cache(layer_fn, x, layers, lora_layers, cache,
-                           windows: tuple = (None,)):
-    """The one scan over layers that has a KV cache (every family).
+                           kinds: tuple = (None,)):
+    """The one scan over layers that has a cache (every family).
 
     ``layer_fn(x, layer, lora_layer, cache, cache_layer) -> (x, cache)``
     runs one layer; it hands ``cache`` and ``cache_layer`` (a
-    ``CacheLayer``) to ``cache_write_and_attend``. The scan runs over
-    the stack's PERIODS (``windows``: ``LlamaConfig.layer_windows``):
+    ``CacheLayer``) to ``cache_write_and_attend``, or, for a recurrent
+    layer (``cache_layer.names == STATE_STACKS``), reads and writes the
+    layer's state at ``cache_layer.index`` itself. The scan runs over
+    the stack's PERIODS (``kinds``: ``layer_kinds(cfg)``):
     its body holds one layer of each kind in the period's order, so a
     stack of one kind is a scan over its layers. The cache's stacks,
-    one pair a kind, ride the scan as its carry beside ``x``, and only
+    a few a kind, ride the scan as its carry beside ``x``, and only
     the weights, adapters and the period's index are scanned: as a
     scanned input and output XLA would slice every layer's whole cache
     out of the stack and write it back, each layer of each step
     (PERF.md, PR 25). With the caller's buffer donated the stacks are
     updated in place."""
-    p = len(windows)
+    p = len(kinds)
     depth = jax.tree_util.tree_leaves(layers)[0].shape[0]
-    assert depth % p == 0, (depth, windows)
-
-    def take(tree, layer_index):
-        # a period's layers are taken from the stacks one by one, each
-        # slice with the one matmul that consumes it: scanned as a
-        # [periods, p, ...] input, XLA copies the whole period's weights
-        # out of the stack on every turn of the scan (PERF.md, PR 26)
-        if tree is None:
-            return None
-        return jax.tree_util.tree_map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, layer_index, 0, False),
-            tree,
-        )
+    assert depth % p == 0, (depth, kinds)
 
     def body(carry, scanned):
         x, cache = carry
         period_index, layer, lora_layer = scanned
-        for cache_layer in cache_layers(windows, period_index):
+        for cache_layer in cache_layers(kinds, period_index):
             if p > 1:
-                layer = take(layers, cache_layer.depth)
-                lora_layer = take(lora_layers, cache_layer.depth)
+                layer = take_layer(layers, cache_layer.depth)
+                lora_layer = take_layer(lora_layers, cache_layer.depth)
             x, cache = layer_fn(x, layer, lora_layer, cache, cache_layer)
         return (x, cache), None
 

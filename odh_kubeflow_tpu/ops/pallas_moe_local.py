@@ -106,6 +106,10 @@ def moe_local_ffn(
     ``n_live`` are NOT written (the caller never reads them)."""
     M, D = x_sorted.shape
     F = gate["q"].shape[-1]
+    if F % min(block_f, F):
+        # a width the block does not divide (768): the widest whole
+        # number of lane tiles that does
+        block_f = max(b for b in range(128, block_f, 128) if F % b == 0)
     block_f = min(block_f, F)
     assert M % block_m == 0 and F % block_f == 0, (M, F, block_m, block_f)
     num_f = F // block_f

@@ -359,6 +359,9 @@ def test_a_failure_under_a_long_queue_does_not_fill_the_kept_store(
         with tracing.span("controlplane.spawn"):
             raise ValueError("an error trace from before")
     (before,) = [tid for tid, _why, _spans in collector.kept_traces()]
+    # the warm-up's turn compiles: on a busy machine that alone can pass
+    # the engine's 2 s and be kept as slow; this case is about errors
+    collector.set_threshold("engine.turn", 60.0)
     engine = _engine(model)
     try:
         engine.submit([5, 9, 13], max_tokens=4).result(timeout=120)  # warm
@@ -399,6 +402,10 @@ def test_hot_thresholds_hold_for_a_collector_installed_later(model):
     collector was current when an engine or a trainer was built."""
     engine = _engine(model)
     engine.submit([5, 9, 13], max_tokens=4).result(timeout=120)  # compiles
+    # a result is set before its turn's span closes: the compiling turn
+    # (seconds on a busy machine) must not close into the late collector,
+    # so a healthy request's turn is the one that may
+    engine.submit([5, 9, 13], max_tokens=4).result(timeout=120)
     late = tracing.SpanCollector()
     old = tracing.set_collector(late)
     try:

@@ -271,3 +271,108 @@ def test_cohere2_decode_step_reads_banks_and_both_caches_in_place(
     ]
     assert not made, made[:3]
     assert mem.temp_size_in_bytes < 3 * 16 * 4096 * 4096, mem.temp_size_in_bytes
+
+
+# ---- Granite 4.0-H's stage (granitemoehybrid): 128 Mamba-2 heads x 64,
+# state 128, chunks of 256; 32 slots; 72 held experts of width 768
+
+
+@pytest.mark.parametrize("S", [2048, 64], ids=["part", "bucket-64"])
+def test_ssd_chunk_scan_compiles_at_published_widths(one_chip, S):
+    from odh_kubeflow_tpu.ops import pallas_ssm
+
+    H, P, N = 128, 64, 128
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    compiled = jax.jit(pallas_ssm.ssd_chunk_scan).lower(*_on(one_chip, (
+        jax.ShapeDtypeStruct((1, S, H, P), bf16),
+        jax.ShapeDtypeStruct((1, S, H), f32), jax.ShapeDtypeStruct((H,), f32),
+        jax.ShapeDtypeStruct((1, S, N), bf16), jax.ShapeDtypeStruct((1, S, N), bf16),
+        jax.ShapeDtypeStruct((1,) + pallas_ssm.state_shape(H, P, N), f32),
+    ))).compile()
+    assert "ssd_chunk_scan" in compiled.as_text()
+
+
+def test_ssm_decode_update_is_one_pass_over_the_stacked_state(one_chip):
+    from odh_kubeflow_tpu.ops import pallas_ssm
+
+    L, B, H, P, N = 9, 32, 128, 64, 128
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    state = jax.ShapeDtypeStruct((L, B) + pallas_ssm.state_shape(H, P, N), f32)
+    compiled = jax.jit(pallas_ssm.ssm_decode_update, donate_argnums=5).lower(
+        *_on(one_chip, (
+            jax.ShapeDtypeStruct((B, H, P), bf16), jax.ShapeDtypeStruct((B, H), f32),
+            jax.ShapeDtypeStruct((H,), f32), jax.ShapeDtypeStruct((B, N), bf16),
+            jax.ShapeDtypeStruct((B, N), bf16), state,
+            jax.ShapeDtypeStruct((), jnp.int32),
+        ))
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert "ssm_decode_update" in compiled.as_text()
+    assert mem.alias_size_in_bytes >= state.size * 4
+    # the rows laid along the lanes (a few MB), never a layer's state
+    assert mem.temp_size_in_bytes < state.size * 4 // L // 4
+
+
+def _granite_stage(monkeypatch, periods=1):
+    from odh_kubeflow_tpu.models import granite_hybrid as gh, moe
+
+    monkeypatch.setattr(llama, "_reads_cache_in_place", lambda leaf, hd: True)
+    monkeypatch.setattr(moe, "reads_banks_in_place", lambda banks: True)
+    monkeypatch.setattr(gh, "_uses_kernels", lambda: True)
+    cfg = gh.GraniteHybridConfig(num_layers=10 * periods)
+    params = jax.eval_shape(
+        lambda: gh.init_params(jax.random.key(0), cfg, jnp.bfloat16)
+    )
+    quantised = {
+        "layers": ("moe_gate", "moe_up", "moe_down", "sh_gate", "sh_up", "sh_down"),
+        "mamba": ("in_proj", "out_proj"), "attn": ("wq", "wk", "wv", "wo"),
+    }
+    for group, names in quantised.items():
+        for name in names:
+            params[group][name] = _int8_bank(params[group][name].shape)
+    return gh, cfg, params
+
+
+@pytest.mark.parametrize("B,S", [(32, 1), (1, 2048)], ids=["decode", "part"])
+def test_granite_stage_updates_state_and_cache_in_place(one_chip, monkeypatch, B, S):
+    """One period at published widths, a decode step of 32 slots and a
+    part of 2048 positions, compiled for the chip: the state and the
+    attention layer's keys and values are aliased, the four kernels are
+    there, and the temporaries are megabytes (no layer's state, expert
+    banks or dequantised projection is written out)."""
+    gh, cfg, params = _granite_stage(monkeypatch)
+    max_len = 13312
+    cache = jax.eval_shape(lambda: init_cache(cfg, B, max_len, widest_part=2048))
+    assert cache["ssm"].shape == (9, B, 64, 128, 128)
+    assert cache["conv"].shape == (9, B, 3, 8448)
+    assert cache["k"].shape == (1, B, max_len, 1024)
+
+    def step(params, cache, tokens, index, kv_mask):
+        return gh.forward_with_cache(
+            params, tokens, cfg, cache, index,
+            positions=jnp.broadcast_to(jnp.arange(S), (B, S)),
+            kv_mask=kv_mask, token_mask=kv_mask[:, :S],
+        )
+
+    compiled = jax.jit(step, donate_argnums=1).lower(*_on(one_chip, (
+        params, cache, jax.ShapeDtypeStruct((B, S), jnp.int32),
+        jax.ShapeDtypeStruct((B,) if S == 1 else (), jnp.int32),
+        jax.ShapeDtypeStruct((B, max_len), jnp.bool_),
+    ))).compile()
+    mem = compiled.memory_analysis()
+    stacks = sum(
+        v.size * v.dtype.itemsize for n, v in cache.items() if n != "moe_stats"
+    )
+    assert mem.alias_size_in_bytes >= stacks
+    text = compiled.as_text()
+    assert "decode_attend" in text and "moe_local_ffn" in text
+    assert ("ssm_decode_update" if S == 1 else "ssd_chunk_scan") in text
+    print(f"granite {B}x{S}: temp {mem.temp_size_in_bytes / 1e6:.1f} MB")
+    if S == 1:
+        # one slot's state in one layer is 4.2 MB, a layer's banks 680 MB,
+        # a dequantised in_proj 137 MB
+        assert mem.temp_size_in_bytes < 100e6, mem.temp_size_in_bytes
+    else:
+        # a part's activations: the sorted rows of 2048 tokens x 10
+        # choices and the projection's [2048, 16768] outputs
+        assert mem.temp_size_in_bytes < 2.5e9, mem.temp_size_in_bytes
