@@ -81,6 +81,102 @@ _ITL_BUCKETS = (
 )
 
 
+# bits of a key one pass of ``_largest_key`` settles: its 2**bits - 1
+# candidates share one read of the row. On a v5e the sampler takes
+# 0.80 / 0.51 / 0.45 ms a step at [32, 100352] with 1 / 2 / 4; with 4
+# every program that holds it loads 0.2 s slower (PERF.md, PR 33)
+_SEARCH_BITS = 2
+
+
+def _ordered_keys(x: jnp.ndarray) -> jnp.ndarray:
+    """uint32 keys whose integer order is the float32 order of ``x``
+    (``-0.0`` one below ``+0.0``)."""
+    b = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(1 << 31))
+
+
+def _key_value(keys: jnp.ndarray) -> jnp.ndarray:
+    """The float32 an ordered key stands for."""
+    b = jnp.where(keys >> 31 == 1, keys ^ jnp.uint32(1 << 31), ~keys)
+    return jax.lax.bitcast_convert_type(b, jnp.float32)
+
+
+def _largest_key(holds, rows: int) -> jnp.ndarray:
+    """Per row the largest uint32 ``t`` with ``holds(t)``, for a
+    ``holds`` ([rows] keys -> [rows] bool) that is true at 0 and, once
+    false, stays false as ``t`` grows. The key is settled from its top
+    bits down, ``_SEARCH_BITS`` a pass: every candidate of a pass is one
+    compare and one row reduction over the same operands, which XLA
+    fuses into one read of them. The passes stay a ``while``: laid out
+    one after the other they save 0.1 ms a step at [32, 100352], and
+    every program that holds the sampler then loads 0.2-0.35 s slower
+    from the compile cache (PERF.md, PR 33)."""
+
+    def settle(i, t):
+        shift = (32 - _SEARCH_BITS * (i + 1)).astype(jnp.uint32)
+        digit = sum(
+            holds(t | (jnp.uint32(d) << shift)).astype(jnp.uint32)
+            for d in range(1, 1 << _SEARCH_BITS)
+        )
+        return t | (digit << shift)
+
+    return jax.lax.fori_loop(
+        0, 32 // _SEARCH_BITS, settle, jnp.zeros((rows,), jnp.uint32)
+    )
+
+
+def mask_logits_rowwise(
+    logits: jnp.ndarray,  # [B, V] float32
+    temperature: jnp.ndarray,  # [B] f32; <=0 → the row is not scaled
+    top_k: jnp.ndarray,  # [B] i32; <=0 → off
+    top_p: jnp.ndarray,  # [B] f32; <=0 or >=1 → off
+) -> jnp.ndarray:
+    """The rows scaled by their temperature, with ``-inf`` wherever a
+    row's top-k or nucleus leaves a token out: what the sampler draws
+    from. Same survivors as ``generate.sample_logits`` row by row, top-p
+    over the top-k-filtered row, ties at either edge all kept.
+
+    Neither cut-off is read from a sorted row. A float32's bits, flipped
+    so that their integer order is the float order (``_ordered_keys``),
+    make a threshold a 32-bit integer, and ``_largest_key`` settles it in
+    ``32 / _SEARCH_BITS`` passes of compares and row reductions: the
+    k-th largest value is the largest key that at least k keys reach (a
+    count: exact), the nucleus's edge the largest key whose keys at or
+    above it hold at least ``top_p`` of the row's mass (a masked sum in
+    one fixed order, so monotone in the key). The cost is the same for a
+    flat row as for a peaked one. The masks themselves compare FLOATS
+    against the value the key stands for, so ``-0.0`` and ``+0.0``, two
+    keys, stay one value."""
+    B, V = logits.shape
+    t = jnp.where(temperature > 0, temperature, 1.0)[:, None]
+    scaled = logits / t
+    keys = _ordered_keys(scaled)
+
+    # top-k: mask below each row's k-th value (k<=0 → keep all)
+    k = jnp.clip(top_k, 1, V)
+    kth = _key_value(_largest_key(
+        lambda c: jnp.sum(keys >= c[:, None], axis=-1, dtype=jnp.int32) >= k,
+        B,
+    ))
+    scaled = jnp.where(
+        (top_k > 0)[:, None] & (scaled < kth[:, None]), -jnp.inf, scaled
+    )
+    # top-p over the top-k-FILTERED distribution (same composition
+    # order as generate.sample_logits: the nucleus mass is computed on
+    # the renormalised survivors, not the raw distribution). A filtered
+    # token weighs 0 whatever its key, so the keys are not made again
+    weight = jnp.exp(scaled - jnp.max(scaled, axis=-1, keepdims=True))
+    need = top_p * jnp.sum(weight, axis=-1)
+    edge = _key_value(_largest_key(
+        lambda c: jnp.sum(
+            jnp.where(keys >= c[:, None], weight, 0.0), axis=-1
+        ) >= need,
+        B,
+    ))
+    cutoff = jnp.where((top_p > 0) & (top_p < 1), edge, -jnp.inf)
+    return jnp.where(scaled < cutoff[:, None], -jnp.inf, scaled)
+
+
 def sample_logits_rowwise(
     logits: jnp.ndarray,  # [B, V] float32
     key: jax.Array,
@@ -89,34 +185,13 @@ def sample_logits_rowwise(
     top_p: jnp.ndarray,  # [B] f32; <=0 or >=1 → off
 ) -> jnp.ndarray:
     """Per-row sampling: each slot applies its own request's knobs.
-    Same semantics as ``generate.sample_logits`` row-wise."""
-    B, V = logits.shape
+    Same semantics as ``generate.sample_logits`` row-wise. The top-k and
+    nucleus thresholds are searched for over the logits' ordered bit
+    patterns, never read from a sorted row, and the masks compare floats
+    (``mask_logits_rowwise``)."""
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-    t = jnp.where(temperature > 0, temperature, 1.0)[:, None]
-    scaled = logits / t
-    # top-k: mask below each row's k-th value (k<=0 → keep all)
-    sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
-    k_idx = jnp.clip(top_k - 1, 0, V - 1)
-    kth = jnp.take_along_axis(sorted_desc, k_idx[:, None], axis=-1)
-    scaled = jnp.where(
-        (top_k[:, None] > 0) & (scaled < kth), -jnp.inf, scaled
-    )
-    # top-p over the top-k-FILTERED distribution (same composition
-    # order as generate.sample_logits: the nucleus mass is computed on
-    # the renormalised survivors, not the raw distribution)
-    sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
-    probs = jax.nn.softmax(sorted_desc, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep = cum - probs < jnp.where(
-        (top_p > 0) & (top_p < 1), top_p, 2.0
-    )[:, None]
-    cutoff = jnp.min(
-        jnp.where(keep, sorted_desc, jnp.inf), axis=-1, keepdims=True
-    )
-    scaled = jnp.where(scaled < cutoff, -jnp.inf, scaled)
-
-    sampled = jax.random.categorical(key, scaled).astype(jnp.int32)
+    masked = mask_logits_rowwise(logits, temperature, top_k, top_p)
+    sampled = jax.random.categorical(key, masked).astype(jnp.int32)
     return jnp.where(temperature > 0, sampled, greedy)
 
 
@@ -524,6 +599,9 @@ class DecodeEngine:
         # tokens_emitted / decode_steps is the batching efficiency
         self.decode_steps = 0
         self.decode_calls = 0  # chunk programs those steps came in
+        # ... and those of them that ran the sampler (the general
+        # program; the others were all-greedy or a draft's rounds)
+        self.decode_calls_sampled = 0
         self.tokens_emitted = 0
         self.spec_rounds = 0
         # loop turns that had work, and prefill programs dispatched
@@ -737,9 +815,8 @@ class DecodeEngine:
             )
             rng, sub = jax.random.split(st["rng"])
             if greedy:
-                # all active slots are temperature<=0: skip the two
-                # full-vocab sorts of the general sampler — at V=128k
-                # they rival the model forward itself in a decode step
+                # all active slots are temperature<=0: no search for
+                # cut-offs and no noise over the vocabulary
                 nxt = jnp.argmax(logits[:, 0, :], axis=-1).astype(
                     jnp.int32
                 )
@@ -1378,9 +1455,9 @@ class DecodeEngine:
             # nothing decoding: a chunked admission runs its parts
             # back-to-back, one turn each
             return True
-        # two compiled chunk programs: the greedy one (argmax, no
-        # vocab sorts) whenever every in-flight request is greedy —
-        # the common serving mix — else the general sampler
+        # two compiled chunk programs: the greedy one (argmax alone)
+        # whenever every in-flight request is greedy, else the general
+        # sampler
         all_greedy = all(
             r is None or r.temperature <= 0 for r in self._slot_req
         )
@@ -1427,6 +1504,8 @@ class DecodeEngine:
             if self._window_layers and self._spec_fn is None:
                 self._count_window_blocks_skipped(mask)
             self._emit_chunk(toks, mask)
+            if program == "sample":
+                self.decode_calls_sampled += 1
         return True
 
     def _count_window_blocks_skipped(self, mask) -> None:

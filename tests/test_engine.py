@@ -225,7 +225,7 @@ def test_prefix_cache_exact_and_hits(model):
 
 
 def test_greedy_fast_path_matches_sampling_program(model):
-    """The greedy chunk program (argmax, no vocab sorts) must produce
+    """The greedy chunk program (argmax alone) must produce
     the same tokens as the general sampling program for temperature=0
     requests — program-to-program, since the two must be
     interchangeable chunk by chunk as the request mix changes."""
