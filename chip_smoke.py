@@ -205,6 +205,7 @@ def check_kernels(cfg) -> dict:
         "rel_err_vs_dense": errs,
         "packed4k_flash_tiles": {"live": int(live), "causal": causal},
         "state_space": check_state_space_kernels(),
+        "delta_rule": check_delta_rule_kernels(),
     }
 
 
@@ -315,6 +316,35 @@ def check_ring_and_held_experts() -> dict:
     return errs
 
 
+def _ms_a_call(fn, *args, n=10) -> float:
+    """Wall milliseconds a call of a jitted ``fn``, warm. A single
+    argument is a donated state handed from call to call (``fn``'s last
+    result)."""
+    out = jax.block_until_ready(fn(*args))
+    t = time.monotonic()
+    for _ in range(n):
+        out = fn(out[-1]) if len(args) == 1 else fn(*args)
+    jax.block_until_ready(out)
+    return round((time.monotonic() - t) * 1e3 / n, 3)
+
+
+def _all_layers(step, args, layers: int, out_shape: tuple):
+    """A decode step's state update in every layer of a stacked state,
+    as the layer scan calls it: ``state -> (sum of the outputs,
+    state)``, the state donated."""
+    def run(state):
+        def body(i, carry):
+            y, state = carry
+            y_i, state = step(*args, state, i)
+            return y + y_i, state
+
+        return jax.lax.fori_loop(
+            0, layers, body, (jnp.zeros(out_shape, jnp.float32), state)
+        )
+
+    return jax.jit(run, donate_argnums=0)
+
+
 def check_state_space_kernels() -> dict:
     """What a stack with recurrent layers adds (PR 31), each kernel
     Mosaic-compiled at Granite 4.0-H's published widths (128 heads x 64,
@@ -357,31 +387,100 @@ def check_state_space_kernels() -> dict:
         if not err < 5e-2:
             raise AssertionError(f"{name}: relative error {err}")
 
-    def all_layers(step):
-        def run(state):
-            def body(i, carry):
-                y, state = carry
-                y_i, state = step(*args, state, i)
-                return y + y_i, state
-
-            return jax.lax.fori_loop(
-                0, L, body, (jnp.zeros((slots, H, P), f32), state)
-            )
-
-        return jax.jit(run, donate_argnums=0)
-
-    ms = {}
-    for name, step in (
-        ("kernel", ps.ssm_decode_update), ("plain", ps.ssm_step_plain),
-    ):
-        fn, st = all_layers(step), state + 0
-        _, st = jax.block_until_ready(fn(st))
-        t = time.monotonic()
-        for _ in range(10):
-            _, st = fn(st)
-        jax.block_until_ready(st)
-        ms[name] = round((time.monotonic() - t) * 100, 3)
+    ms = {
+        name: _ms_a_call(_all_layers(step, args, L, (slots, H, P)), state + 0)
+        for name, step in (
+            ("kernel", ps.ssm_decode_update), ("plain", ps.ssm_step_plain),
+        )
+    }
     return {"rel_err": errs, "ssm_decode_ms_a_step_of_9_layers": ms}
+
+
+def check_delta_rule_kernels() -> dict:
+    """What a stack with Gated DeltaNet layers adds (PR 34), each kernel
+    Mosaic-compiled at Qwen3-Next's published widths (16 key heads onto
+    32 value heads, 128 x 128, chunks of 64) against its plain
+    ``jax.numpy`` form: ``gdn_chunk_scan`` over a part of 2048 positions
+    with a state in, a padded tail and the final state out, against the
+    recurrence token by token; ``gdn_decode_update`` for 32 slots on a
+    stacked state against the one step written out; and ``decode_attend``
+    at that family's attention shape (head 256, 8 query heads a KV head,
+    a row of 512), which the kernel's ``supported`` admits and no other
+    configuration runs. Same bound as flash. Also times both kernels
+    beside their plain forms (XLA's own fusions)."""
+    from odh_kubeflow_tpu.ops import pallas_gdn as pg
+    from odh_kubeflow_tpu.ops.attention import dense_attention
+    from odh_kubeflow_tpu.ops.pallas_decode_attention import decode_attend
+
+    Hk, H, dk, dv, S, slots, L = 16, 32, 128, 128, 2048, 32, 9
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    k = jax.random.split(jax.random.key(34), 10)
+    unit = lambda a: a * jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True))  # noqa: E731
+    real = (jnp.arange(S) < S - 300)[None, :, None]  # a padded tail
+    q = (unit(jax.random.normal(k[0], (1, S, Hk, dk), f32)) * dk**-0.5).astype(bf16)
+    kk = unit(jax.random.normal(k[1], (1, S, Hk, dk), f32)).astype(bf16)
+    v = jax.random.normal(k[2], (1, S, H, dv), bf16)
+    A = jax.random.uniform(k[3], (H,), f32, 1.0, 16.0)
+    g = -A * jax.nn.softplus(jax.random.normal(k[4], (1, S, H), f32) - 4.0) * real
+    beta = jax.nn.sigmoid(jax.random.normal(k[5], (1, S, H), f32)) * real
+    init = jax.random.normal(k[6], (1, H, dk, dv), f32)
+    o, fin = pg.gdn_chunk_scan(q, kk, v, g, beta, init)
+    o0, fin0 = jax.jit(pg.gdn_scan_plain)(q, kk, v, g, beta, init)
+    errs = {
+        "gdn_chunk_scan.o": round(_rel_err(o, o0), 5),
+        "gdn_chunk_scan.state": round(_rel_err(fin, fin0), 5),
+    }
+    ms = {
+        f"gdn_chunk_scan_{c}": _ms_a_call(
+            jax.jit(functools.partial(pg.gdn_chunk_scan, chunk=c)),
+            q, kk, v, g, beta, init,
+        )
+        for c in (64, 128)
+    }
+
+    state = jax.random.normal(k[7], (L, slots, H, dk, dv), f32)
+    args = (
+        q[0, :slots], kk[0, :slots], v[0, :slots],
+        g[0, :slots].at[3].set(0.0), beta[0, :slots].at[3].set(0.0),
+    )
+    y1, s1 = pg.gdn_decode_update(*args, state, 4)
+    y2, s2 = jax.jit(pg.gdn_step_plain)(*args, state, 4)
+    errs["gdn_decode_update.o"] = round(_rel_err(y1, y2), 6)
+    errs["gdn_decode_update.state"] = round(_rel_err(s1, s2), 6)
+    if not bool(jnp.all(s1[4, 3] == state[4, 3])):
+        raise AssertionError("a row with g = 0 and beta = 0 moved its state")
+
+    for name, step in (
+        ("kernel", pg.gdn_decode_update), ("plain", pg.gdn_step_plain),
+    ):
+        ms[f"gdn_decode_9_layers_{name}"] = _ms_a_call(
+            _all_layers(step, args, L, (slots, H, dv)), state + 0
+        )
+
+    # the family's attention read: 16 query heads x 256 onto 2 KV heads
+    Hq, Hkv, hd, S_max = 16, 2, 256, 2048
+    cache_k = jax.random.normal(k[8], (3, 4, S_max, Hkv * hd), bf16)
+    cache_v = jax.random.normal(k[9], (3, 4, S_max, Hkv * hd), bf16)
+    kv_mask = jnp.ones((4, S_max), bool)
+    for name, rows, Sq, index in (
+        ("decode", 4, 1, jnp.asarray([0, 300, 1100, S_max - 1], jnp.int32)),
+        ("prefill", 1, 256, jnp.int32(384)),
+    ):
+        qa = jax.random.normal(k[0], (rows, Sq, Hq, hd), bf16)
+        got = decode_attend(
+            qa, cache_k[:, :rows], cache_v[:, :rows], jnp.int32(2), index,
+            kv_mask[:rows],
+        )
+        want = dense_attention(
+            qa, cache_k[2, :rows].reshape(rows, S_max, Hkv, hd),
+            cache_v[2, :rows].reshape(rows, S_max, Hkv, hd),
+            causal=True, q_offset=index, kv_mask=kv_mask[:rows],
+        )
+        errs[f"decode_attend_hd256.{name}"] = round(_rel_err(got, want), 5)
+    for name, err in errs.items():
+        if not err < 5e-2:
+            raise AssertionError(f"{name}: relative error {err}")
+    return {"rel_err": errs, "ms": ms}
 
 
 def cache_layer_copies(hlo_text: str, cache_leaf) -> list:

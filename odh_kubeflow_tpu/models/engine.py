@@ -562,6 +562,7 @@ class DecodeEngine:
         self.moe_local_assignments = 0
         self.moe_experts_hit = 0
         self.moe_dropped = 0
+        self.moe_rows_computed = 0  # rows of the expert kernel's live tiles
         self.window_blocks_skipped = 0
         kinds = layer_kinds(cache_cfg)
         # {window: how many layers of the whole stack have it}
@@ -1501,6 +1502,7 @@ class DecodeEngine:
                 self.moe_local_assignments += int(moe_stats[0])
                 self.moe_experts_hit += int(moe_stats[1])
                 self.moe_dropped += int(moe_stats[2])
+                self.moe_rows_computed += int(moe_stats[3])
             if self._window_layers and self._spec_fn is None:
                 self._count_window_blocks_skipped(mask)
             self._emit_chunk(toks, mask)

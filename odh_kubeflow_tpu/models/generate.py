@@ -117,7 +117,7 @@ def init_cache(
     if hasattr(cfg, "experts_held"):
         # a call's expert counters (``moe.local_expert_ffn``): the
         # engine zeroes them before a decode chunk and reads them with it
-        cache["moe_stats"] = jnp.zeros((3,), jnp.int32)
+        cache["moe_stats"] = jnp.zeros((4,), jnp.int32)
     return cache
 
 

@@ -228,9 +228,9 @@ def _routing_stats(
     parallel path can average f/p ACROSS batch shards before taking the
     product (matching the global-batch aux exactly; averaging the
     per-shard products would not)."""
-    probs = jax.nn.softmax(router_logits, axis=-1)  # [B,S,E]
-    top_p, top_idx = jax.lax.top_k(probs, cfg.num_experts_per_tok)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    top_p, top_idx, probs = route_softmax_topk(
+        router_logits, cfg.num_experts_per_tok
+    )
     E = router_logits.shape[-1]
     first_choice = jax.nn.one_hot(top_idx[..., 0], E, dtype=jnp.float32)
     if token_mask is None:
@@ -1732,12 +1732,26 @@ def _apply_layers_pipelined(
 # an expert-parallel share's expert layer: told which experts it holds
 
 
+def route_softmax_topk(
+    router_logits: jnp.ndarray,  # [..., E] float32
+    k: int,
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """A softmax over ALL the experts, the ``k`` largest probabilities,
+    divided by their sum (``norm_topk_prob``): the training path's
+    selection (``_routing_stats``) and a served share's alike. Returns
+    ``(weights [..., k] float32, ids [..., k], probs [..., E])``."""
+    probs = jax.nn.softmax(router_logits, axis=-1)
+    top_p, top_idx = jax.lax.top_k(probs, k)
+    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    return top_p, top_idx, probs
+
+
 def route_sigmoid_topk(
     router_logits: jnp.ndarray,  # [..., E] float32
     k: int,
     normalise: bool = True,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """The other published selection beside ``_routing_stats``'s softmax:
+    """The other published selection beside ``route_softmax_topk``:
     each expert's score is the SIGMOID of its own logit, the ``k``
     largest are chosen and (``norm_topk_prob``) their scores divided by
     their sum. Returns ``(weights [..., k] float32, ids [..., k])``."""
@@ -1833,14 +1847,22 @@ def local_expert_ffn(
     token_mask: Optional[jnp.ndarray] = None,  # [T] bool; False = not a token
     in_place: Optional[bool] = None,
     interpret: bool = False,
+    num_experts: Optional[int] = None,  # the router's width, where it sizes the tile
 ):
     """The part of a mixture layer's output that the experts HELD HERE
     give: ``sum over a token's chosen experts e that are held of w_e *
     (silu(h G_e) * (h U_e)) D_e``. The router ran over all the layer's
     experts; what the experts held elsewhere would add is their chips'
     to compute and is not stood in for. Dropless. Returns ``(out [T, D]
-    in h's dtype, stats int32 [3])``: local assignments, distinct held
-    experts hit, assignments dropped (always 0: counted, not assumed).
+    in h's dtype, stats int32 [4])``: local assignments, distinct held
+    experts hit, assignments dropped (always 0: counted, not assumed),
+    rows of the sorted layout that hold an expert's group (what the
+    kernel computes: ``n_live`` tiles of ``block_m``).
+
+    ``num_experts`` is the router's width: with it the row tile follows
+    the rows an expert can expect of this call (``pallas_moe_local.
+    block_m_for``: 512 experts give a part of 2048 tokens 40 rows each,
+    not 128); without it the tile follows the call's tokens alone.
     """
     from odh_kubeflow_tpu.ops import pallas_moe_local as pml
 
@@ -1848,13 +1870,15 @@ def local_expert_ffn(
     first, count = experts_held
     if in_place is None:
         in_place = reads_banks_in_place(banks)
-    block_m = pml.block_m_for(T)
+    block_m = pml.block_m_for(
+        T, None if num_experts is None else T * top_idx.shape[1] / num_experts
+    )
     d = local_dispatch(top_idx, token_mask, experts_held, block_m)
     M = d["token_of_row"].shape[0]
     w = jnp.where(d["local"], top_w, 0.0)
     stats = jnp.stack([
         jnp.sum(d["local"]), jnp.sum(d["sizes"] > 0),
-        jnp.sum(d["local"] & (d["row_of"] >= M)),
+        jnp.sum(d["local"] & (d["row_of"] >= M)), d["n_live"][0] * block_m,
     ]).astype(jnp.int32)
     if in_place:
         with jax.named_scope("moe_local_ffn"):

@@ -29,6 +29,7 @@ reaches HBM. int8 blocks are widened to the activations' dtype in VMEM
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -48,9 +49,21 @@ DEFAULT_BLOCK_F = 512
 _VMEM_LIMIT = 64 << 20
 
 
-def block_m_for(rows: int) -> int:
-    """The row tile for a call that routes ``rows`` tokens."""
-    return DECODE_BLOCK_M if rows <= 64 else PREFILL_BLOCK_M
+def block_m_for(rows: int, expected: Optional[float] = None) -> int:
+    """The row tile for a call that routes ``rows`` tokens. ``expected``
+    is the rows ONE expert can expect of them (tokens x choices / the
+    router's width), where the caller knows the router's width: the tile
+    is then the power of two that holds them, between the two tiles, so
+    that an expert's group is one tile and little of it is padding. A
+    caller that does not say gets the tile its tokens alone give."""
+    if rows <= 64:
+        return DECODE_BLOCK_M
+    if expected is None:
+        return PREFILL_BLOCK_M
+    tile = DECODE_BLOCK_M
+    while tile < min(expected, PREFILL_BLOCK_M):
+        tile *= 2
+    return tile
 
 
 def _kernel(layer_ref, expert_ref, live_ref, x_ref, g_ref, u_ref, d_ref,

@@ -1,6 +1,7 @@
 """Rotary position embeddings (plain RoPE with configurable theta), in
 the two pairings models are published with: the half-split one
-(``apply_rope``: pairs ``(i, i + hd/2)``, Llama / Mistral) and the
+(``apply_rope``: pairs ``(i, i + hd/2)``, Llama / Mistral; over a
+leading slice of the head where the angles are made for one) and the
 interleaved one (``apply_rope_interleaved``: pairs ``(2i, 2i + 1)``,
 GPT-J's, which Cohere's ``rope_gptj`` names). Both take the same
 ``rope_angles``.
@@ -29,16 +30,23 @@ def rope_angles(
 
 def apply_rope(
     x: jnp.ndarray,  # [B, S, H, hd]
-    sin: jnp.ndarray,  # [B, S, hd/2]
-    cos: jnp.ndarray,  # [B, S, hd/2]
+    sin: jnp.ndarray,  # [B, S, rd/2]: rd = hd, or a leading slice of it
+    cos: jnp.ndarray,  # [B, S, rd/2]
 ) -> jnp.ndarray:
+    """Half-split rotation of the head's first ``rd = 2 * sin.shape[-1]``
+    dims; the dims from ``rd`` on pass as they are (a
+    ``partial_rotary_factor`` below 1: the angles are made for ``rd``)."""
     dtype = x.dtype
     x = x.astype(jnp.float32)
+    rd = 2 * sin.shape[-1]
+    rest = []
+    if rd < x.shape[-1]:
+        x, rest = x[..., :rd], [x[..., rd:]]
     x1, x2 = jnp.split(x, 2, axis=-1)
     sin = sin[:, :, None, :]
     cos = cos[:, :, None, :]
     out = jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, *rest], axis=-1
     )
     return out.astype(dtype)
 

@@ -961,7 +961,8 @@ def _run_suspend_resume_property(tmp_path, chaos=None):
         committed = active_chips + sum(
             ck["spec"]["chips"]
             for ck in api.list("SessionCheckpoint", namespace="team-a")
-            if ck["status"].get("phase") in ("Suspended", "Resuming")
+            # a checkpoint just created has no status yet
+            if ck.get("status", {}).get("phase") in ("Suspended", "Resuming")
         )
         assert committed <= 24, f"committed {committed} chips > cap 24"
         # 3. no lost sessions: every live notebook is either active

@@ -376,3 +376,113 @@ def test_granite_stage_updates_state_and_cache_in_place(one_chip, monkeypatch, B
         # a part's activations: the sorted rows of 2048 tokens x 10
         # choices and the projection's [2048, 16768] outputs
         assert mem.temp_size_in_bytes < 2.5e9, mem.temp_size_in_bytes
+
+
+# ---- Qwen3-Next's share (qwen3_next): 16 key heads onto 32 value heads of
+# 128 x 128, chunks of 64; 32 slots; 256 held experts of width 512 under a
+# router of 512; attention at head 256, 16 / 2 heads
+
+
+@pytest.mark.parametrize("S", [2048, 64], ids=["part", "bucket-64"])
+def test_gdn_chunk_scan_compiles_at_published_widths(one_chip, S):
+    from odh_kubeflow_tpu.ops import pallas_gdn
+
+    Hk, H, dk, dv = 16, 32, 128, 128
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    compiled = jax.jit(pallas_gdn.gdn_chunk_scan).lower(*_on(one_chip, (
+        jax.ShapeDtypeStruct((1, S, Hk, dk), bf16),
+        jax.ShapeDtypeStruct((1, S, Hk, dk), bf16),
+        jax.ShapeDtypeStruct((1, S, H, dv), bf16),
+        jax.ShapeDtypeStruct((1, S, H), f32), jax.ShapeDtypeStruct((1, S, H), f32),
+        jax.ShapeDtypeStruct((1, H, dk, dv), f32),
+    ))).compile()
+    assert "gdn_chunk_scan" in compiled.as_text()
+
+
+def test_gdn_decode_update_is_one_pass_over_the_stacked_state(one_chip):
+    from odh_kubeflow_tpu.ops import pallas_gdn
+
+    L, B, Hk, H, dk, dv = 9, 32, 16, 32, 128, 128
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    state = jax.ShapeDtypeStruct((L, B, H, dk, dv), f32)
+    compiled = jax.jit(pallas_gdn.gdn_decode_update, donate_argnums=5).lower(
+        *_on(one_chip, (
+            jax.ShapeDtypeStruct((B, Hk, dk), bf16),
+            jax.ShapeDtypeStruct((B, Hk, dk), bf16),
+            jax.ShapeDtypeStruct((B, H, dv), bf16),
+            jax.ShapeDtypeStruct((B, H), f32), jax.ShapeDtypeStruct((B, H), f32),
+            state, jax.ShapeDtypeStruct((), jnp.int32),
+        ))
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert "gdn_decode_update" in compiled.as_text()
+    assert mem.alias_size_in_bytes >= state.size * 4
+    # q and k as columns and three rows a head (a few MB), never a layer's state
+    assert mem.temp_size_in_bytes < state.size * 4 // L // 4
+
+
+@pytest.mark.parametrize("B,S", [(32, 1), (1, 2048)], ids=["decode", "part"])
+def test_qwen3_next_share_updates_state_and_cache_in_place(one_chip, monkeypatch, B, S):
+    """One period at published widths, a decode step of 32 slots and a
+    part of 2048 positions, compiled for the chip: the delta-rule state
+    and the attention layer's keys and values are aliased, the kernels
+    are there (``decode_attend`` at head 256, ``moe_local_ffn`` on 256
+    banks at the tile its rows fill), and the temporaries are megabytes
+    (no layer's state, expert banks or dequantised projection is written
+    out)."""
+    from odh_kubeflow_tpu.models import moe
+    from odh_kubeflow_tpu.models import qwen3_next as qn
+
+    monkeypatch.setattr(llama, "_reads_cache_in_place", lambda leaf, hd: True)
+    monkeypatch.setattr(moe, "reads_banks_in_place", lambda banks: True)
+    monkeypatch.setattr(qn, "_uses_kernels", lambda: True)
+    cfg = qn.Qwen3NextConfig(num_layers=4, vocab_size=75968, experts_held=(0, 256))
+    params = jax.eval_shape(
+        lambda: qn.init_params(jax.random.key(0), cfg, jnp.bfloat16)
+    )
+    quantised = {
+        "layers": ("moe_gate", "moe_up", "moe_down", "sh_gate", "sh_up", "sh_down"),
+        "gdn": ("in_qkvz", "out_proj"), "attn": ("wq", "wk", "wv", "wo"),
+    }
+    for group, names in quantised.items():
+        for name in names:
+            params[group][name] = _int8_bank(params[group][name].shape)
+    max_len = 13312
+    cache = jax.eval_shape(lambda: init_cache(cfg, B, max_len, widest_part=2048))
+    assert cache["ssm"].shape == (3, B, 32, 128, 128)
+    assert cache["conv"].shape == (3, B, 3, 8192)
+    assert cache["k"].shape == (1, B, max_len, 512)
+
+    def step(params, cache, tokens, index, kv_mask):
+        return qn.forward_with_cache(
+            params, tokens, cfg, cache, index,
+            positions=jnp.broadcast_to(jnp.arange(S), (B, S)),
+            kv_mask=kv_mask, token_mask=kv_mask[:, :S],
+        )
+
+    compiled = jax.jit(step, donate_argnums=1).lower(*_on(one_chip, (
+        params, cache, jax.ShapeDtypeStruct((B, S), jnp.int32),
+        jax.ShapeDtypeStruct((B,) if S == 1 else (), jnp.int32),
+        jax.ShapeDtypeStruct((B, max_len), jnp.bool_),
+    ))).compile()
+    mem = compiled.memory_analysis()
+    stacks = sum(
+        v.size * v.dtype.itemsize for n, v in cache.items() if n != "moe_stats"
+    )
+    assert mem.alias_size_in_bytes >= stacks
+    text = compiled.as_text()
+    assert "decode_attend" in text and "moe_local_ffn" in text
+    assert ("gdn_decode_update" if S == 1 else "gdn_chunk_scan") in text
+    # the expert kernel's rows: 32 x 10 choices and a tile of 16 a bank;
+    # 2048 x 10 and a tile of 64 a bank (40 rows expected, not 128)
+    rows = 320 + 256 * 16 if S == 1 else 20480 + 256 * 64
+    assert f"bf16[{rows},2048]" in text
+    print(f"qwen3_next {B}x{S}: temp {mem.temp_size_in_bytes / 1e6:.1f} MB")
+    if S == 1:
+        # one slot's state in one layer is 2.1 MB, a layer's banks 805 MB,
+        # a dequantised W_qkvz 50 MB
+        assert mem.temp_size_in_bytes < 60e6, mem.temp_size_in_bytes
+    else:
+        # a part's activations: the sorted rows of 2048 tokens x 10
+        # choices and the projections' [2048, 12288] outputs
+        assert mem.temp_size_in_bytes < 0.6e9, mem.temp_size_in_bytes
