@@ -20,7 +20,11 @@ bf16; random weights from a seed), in ONE process on ONE device:
 3. *serve* — what ``python -m odh_kubeflow_tpu.models.serve --config
    llama3_1b --int8`` builds, on a local port: concurrent completions
    of different prompt lengths, two identical greedy prompts, one SSE
-   stream — every reply from the decode engine, which has not failed.
+   stream — every reply from the decode engine, which has not failed;
+4. *parts_ahead* — one period of Qwen3-Next at published widths behind
+   a small engine: a prompt admitted in three parts beside a decoding
+   stream, each part dispatched behind a decode chunk before its fetch,
+   token for token what the same prompt gives admitted whole.
 
 It checks that no fallback that hides the device fired: attention
 resolved to ``flash``, no pallas op defaulted to interpret mode, the
@@ -772,6 +776,81 @@ def serve_leg(cfg) -> dict:
         engine.stop()
 
 
+def check_parts_go_ahead() -> dict:
+    """The engine's turn on the real device (PR 36): one period of
+    Qwen3-Next at published widths (three Gated DeltaNet layers to one
+    gated-attention layer; 32 int8 expert banks, so the chunk's expert
+    counters live in the cache), a prompt admitted in three parts of 256
+    while another stream decodes. Each part is dispatched BEHIND a
+    decode chunk, before that chunk's tokens are fetched, and the final
+    one donates the state those tokens' counters came in. Holds:
+    ``parts_ahead`` > 0, the stream beside it and the admitted request
+    both end, and the admitted request's greedy tokens are those of the
+    same prompt admitted whole (a bucket of 1024 on an engine of its
+    own): the kernels' per-part path (``gdn_chunk_scan`` handed a state
+    from part to part, ``decode_attend`` over a part, ``moe_local_ffn``
+    at a part's tile) against their whole-prompt path."""
+    import numpy as np
+
+    from odh_kubeflow_tpu.models import qwen3_next as qn
+    from odh_kubeflow_tpu.models.engine import DecodeEngine
+    from odh_kubeflow_tpu.models.quant import quantize_tensor
+
+    cfg = qn.Qwen3NextConfig(
+        num_layers=4, vocab_size=4096, num_experts=32, experts_held=(0, 32)
+    )
+    params = qn.init_params(jax.random.key(36), cfg, jnp.bfloat16)
+    for name in ("moe_gate", "moe_up", "moe_down"):
+        params["layers"][name] = quantize_tensor(params["layers"][name])
+    rng = np.random.default_rng(36)
+    short = rng.integers(1, cfg.vocab_size, size=20).tolist()
+    long = rng.integers(1, cfg.vocab_size, size=2 * 256 + 40).tolist()
+    shape = dict(n_slots=4, max_len=2048, chunk=4, prompt_buckets=(64, 1024))
+    n = 8
+
+    engine = DecodeEngine(params, cfg, prefill_chunk=256, **shape)
+    try:
+        # every program compiled before the order of dispatches is read
+        engine.submit(long, max_tokens=2).result(timeout=900)
+        engine.submit(short, max_tokens=n).result(timeout=900)
+        parts, ahead = engine.parts, engine.parts_ahead
+        t = time.monotonic()
+        beside = engine.submit(short, max_tokens=64, stream=True)
+        stream = beside.iter_tokens(timeout=900)
+        first = [next(stream)]  # it decodes
+        in_parts = engine.submit(long, max_tokens=n).result(timeout=900)
+        beside_tokens = first + list(stream)
+        wall = time.monotonic() - t
+        parts, ahead = engine.parts - parts, engine.parts_ahead - ahead
+        experts_hit, failure = engine.moe_experts_hit, engine.failure
+    finally:
+        engine.stop()
+    if failure is not None:
+        raise AssertionError(f"engine failed: {failure!r}")
+    if parts != 3 or not 0 < ahead <= parts:
+        raise AssertionError(f"{ahead} of {parts} parts went out ahead")
+    if len(beside_tokens) != 64 or experts_hit <= 0:
+        raise AssertionError(
+            f"{len(beside_tokens)} tokens beside the admission, "
+            f"{experts_hit} expert banks counted"
+        )
+    engine = DecodeEngine(params, cfg, **shape)
+    try:
+        whole = engine.submit(long, max_tokens=n).result(timeout=900)
+        alone = engine.submit(short, max_tokens=64).result(timeout=900)
+    finally:
+        engine.stop()
+    if in_parts != whole or beside_tokens != alone:
+        raise AssertionError(
+            f"in parts {in_parts} / whole {whole}; beside the admission "
+            f"{beside_tokens} / alone {alone}"
+        )
+    return {
+        "parts": parts, "parts_ahead": ahead, "tokens": in_parts,
+        "tokens_beside": len(beside_tokens), "wall_s": round(wall, 3),
+    }
+
+
 def main() -> int:
     t0 = time.monotonic()
     try:
@@ -825,6 +904,7 @@ def main() -> int:
         ("kernels", lambda: check_kernels(cfg)),
         ("train", lambda: train_leg(cfg, device)),
         ("serve", lambda: serve_leg(cfg)),
+        ("parts_ahead", check_parts_go_ahead),
     )
     failed = []
     for name, fn in phases:

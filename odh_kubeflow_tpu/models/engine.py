@@ -18,6 +18,18 @@ into the running decode loop**:
 - a prefill's first token is streamed when the prefill ends: the turn
   dispatches the chunk behind the prefills, fetches and emits each
   first token as its prefill ends, and only then blocks on the chunk;
+- the device has a program queued whenever the host knows one: what
+  needs no token of the chunk in flight goes out BEHIND that chunk,
+  before its fetch (the next part of an admission in parts: its
+  prompt, its private cache and its offset were all known before the
+  chunk was), and a chunk's tokens are SETTLED at the fetch (counted
+  for their requests, ended requests' slots freed, counters moved: all
+  the next admit phase reads) and PUBLISHED (appended to their
+  requests, stamped, put on their streams, requests finished) once the
+  next turn's prefills and chunk have been dispatched. A decode chunk
+  itself is never dispatched before the last one's tokens are settled:
+  which slots it would decode, and which arrivals it would leave
+  waiting, are in those tokens;
 - static shapes throughout: compile count = #prompt_buckets + 1,
   independent of request mix (XLA discipline — no shape depends on
   arrival order or request params);
@@ -233,8 +245,13 @@ class _Request:
     @property
     def complete(self) -> bool:
         """Every token it asked for, or its eos, has been emitted."""
-        return len(self.tokens) >= self.max_tokens or (
-            bool(self.tokens) and self.tokens[-1] == self.eos_id
+        return self.completed_by(())
+
+    def completed_by(self, more: Sequence[int]) -> bool:
+        """``complete`` as it will read once ``more`` have been emitted
+        too."""
+        return len(self.tokens) + len(more) >= self.max_tokens or (
+            list((more or self.tokens)[-1:]) == [self.eos_id]
         )
 
     def cancel(self) -> None:
@@ -515,6 +532,12 @@ class DecodeEngine:
         if draft_params is not None:
             dcache_cfg, _ = family_forward(draft_cfg)
             self._state["dcache"] = self._new_cache(dcache_cfg, S)
+        # a second home for a chunk's expert counters, made and placed
+        # as the cache's own: ``_take_chunk_stats`` swaps the two
+        self._stats_spare = (
+            jnp.zeros_like(self._state["cache"]["moe_stats"])
+            if "moe_stats" in self._state["cache"] else None
+        )
         if mesh is not None:
             from jax.sharding import NamedSharding
 
@@ -534,6 +557,10 @@ class DecodeEngine:
                 )
                 for k, v in self._state.items()
             }
+            if self._stats_spare is not None:
+                self._stats_spare = jax.device_put(
+                    self._stats_spare, cspec["moe_stats"]
+                )
         # the cache by kind of layer, and what the kinds ask of the
         # engine's own indexing
         self.cache_bytes = cache_bytes(self._state["cache"])
@@ -616,6 +643,17 @@ class DecodeEngine:
         # first tokens emitted ahead of their turn's chunk fetch: every
         # request that reached a slot with max_tokens > 1
         self.first_tokens_early = 0
+        # parts of admissions in parts dispatched (interior and final),
+        # and those of them that went out behind a decode chunk before
+        # its fetch, so that the device walks from the chunk into the
+        # part with no host in between
+        self.parts = 0
+        self.parts_ahead = 0
+        # the admission's part for the coming turn has already gone out
+        self._part_ahead = False
+        # the last chunk's tokens, settled and not yet published (nor
+        # in ``req.tokens``): ([(req, tokens, ended)], slots then held)
+        self._settled: Optional[tuple] = None
         # set on unrecoverable device failure; submit() then raises
         self.failure: Optional[Exception] = None
         self._slot_req: list[Optional[_Request]] = [None] * S
@@ -1143,6 +1181,8 @@ class DecodeEngine:
         the request (for its ``engine.request`` span) and noted as an
         event on the turn's ``engine.admit``."""
         self.prefill_calls += 1
+        if part.startswith(("part@", "final")):
+            self.parts += 1
         self.prefill_tokens += tokens
         self.prefill_positions += bucket if tokens else 0
         req.slot, req.bucket, req.prefix_hit = slot, bucket, prefix_hit
@@ -1315,6 +1355,9 @@ class DecodeEngine:
         for its consumers."""
         if self.failure is None:
             self.failure = exc
+        # what was settled was served: its clients get it, and a request
+        # that ended with it finishes as it would have
+        self._publish()
         self._admitting = None  # its request is failed via _slot_req
         # the span that raised (a phase of the turn, or the admission's
         # request) carries status ``error``; every other request names
@@ -1372,6 +1415,7 @@ class DecodeEngine:
                 and not self._held
                 and all(r is None for r in self._slot_req)
                 and self._queue.empty()
+                and self._settled is None  # else: a turn, to publish it
             ):
                 # nothing in flight, nothing queued: one span per idle
                 # period, a root of its own (never "slow"), so that an
@@ -1392,21 +1436,39 @@ class DecodeEngine:
                     return
 
     def _turn(self) -> bool:
-        """One turn of the loop: admit, then (if anything decodes)
-        dispatch a chunk, fetch and emit the first token of each
-        prefill this turn admitted, fetch the chunk, emit. Each phase
-        is a child span of the caller's ``engine.turn`` and they tile
-        it; a phase that fails closes with status ``error``. False: the
-        loop must exit (stop sentinel, or the engine failed)."""
+        """One turn of the loop: admit; then, if anything decodes,
+        dispatch a chunk; publish the LAST chunk's tokens and dispatch
+        the next part of an admission in parts behind the chunk; fetch
+        and emit the first token of each prefill that has not streamed
+        its own yet; fetch the chunk; settle its tokens. Each phase is a
+        child span of the caller's ``engine.turn`` and they tile it; a
+        phase that fails closes with status ``error``. False: the loop
+        must exit (stop sentinel, or the engine failed).
+
+        What goes out before the chunk's fetch is what needs nothing of
+        it. A part of an admission needs its prompt, its private cache
+        and its offset, all known before the chunk was dispatched, so it
+        is queued behind the chunk and the device walks from one into
+        the other with no host in between (``engine.admit`` with
+        ``ahead=1``; it is the coming turn's one part, and the top of
+        that turn runs none: on the device the order stays part, whole
+        prompts, chunk). Publishing the last chunk's tokens (stamps,
+        streams, histograms, finished requests' traces: ``engine.emit``
+        with ``deferred=1``) needs nothing of the device at all, so it
+        waits for this turn's dispatches and then runs under them. The
+        NEXT decode chunk does need this one's tokens: they say which
+        slots ended and are free for the arrivals waiting, so no chunk
+        is ever in flight across a turn's end, and at every such end
+        the slots hold exactly the tokens their requests do."""
         adm = self._admitting
-        with hot_span("engine.admit", **({} if adm is None else {
-            # an admission in parts: how many it takes in all
-            "parts": -(-len(adm["req"].prompt) // self.prefill_chunk),
-        })):
-            if self._admitting is not None:
+        went_ahead, self._part_ahead = self._part_ahead, False
+        with hot_span("engine.admit", **self._parts_attr()):
+            if adm is not None and (not went_ahead or adm["req"].cancelled):
                 # one prefill part per loop turn: active slots get a
-                # decode chunk below before the next part runs
-                req = self._admitting["req"]
+                # decode chunk below before the next part runs. A part
+                # that went out behind the last chunk WAS this turn's
+                # (its request, if cancelled since, is dropped here)
+                req = adm["req"]
                 try:
                     self._admit_step()
                 except Exception as e:  # noqa: BLE001 — state integrity unknown
@@ -1454,7 +1516,8 @@ class DecodeEngine:
             for s, r in enumerate(self._slot_req)
         ):
             # nothing decoding: a chunked admission runs its parts
-            # back-to-back, one turn each
+            # back-to-back, one turn each, at the top
+            self._publish_in_turn()
             return True
         # two compiled chunk programs: the greedy one (argmax alone)
         # whenever every in-flight request is greedy, else the general
@@ -1477,9 +1540,34 @@ class DecodeEngine:
         try:
             with hot_span("engine.dispatch", program=program):
                 self._state, (toks, mask) = chunk_fn(weights, self._state)
+                moe_stats = self._take_chunk_stats()
             if self._spec_fn is not None:
                 self.spec_rounds += self.spec_rounds_per_call
+        except Exception as e:  # noqa: BLE001 — state integrity unknown
+            self._fail_engine(e)
+            return False
+        # the device has the turn's prefills and its chunk queued: what
+        # the host still owes from the last turn runs under them
+        self._publish_in_turn()
+        if self._admitting is not None:
+            with hot_span("engine.admit", ahead=1, **self._parts_attr()):
+                req = self._admitting["req"]
+                parts = self.parts
+                try:
+                    self._admit_step()
+                except Exception as e:  # noqa: BLE001 — state integrity unknown
+                    self._fail_admission(req, e)
+                    return False
+                # a cancelled request's step dispatched nothing
+                self._part_ahead = self.parts > parts
+                self.parts_ahead += self.parts - parts
+        try:
             for req, first, slot in pending:
+                if self._slot_req[slot] is not req:
+                    # the final part went out behind the last chunk and
+                    # its request was cancelled before this fetch: the
+                    # slot is no longer its own
+                    continue
                 # the device runs its programs in order: this token
                 # exists when the request's own prefill ends, with the
                 # turn's later prefills and the chunk running behind
@@ -1491,24 +1579,47 @@ class DecodeEngine:
                     self._emit_first(req, tok, slot)
             # the host waiting for the device: the chunk's tokens
             with hot_span("engine.fetch"):
-                toks, mask, moe_stats = jax.device_get((
-                    toks, mask, self._state["cache"].get("moe_stats"),
-                ))
+                toks, mask, stats = jax.device_get((toks, mask, moe_stats))
         except Exception as e:  # noqa: BLE001 — state integrity unknown
             self._fail_engine(e)
             return False
         with hot_span("engine.emit"):
-            if moe_stats is not None:
-                self.moe_local_assignments += int(moe_stats[0])
-                self.moe_experts_hit += int(moe_stats[1])
-                self.moe_dropped += int(moe_stats[2])
-                self.moe_rows_computed += int(moe_stats[3])
+            if stats is not None:
+                self.moe_local_assignments += int(stats[0])
+                self.moe_experts_hit += int(stats[1])
+                self.moe_dropped += int(stats[2])
+                self.moe_rows_computed += int(stats[3])
             if self._window_layers and self._spec_fn is None:
                 self._count_window_blocks_skipped(mask)
-            self._emit_chunk(toks, mask)
+            self._settle_chunk(toks, mask)
             if program == "sample":
                 self.decode_calls_sampled += 1
         return True
+
+    def _parts_attr(self) -> dict:
+        """For an ``engine.admit`` span while an admission in parts is
+        under way: how many parts it takes in all."""
+        adm = self._admitting
+        return {} if adm is None else {
+            "parts": -(-len(adm["req"].prompt) // self.prefill_chunk),
+        }
+
+    def _take_chunk_stats(self):
+        """The expert counters of the chunk just dispatched (a device
+        value, None where the cache keeps none), taken OUT of the state
+        and a spare put in their place. A final part dispatched behind
+        the chunk donates the state, and with it every buffer the state
+        holds: the counters the host is about to fetch must not be among
+        them. The spare is the buffer the last chunk's counters came in,
+        fetched a turn ago; what it holds is never read (the next chunk
+        zeroes its counters and the prefill programs hand them through).
+        Not beside a draft: its rounds add to the counters they find,
+        and no part is ever dispatched beside them (``submit``)."""
+        cache = self._state["cache"]
+        stats = cache.get("moe_stats")
+        if stats is not None and self._spec_fn is None:
+            cache["moe_stats"], self._stats_spare = self._stats_spare, stats
+        return stats
 
     def _count_window_blocks_skipped(self, mask) -> None:
         """kv-blocks that lie wholly before a window layer's window and
@@ -1546,13 +1657,24 @@ class DecodeEngine:
             req._finish()
             self._slot_req[slot] = None
 
-    def _emit_chunk(self, toks, mask) -> None:
+    def _settle_chunk(self, toks, mask) -> None:
+        """What the next admit phase and the counters' readers depend
+        on, right after the chunk's fetch: each slot's live tokens are
+        taken for their request, a request they complete (or a cancelled
+        one) gives up its slot, the step and token counts move. The
+        tokens themselves, with their stamps, streams and everything
+        else a client sees, wait in ``_settled`` for ``_publish``, where
+        each goes through ``_Request._emit`` as a first token does: at
+        most one chunk's tokens, for the length of one admit and
+        dispatch, and always published before the next chunk is
+        settled, so ``req.tokens`` here is all that came before."""
         self.decode_calls += 1
         self.decode_steps += (
             self.spec_rounds_per_call
             if self._spec_fn is not None
             else self.chunk
         )
+        settled = []
         for slot, req in enumerate(self._slot_req):
             if req is None:
                 continue
@@ -1571,21 +1693,44 @@ class DecodeEngine:
                 self._state["active"] = (
                     self._state["active"].at[slot].set(False)
                 )
-                req._finish()
                 self._slot_req[slot] = None
+                settled.append((req, (), True))
                 continue
-            for t, live in zip(toks[slot], mask[slot]):
-                if live:
-                    req._emit(int(t))
-                    self._observe_emit(req)
-                    self.tokens_emitted += 1
-            if req.complete:
-                req._finish()
+            live = toks[slot][mask[slot]].tolist()
+            self.tokens_emitted += len(live)
+            ended = req.completed_by(live)
+            if ended:
                 self._slot_req[slot] = None
-        self.m_occupancy.set(
-            sum(1 for r in self._slot_req if r is not None)
-            / float(self.n_slots)
-        )
+            if live or ended:
+                settled.append((req, live, ended))
+        held = sum(1 for r in self._slot_req if r is not None)
+        self._settled = (settled, held)
+
+    def _publish_in_turn(self) -> None:
+        """``_publish`` as a phase of the turn under way, where there
+        is something to publish."""
+        if self._settled is not None:
+            with hot_span("engine.emit", deferred=1):
+                self._publish()
+
+    def _publish(self) -> None:
+        """The last chunk's settled tokens as their clients see them:
+        appended to their requests, stamped now, put on their streams,
+        observed, and the requests that ended with them finished (done,
+        trace written). Called once
+        the turn's programs have been dispatched, where a turn finds
+        nothing to dispatch, and before the engine fails or stops, so
+        nothing settled is ever left unpublished."""
+        if self._settled is None:
+            return
+        (settled, held), self._settled = self._settled, None
+        for req, live, ended in settled:
+            for tok in live:
+                req._emit(tok)
+                self._observe_emit(req)
+            if ended:
+                req._finish()
+        self.m_occupancy.set(held / float(self.n_slots))
 
     # -- public API ---------------------------------------------------------
 

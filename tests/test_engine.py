@@ -7,6 +7,7 @@ arrivals must share decode steps (the rate that buys is a cell's to
 measure, not a CPU test's).
 """
 
+import threading
 import time
 
 import jax
@@ -562,7 +563,7 @@ def test_first_token_is_streamed_before_its_turns_chunk_is_fetched(model):
         engine.stop()
     assert [first] + rest == _reference_greedy(params, cfg, [5, 9, 13], 6)
     # the turn that admitted it: admit, dispatch, then the first tokens'
-    # fetch and emit, then the chunk's fetch
+    # fetch and emit, then the chunk's fetch, and its tokens settled
     early, chunk = sorted(
         ring.spans_named("engine.fetch"), key=lambda s: s.start_mono
     )[:2]
@@ -574,6 +575,25 @@ def test_first_token_is_streamed_before_its_turns_chunk_is_fetched(model):
     assert got_first_at < chunk.start_mono + delay
     assert req.times[1] - req.times[0] >= delay
     assert engine.first_tokens_early == 2
+    # the chunk's tokens are published by the NEXT turn, once it has
+    # dispatched its own chunk: under it, not before it
+    end = lambda s: s.start_mono + s.duration  # noqa: E731
+    phases = sorted(
+        (
+            s for s in ring.spans_named("engine.")
+            if s.name.split(".")[1] in ("admit", "dispatch", "fetch", "emit")
+        ),
+        key=lambda s: s.start_mono,
+    )
+    after = [s for s in phases if s.trace_id != chunk.trace_id]
+    assert [s.name for s in after[:3]] == [
+        "engine.admit", "engine.dispatch", "engine.emit",
+    ]
+    assert after[2].attrs == {"deferred": 1}
+    assert end(after[1]) <= after[2].start_mono <= req.times[1] <= end(after[2])
+    settle = [s for s in phases if s.trace_id == chunk.trace_id][-1]
+    assert settle.name == "engine.emit" and not settle.attrs
+    assert end(settle) <= after[0].start_mono
 
 
 def test_first_token_does_not_wait_for_a_later_prefill_of_its_turn(model):
@@ -729,3 +749,311 @@ def test_first_tokens_early_counts_the_requests_that_reached_a_slot(model):
         )
     finally:
         engine.stop()
+
+
+# ---- what goes out behind a chunk, and what is published under the next
+
+
+def _device_order(ring, req):
+    """What the loop handed the device, in order, as its spans say:
+    ``P`` for a part of ``req``'s admission, ``D`` for a decode chunk."""
+    marks = []
+    for s in ring.spans_named("engine."):
+        if s.name == "engine.dispatch":
+            marks.append((s.start_mono, "D"))
+        elif s.name == "engine.admit":
+            marks += [
+                (s.start_mono, "P") for ev in s.events
+                if ev[2]["request"] == req.request_id
+            ]
+    return "".join(m for _, m in sorted(marks))
+
+
+@pytest.mark.parametrize("begun", ["beside_decode", "alone"])
+def test_parts_go_out_behind_the_chunk_while_other_slots_decode(model, begun):
+    """A prompt admitted in four parts while two streams decode: every
+    request gets the tokens it would have got alone, each part that
+    follows a chunk was dispatched behind it before its fetch
+    (``parts_ahead``: all four where the admission begins beside a
+    decode, all but the first where it begins alone and its first part
+    runs at the top of a turn), and on the device parts and chunks
+    alternate as before: part, chunk, part, chunk."""
+    cfg, params = model
+    long = list(range(3, 3 + 53))  # 16 + 16 + 16 + 5
+    # (prompts whose greedy streams hold no near-tie over 60 tokens: the
+    # engine's batch of three and generate's of one round differently)
+    shorts = [[3, 5, 8], [9, 8, 7]]
+    engine = DecodeEngine(
+        params, cfg, n_slots=3, max_len=128, chunk=2, prompt_buckets=(16,),
+        cache_dtype=jnp.float32, prefill_chunk=16,
+    )
+    ring = tracing.SpanCollector()
+    try:
+        # every program compiled before anything is counted
+        engine.submit(long, max_tokens=2).result(timeout=300)
+        engine.submit(shorts[0], max_tokens=3).result(timeout=300)
+        parts, ahead = engine.parts, engine.parts_ahead
+        assert (parts, ahead) == (4, 0)  # alone: each at the top of a turn
+        old = tracing.set_collector(ring)
+        try:
+            if begun == "beside_decode":
+                reqs = [engine.submit(p, max_tokens=60) for p in shorts]
+                while sum(r is not None for r in engine._slot_req) < 2:
+                    time.sleep(0.002)
+                reqs.append(engine.submit(long, max_tokens=7))
+            else:
+                # the loop is held inside the first part's dispatch, at
+                # the top of a turn, until the two short prompts queue up
+                part_fn = engine._prefill_fns[("part", 16)]
+                gate = threading.Event()
+
+                def held(*a):
+                    gate.wait(timeout=300)
+                    return part_fn(*a)
+
+                engine._prefill_fns[("part", 16)] = held
+                reqs = [engine.submit(long, max_tokens=7)]
+                while engine.parts == parts:
+                    time.sleep(0.002)
+                reqs = [engine.submit(p, max_tokens=60) for p in shorts] + reqs
+                gate.set()
+            got = [r.result(timeout=300) for r in reqs]
+        finally:
+            tracing.set_collector(old)
+    finally:
+        engine.stop()
+    for prompt, toks in zip(shorts + [long], got):
+        assert toks == _reference_greedy(params, cfg, prompt, len(toks))
+    assert engine.parts - parts == 4
+    assert engine.parts_ahead - ahead == (4 if begun == "beside_decode" else 3)
+    ahead_spans = [
+        s for s in ring.spans_named("engine.admit") if s.attrs.get("ahead")
+    ]
+    assert len(ahead_spans) == engine.parts_ahead - ahead
+    assert all(s.attrs == {"ahead": 1, "parts": 4} for s in ahead_spans)
+    # one part, then a chunk for the slots that decode, then the next
+    order = _device_order(ring, reqs[-1])
+    assert "PP" not in order and order.count("P") == 4
+    assert "PDPDPDP" in order
+    # a part that went out ahead is dispatched between its turn's chunk
+    # and that chunk's fetch
+    for s in ahead_spans:
+        turn = sorted(
+            (k for k in ring.trace(s.trace_id) if k.parent_span_id),
+            key=lambda k: k.start_mono,
+        )
+        names = [k.name for k in turn]
+        assert names.index("engine.dispatch") < turn.index(s) < (
+            len(names) - 1 - names[::-1].index("engine.fetch")
+        )
+
+
+@pytest.fixture(scope="module")
+def hybrid():
+    """A tiny stack with a recurrent state beside its cache and a
+    mixture's counters in it (Granite 4.0-H's family)."""
+    from odh_kubeflow_tpu.models import granite_hybrid as gh
+
+    cfg = gh.GraniteHybridConfig.tiny(dtype=jnp.float32)
+    return cfg, gh.init_params(jax.random.key(0), cfg)
+
+
+def _state_after(cfg, params, tokens, max_len):
+    """The recurrent state the family's own cached forward leaves after
+    ``tokens``, one call on a fresh cache: ``{name: [layers, ...]}``."""
+    from odh_kubeflow_tpu.models.generate import family_forward, init_cache
+    from odh_kubeflow_tpu.models.llama import STATE, stack_kind
+
+    cache_cfg, fwd = family_forward(cfg)
+    n = len(tokens)
+    _, cache = fwd(
+        params, jnp.asarray([tokens], jnp.int32), cfg,
+        init_cache(cache_cfg, 1, max_len, jnp.float32), jnp.int32(0),
+        positions=jnp.arange(n, dtype=jnp.int32)[None],
+        kv_mask=(jnp.arange(max_len) < n)[None],
+        token_mask=jnp.ones((1, n), jnp.bool_),
+    )
+    return {
+        name: np.asarray(leaf[:, 0]) for name, leaf in cache.items()
+        if stack_kind(name) == STATE
+    }
+
+
+class _Lost:
+    """A chunk's tokens that never reach the host."""
+
+    def __array__(self, *args, **kwargs):
+        raise RuntimeError("device lost")
+
+
+@pytest.mark.parametrize("how", ["stop", "part_ahead_fails", "fetch_fails"])
+def test_nothing_settled_is_left_unpublished_when_the_loop_ends(hybrid, how):
+    """The engine stops, or a program it dispatched fails (a part sent
+    out behind a chunk; a chunk whose tokens never arrive), while one
+    stream decodes beside an admission in parts: every reader's stream
+    ends, ``result()`` raises, and each reader has read exactly the
+    tokens its request holds (a chunk's tokens settled and not yet
+    published are published first). Stopped, the slot of the request
+    still decoding holds the state of exactly those tokens."""
+    cfg, params = hybrid
+    rng = np.random.default_rng(36)
+    engine = DecodeEngine(
+        params, cfg, n_slots=3, max_len=128, chunk=4, prompt_buckets=(8, 16),
+        prefill_chunk=16, cache_dtype=jnp.float32,
+    )
+    long = rng.integers(1, 256, size=70).tolist()  # five parts
+    read = {}
+
+    def reader(name, req):
+        toks = read.setdefault(name, [])
+        try:
+            for tok in req.iter_tokens(timeout=300):
+                toks.append(tok)
+        except RuntimeError as e:
+            read[name + ".error"] = e
+
+    try:
+        # every program compiled
+        engine.submit(long[:37], max_tokens=2).result(timeout=300)
+        engine.submit(long[:5], max_tokens=5).result(timeout=300)
+        prompt = rng.integers(1, 256, size=11).tolist()
+        decoding = engine.submit(prompt, max_tokens=100, stream=True)
+        threads = [threading.Thread(target=reader, args=("decoding", decoding))]
+        threads[0].start()
+        while len(decoding.tokens) < 9:
+            time.sleep(0.002)
+        if how == "part_ahead_fails":
+            part_fn = engine._prefill_fns[("part", 16)]
+            calls = []
+
+            def third_part_is_lost(*a):
+                calls.append(None)
+                if len(calls) == 3:
+                    raise RuntimeError("device lost")
+                return part_fn(*a)
+
+            engine._prefill_fns[("part", 16)] = third_part_is_lost
+        elif how == "fetch_fails":
+            chunk_fn = engine._decode_greedy_fn
+            chunks = []
+
+            def third_chunk_is_lost(*a):
+                state, (toks, mask) = chunk_fn(*a)
+                chunks.append(None)
+                return state, (_Lost() if len(chunks) == 3 else toks, mask)
+
+            engine._decode_greedy_fn = third_chunk_is_lost
+        admitted = engine.submit(long, max_tokens=30, stream=True)
+        threads.append(threading.Thread(target=reader, args=("admitted", admitted)))
+        threads[1].start()
+        if how == "stop":
+            while engine.parts_ahead < 2:
+                time.sleep(0.002)
+        else:
+            assert decoding.done.wait(timeout=300)
+    finally:
+        engine.stop()
+    for t in threads:
+        t.join(timeout=300)
+        assert not t.is_alive()
+    lost = "stopped" if how == "stop" else "device lost"
+    for name, req in (("decoding", decoding), ("admitted", admitted)):
+        assert read[name] == req.tokens and len(req.times) == len(req.tokens)
+        assert lost in str(read[name + ".error"])
+        with pytest.raises(RuntimeError, match=lost):
+            req.result(timeout=300)
+    assert engine._settled is None
+    assert 9 <= len(decoding.tokens) < 100 and not decoding.complete
+    if how != "stop":
+        assert lost in str(engine.failure)
+        assert engine.parts_ahead >= 2
+        return
+    state = engine.slot_state(decoding.slot)
+    want = _state_after(cfg, params, prompt + decoding.tokens[:-1], 128)
+    assert set(state) == set(want) and state
+    for name in state:
+        np.testing.assert_allclose(
+            state[name], want[name], rtol=2e-4, atol=2e-5, err_msg=name
+        )
+
+
+def test_a_request_cancelled_after_its_part_went_ahead_frees_the_lane(model):
+    """An admission in parts is cancelled once one of its parts has gone
+    out behind a chunk: it is dropped at its next step, its slot is
+    free again, and the long prompt held behind it for the lane is
+    admitted and served as it would have been alone."""
+    cfg, params = model
+    rng = np.random.default_rng(7)
+    long_a, long_b = (rng.integers(1, 200, size=n).tolist() for n in (100, 40))
+    engine = DecodeEngine(
+        params, cfg, n_slots=3, max_len=160, chunk=2, prompt_buckets=(8,),
+        prefill_chunk=8, cache_dtype=jnp.float32,
+    )
+    try:
+        engine.submit(long_a[:20], max_tokens=2).result(timeout=300)  # compiles
+        running = engine.submit([3, 5, 8], max_tokens=120)
+        while not running.tokens:
+            time.sleep(0.002)
+        a = engine.submit(long_a, max_tokens=4)  # thirteen parts
+        b = engine.submit(long_b, max_tokens=4)
+        while engine.parts_ahead < 2:
+            time.sleep(0.002)
+        assert list(engine._held) == [b] or b.admit_t is None
+        a.cancel()
+        assert a.done.wait(timeout=300) and not a.tokens
+        got = b.result(timeout=300)
+        assert b.slot == a.slot and not engine._held
+        assert got == _reference_greedy(params, cfg, long_b, 4)
+        assert running.result(timeout=300) == _reference_greedy(
+            params, cfg, [3, 5, 8], 120
+        )
+        # a's parts stopped where it was dropped
+        assert engine.parts < 3 + 13 + 5
+    finally:
+        engine.stop()
+
+
+def test_a_slot_freed_by_a_chunk_is_taken_in_the_very_next_turn(model):
+    """A request that ends in a chunk gives up its slot when that chunk
+    is settled, before its last tokens are published: the request
+    waiting for a slot is admitted by the turn that follows, not a
+    chunk later."""
+    cfg, params = model
+    engine = DecodeEngine(
+        params, cfg, n_slots=2, max_len=128, chunk=4, prompt_buckets=(16,),
+        cache_dtype=jnp.float32,
+    )
+    ring = tracing.SpanCollector()
+    try:
+        engine.submit([5, 9, 13], max_tokens=6).result(timeout=300)  # compiles
+        old = tracing.set_collector(ring)
+        try:
+            # 1 + 8 tokens: ends with its second chunk; the other decodes on
+            ending = engine.submit([7, 7, 7], max_tokens=9)
+            other = engine.submit([4, 4, 4], max_tokens=40)
+            while sum(r is not None for r in engine._slot_req) < 2:
+                time.sleep(0.002)
+            waiting = engine.submit([5, 9, 13], max_tokens=5)
+            for r in (ending, other, waiting):
+                r.result(timeout=300)
+        finally:
+            tracing.set_collector(old)
+    finally:
+        engine.stop()
+    assert waiting.tokens == _reference_greedy(params, cfg, [5, 9, 13], 5)
+    assert waiting.slot == ending.slot
+    end = lambda s: s.start_mono + s.duration  # noqa: E731
+    # the chunk that ended it was settled by the last ``engine.emit`` that
+    # is neither a first token's nor a publishing one before its last
+    # token's stamp ...
+    settled_at = max(
+        end(s) for s in ring.spans_named("engine.emit")
+        if not s.attrs and end(s) <= ending.times[-1]
+    )
+    # ... and the request waiting was admitted after that, with no chunk
+    # dispatched in between, and before those last tokens were published
+    assert settled_at < waiting.admit_t < ending.times[-4] <= ending.finish_t
+    assert not [
+        s for s in ring.spans_named("engine.dispatch")
+        if settled_at <= s.start_mono <= waiting.admit_t
+    ]

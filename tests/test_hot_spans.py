@@ -22,7 +22,10 @@ from odh_kubeflow_tpu.utils.profiling import hot_span
 
 EPS = 1e-9
 PHASES = ("engine.admit", "engine.dispatch", "engine.fetch", "engine.emit")
-KINDS = ("plain", "chunked", "prefix_hit", "max_tokens_1", "cancelled_queued")
+KINDS = (
+    "plain", "chunked", "prefix_hit", "max_tokens_1", "cancelled_queued",
+    "parts_beside_decode",
+)
 
 
 @pytest.fixture(scope="module")
@@ -83,7 +86,15 @@ def served(model):
         for r in busy:
             r.result(timeout=120)
         assert reqs["cancelled_queued"].done.wait(timeout=120)
-        n_requests = 8
+        # a prompt admitted in three parts (32 + 32 + 6) while another
+        # stream decodes (26 chunks): each part goes out behind a chunk
+        running = engine.submit([9, 8, 7], max_tokens=100)
+        reqs["parts_beside_decode"] = engine.submit(
+            list(range(40, 110)), max_tokens=5
+        )
+        reqs["parts_beside_decode"].result(timeout=120)
+        running.result(timeout=120)
+        n_requests = 10
     finally:
         engine.stop()
         tracing.set_collector(old)
@@ -137,6 +148,7 @@ def test_request_trace_has_abutting_phases(served, kind):
     assert root.attrs["slot"] in (0, 1)
     assert root.attrs["bucket"] == {
         "plain": 16, "chunked": 32, "prefix_hit": 16, "max_tokens_1": 16,
+        "parts_beside_decode": 32,
     }[kind]
     # the wall-clock start is the monotonic one, shifted
     assert abs(
@@ -159,32 +171,76 @@ def _turns(c):
 TILING_TOLERANCE_S = 0.020
 
 
-def _decoded(admitted=0):
-    """The phases of a turn that decoded; for each request it also
-    brought to a slot, its first token's fetch and emit come between
-    the dispatch and the chunk's fetch."""
+def _decoded(admitted=0, published=False, ahead=False):
+    """The phases of a turn that decoded, in the loop's order: admit,
+    the chunk's dispatch, then under the chunk the last chunk's tokens
+    published (where one was settled) and the admission's next part
+    dispatched ahead (where one is under way); for each request that
+    has its first token coming, that token's fetch and emit; the
+    chunk's fetch, and its tokens settled."""
     return [
-        *PHASES[:2], *PHASES[2:] * admitted, *PHASES[2:],
+        *PHASES[:2], *PHASES[3:] * published, *PHASES[:1] * ahead,
+        *PHASES[2:] * admitted, *PHASES[2:],
     ]
 
 
-def test_phases_of_a_turn_tile_it(served):
+def _parts_of(span):
+    """The parts of an admission in parts that an admit span dispatched."""
+    return [
+        ev[2]["part"] for ev in span.events
+        if ev[1] == "prefill" and ev[2]["part"] != "whole"
+    ]
+
+
+@pytest.mark.parametrize("case", ["part_ahead", "no_part", "first_tokens"])
+def test_phases_of_a_turn_tile_it(served, case):
+    """Every turn's phases come in the loop's order, do not overlap and
+    cover the turn; by case, the turns that sent a part out behind
+    their chunk, the decoding turns that did not, and the turns that
+    fetched a first token ahead of their chunk."""
     c, engine, _reqs, _n = served
     turns = _turns(c)
     assert len(turns) == engine.turns > 10
-    decoded = early = 0
+    decoded = early = ahead_turns = seen = 0
+    final_ahead = False  # the turn before sent a FINAL part out ahead
     for turn, kids in turns:
         assert turn.parent_span_id == "" and turn.status == "ok"
         names = [k.name for k in kids]
-        # the requests this turn's admit phase brought to a slot (a part
-        # that is not the final one, or a single token, brings none)
         first = [k for k in kids if k.attrs.get("first_tokens")]
-        assert names in (_decoded(len(first) // 2), ["engine.admit"])
-        assert len(first) // 2 <= len(kids[0].events)
-        assert first == kids[2:2 + len(first)]
+        published = [k for k in kids if k.attrs.get("deferred")]
+        ahead = [k for k in kids if k.attrs.get("ahead")]
+        assert len(published) <= 1 and len(ahead) <= 1
+        shape = _decoded(len(first) // 2, bool(published), bool(ahead))
+        # a turn with nothing to decode: its admit phase, and what the
+        # last chunk left to publish
+        assert names in (shape, ["engine.admit"] + ["engine.emit"] * len(published))
+        # the first tokens of the requests this turn's admit phase
+        # brought to a slot (a part that is not the final one, or a
+        # single token, brings none), and of a final part sent out
+        # behind the last turn's chunk
+        assert len(first) // 2 <= len(kids[0].events) + final_ahead
+        at_first = 2 + len(published) + len(ahead)
+        assert first == kids[at_first:at_first + len(first)]
         assert all(k.attrs == {"first_tokens": 1} for k in first)
-        decoded += len(kids) > 1
+        assert all(k.attrs == {"deferred": 1} for k in published)
+        if ahead:
+            # one part, and the top of the turn after it runs none
+            (part,) = _parts_of(ahead[0])
+            assert ahead[0].attrs == {"ahead": 1, "parts": 3}
+            final_ahead = part == "final"
+        else:
+            final_ahead = False
+        mine = {
+            "part_ahead": bool(ahead),
+            "no_part": len(names) > 2 and not ahead and not first,
+            "first_tokens": bool(first),
+        }[case]
+        decoded += "engine.dispatch" in names
         early += len(first) // 2
+        ahead_turns += bool(ahead)
+        if not mine:
+            continue
+        seen += 1
         at = turn.start_mono
         for k in kids:
             assert k.trace_id == turn.trace_id
@@ -195,10 +251,15 @@ def test_phases_of_a_turn_tile_it(served):
         assert turn.duration - TILING_TOLERANCE_S <= covered <= (
             turn.duration + EPS
         )
+    assert seen >= 3
     assert decoded * engine.chunk == engine.decode_steps
     # plain, chunked, the two that share a prefix, the two that keep the
-    # slots busy: not the one of a single token, not the one cancelled
-    assert early == engine.first_tokens_early == 6
+    # slots busy, the two of the parts beside a decode: not the one of a
+    # single token, not the one cancelled
+    assert early == engine.first_tokens_early == 8
+    # the three parts beside a decoding stream, and not the two parts of
+    # the prompt that was admitted alone
+    assert ahead_turns == engine.parts_ahead == 3 and engine.parts == 5
 
 
 def test_span_count_is_bounded_by_turns_and_requests(served):
@@ -207,9 +268,10 @@ def test_span_count_is_bounded_by_turns_and_requests(served):
     idle = [s for s in spans if s.name == "engine.idle"]
     assert all(s.parent_span_id == "" for s in idle)
     assert len(idle) <= n_requests + 1
-    # a turn and its four phases, a request and its three, and two more
-    # phases in the turn that fetches the request's first token early
-    assert len(spans) <= 5 * engine.turns + 6 * n_requests + len(idle)
+    # a turn and its six phases (the last chunk's tokens published and a
+    # part sent out ahead among them), a request and its three, and two
+    # more phases in the turn that fetches the request's first token early
+    assert len(spans) <= 7 * engine.turns + 6 * n_requests + len(idle)
     assert len(spans) == c.recorded_total  # nothing else wrote here
     # per token there is nothing: far more tokens than turns
     assert engine.tokens_emitted > 3 * engine.turns
