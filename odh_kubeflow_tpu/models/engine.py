@@ -30,6 +30,11 @@ into the running decode loop**:
   itself is never dispatched before the last one's tokens are settled:
   which slots it would decode, and which arrivals it would leave
   waiting, are in those tokens;
+- the loop accounts for what it did not decode: every slot-step of
+  every chunk is counted under one of ``SLOT_STATES`` and every waiting
+  request's seconds are charged to the lane or to the slots, once a
+  chunk and once a turn, and each ``engine.turn`` span carries the
+  running totals (``_turn_totals``);
 - static shapes throughout: compile count = #prompt_buckets + 1,
   independent of request mix (XLA discipline — no shape depends on
   arrival order or request params);
@@ -81,6 +86,14 @@ Params = dict[str, Any]
 # prefix seeding, the draft's prefill) with this substring in its name.
 DECODE_PROGRAM = "_decode_chunk"
 PREFILL_PROGRAM_TAG = "_prefill"
+
+# What a slot did in one step of a decode chunk (``DecodeEngine.slot_steps``
+# counts every one of a chunk's ``chunk x n_slots`` under exactly one):
+# it emitted a token; it held a decoding request that had ended; the
+# admission in parts under way held it; it was free while a prompt that
+# needs the part-by-part lane waited for that lane; it was free and
+# nothing waited.
+SLOT_STATES = ("live", "ended", "admitting", "free_lane", "free_no_work")
 
 # TTFT spans fast warm admissions to cold-compile prefills
 _TTFT_BUCKETS = (
@@ -233,6 +246,8 @@ class _Request:
     submit_wall: float = 0.0  # submit_t on the wall clock
     # when the loop took it from the queue with a slot free (monotonic)
     admit_t: Optional[float] = None
+    # when the loop set it aside for the part-by-part lane, if it did
+    held_t: Optional[float] = None
     finish_t: Optional[float] = None
     slot: int = -1
     bucket: int = -1
@@ -337,9 +352,11 @@ def _record_request(req: _Request) -> None:
     """The request's trace, written once, when it finishes, from the
     stamps it carries (no span is held open across loop turns): root
     ``engine.request`` (submit to finish) over ``engine.request.queued``
-    (submit to the loop taking it with a slot free),
-    ``engine.request.first_token`` (from there to the emit of its first
-    token: the turn's prefills up to its own, and its fetch) and
+    (submit to the loop taking it with a slot free; attr ``held_s``: how
+    much of that it sat in ``_held``, waiting for the part-by-part lane
+    and not for a slot), ``engine.request.first_token`` (from there to
+    the emit of its first token: the turn's prefills up to its own, and
+    its fetch) and
     ``engine.request.decode`` (first token to finish). A request that
     ends early has the phases it reached, the last one cut at the end.
     Children first: the root's outcome decides whether the collector
@@ -370,14 +387,20 @@ def _record_request(req: _Request) -> None:
             **kw,
         ))
 
+    taken = end if req.admit_t is None else req.admit_t
+    held_s = 0.0 if req.held_t is None else taken - req.held_t
     marks = (
-        ("engine.request.queued", req.submit_t, req.admit_t),
-        ("engine.request.first_token", req.admit_t, first),
-        ("engine.request.decode", first, end),
+        ("engine.request.queued", req.submit_t, req.admit_t,
+         {"held_s": held_s}),
+        ("engine.request.first_token", req.admit_t, first, {}),
+        ("engine.request.decode", first, end, {}),
     )
-    for name, t_from, t_to in marks:
+    for name, t_from, t_to, attrs in marks:
         if t_from is not None:
-            record(name, t_from, end if t_to is None else t_to, root_id)
+            record(
+                name, t_from, end if t_to is None else t_to, root_id,
+                attrs=attrs,
+            )
     attrs = {
         "prompt_len": len(req.prompt),
         "max_tokens": req.max_tokens,
@@ -466,8 +489,6 @@ class DecodeEngine:
         self.prefix_cache_entries = prefix_cache_entries
         self.prefix_buckets = tuple(sorted(prefix_buckets))
         self._prefix_cache: "dict[tuple, dict]" = {}
-        self.prefix_hits = 0
-        self.prefix_misses = 0
 
         # speculative decoding per slot: the draft model proposes
         # spec_k tokens, the target verifies them in ONE k+1-token
@@ -622,6 +643,15 @@ class DecodeEngine:
             "serving_batch_occupancy",
             "Fraction of decode slots active after the last chunk",
         )
+        self.m_slot_steps = reg.counter(
+            "serving_slot_steps_total",
+            "Slot-steps of the decode chunks by what the slot did in them",
+            labelnames=("state",),
+        )
+        self.m_lane_held = reg.gauge(
+            "serving_lane_held",
+            "Requests set aside for the part-by-part admission lane",
+        )
         # observability: decode_steps × n_slots is the work a serial
         # server would have spent per-request; the ratio
         # tokens_emitted / decode_steps is the batching efficiency
@@ -631,7 +661,6 @@ class DecodeEngine:
         # program; the others were all-greedy or a draft's rounds)
         self.decode_calls_sampled = 0
         self.tokens_emitted = 0
-        self.spec_rounds = 0
         # loop turns that had work, and prefill programs dispatched
         # (whole prompts, parts of a chunked admission, prefix seeding)
         self.turns = 0
@@ -649,6 +678,17 @@ class DecodeEngine:
         # part with no host in between
         self.parts = 0
         self.parts_ahead = 0
+        # every slot-step of every decode chunk under the one state it
+        # was in, closed chunk by chunk at the settle (not beside a
+        # draft, whose rounds are no steps)
+        self.slot_steps = dict.fromkeys(SLOT_STATES, 0)
+        # what the waiting requests waited for, in request-seconds: the
+        # lane (in ``_held`` with a slot free) or a slot (none free),
+        # charged at the end of each top admit phase for the time since
+        # the last one
+        self.wait_lane_s = 0.0
+        self.wait_slot_s = 0.0
+        self._wait_stamp = time.monotonic()
         # the admission's part for the coming turn has already gone out
         self._part_ahead = False
         # the last chunk's tokens, settled and not yet published (nor
@@ -1201,13 +1241,11 @@ class DecodeEngine:
             row = self.pack_admission(rem, self.pad_id, bucket, req)
             row[0, bucket + 1] = slot
             packed = jnp.asarray(row)
-            self.prefix_hits += 1
             self._note_prefill(req, slot, bucket, True, "whole", len(rem))
             self._state, first = self._prefill_ext_runner(plen, bucket)(
                 self.params, self.lora, self._state, entry, packed,
             )
         else:
-            self.prefix_misses += 1
             bucket = next(b for b in self.prompt_buckets if L <= b)
             row = self.pack_admission(req.prompt, self.pad_id, bucket, req)
             row[0, bucket + 1] = slot
@@ -1260,12 +1298,9 @@ class DecodeEngine:
         start = 0
         plen, entry = self._match_prefix(req.prompt)
         if plen is not None:
-            self.prefix_hits += 1
             self._note_prefill(req, slot, plen, True, "seed", 0)
             sub_cache = self._prefill_seed_runner(plen)(sub_cache, entry)
             start = plen
-        else:
-            self.prefix_misses += 1
         self._slot_req[slot] = req  # reserve; device-inactive until final
         self._admitting = dict(
             req=req, slot=slot, sub=sub_cache, consumed=start,
@@ -1432,7 +1467,9 @@ class DecodeEngine:
                 continue
             self.turns += 1
             with hot_span("engine.turn", turn=self.turns):
-                if not self._turn():
+                go_on = self._turn()
+                self._close_turn()
+                if not go_on:
                     return
 
     def _turn(self) -> bool:
@@ -1495,6 +1532,7 @@ class DecodeEngine:
                     and len(req.prompt) > self.prefill_chunk
                 )
                 if in_parts and self._admitting is not None:
+                    req.held_t = time.monotonic()
                     self._held.append(req)  # the lane is taken
                     continue
                 req.admit_t = time.monotonic()
@@ -1508,13 +1546,12 @@ class DecodeEngine:
                     self._fail_admission(req, e)
                     return False
             self.m_queue_depth.set(self._queue.qsize() + len(self._held))
-        adm_slot = (
-            self._admitting["slot"] if self._admitting is not None else -1
-        )
-        if not any(
-            r is not None and s != adm_slot
-            for s, r in enumerate(self._slot_req)
-        ):
+            self._charge_waits()
+        # the slots this turn's chunk decodes, beside the one an
+        # admission in parts holds
+        admitting = int(self._admitting is not None)
+        decoding = sum(r is not None for r in self._slot_req) - admitting
+        if not decoding:
             # nothing decoding: a chunked admission runs its parts
             # back-to-back, one turn each, at the top
             self._publish_in_turn()
@@ -1541,8 +1578,6 @@ class DecodeEngine:
             with hot_span("engine.dispatch", program=program):
                 self._state, (toks, mask) = chunk_fn(weights, self._state)
                 moe_stats = self._take_chunk_stats()
-            if self._spec_fn is not None:
-                self.spec_rounds += self.spec_rounds_per_call
         except Exception as e:  # noqa: BLE001 — state integrity unknown
             self._fail_engine(e)
             return False
@@ -1592,9 +1627,70 @@ class DecodeEngine:
             if self._window_layers and self._spec_fn is None:
                 self._count_window_blocks_skipped(mask)
             self._settle_chunk(toks, mask)
+            if self._spec_fn is None:
+                self._count_slot_steps(mask, decoding, admitting)
             if program == "sample":
                 self.decode_calls_sampled += 1
         return True
+
+    def _charge_waits(self) -> None:
+        """The end of a turn's top admit phase: the time since the last
+        one is charged once for every request that still waits, to the
+        lane where a slot is free (only ``_held`` can then hold any: the
+        phase drains the queue while one is) and to the slots where none
+        is. Two products, no walk over the queue; an arrival from here
+        on waits a turn and shows in its own ``queued`` span."""
+        now = time.monotonic()
+        since, self._wait_stamp = now - self._wait_stamp, now
+        held = len(self._held)
+        if None in self._slot_req:
+            self.wait_lane_s += held * since
+        else:
+            self.wait_slot_s += (held + self._queue.qsize()) * since
+
+    def _count_slot_steps(self, mask, decoding: int, admitting: int) -> None:
+        """Every slot-step of the chunk just fetched, under one of
+        ``SLOT_STATES``: ``decoding`` slots held a decoding request at
+        its dispatch and ``admitting`` (0 or 1) the admission in parts;
+        the fetched mask says in which steps a slot emitted. A free
+        slot stood empty for the lane if anything sat in ``_held`` when
+        the turn's top admit phase ended (only that phase moves
+        ``_held``, so it reads the same here), else for want of work.
+        The registry's counter moves with it: once a chunk, so once a
+        turn."""
+        k = mask.shape[1]
+        live = int(mask.sum())
+        free = k * (self.n_slots - decoding - admitting)
+        lane = bool(self._held)
+        chunk = (
+            live, k * decoding - live, k * admitting,
+            free if lane else 0, 0 if lane else free,
+        )
+        for state, n in zip(SLOT_STATES, chunk):
+            self.slot_steps[state] += n
+            self.m_slot_steps.inc({"state": state}, n)
+
+    def _turn_totals(self) -> dict:
+        """The loop's running totals, as an ``engine.turn`` span carries
+        them: CUMULATIVE, so that two turns give any interval's counts
+        by their difference, whatever a caller did or did not snapshot;
+        ``held`` alone is a depth."""
+        return {
+            **{f"slot_steps_{s}": n for s, n in self.slot_steps.items()},
+            "wait_lane_s": self.wait_lane_s, "wait_slot_s": self.wait_slot_s,
+            "held": len(self._held),
+            "parts": self.parts, "parts_ahead": self.parts_ahead,
+            "prefill_tokens": self.prefill_tokens,
+            "prefill_positions": self.prefill_positions,
+        }
+
+    def _close_turn(self) -> None:
+        """Once a turn, as its span closes: the registry's view of the
+        lane moves and the span takes the totals as attributes. Not
+        beside a draft (no ledger is kept)."""
+        if self._spec_fn is None:
+            self.m_lane_held.set(len(self._held))
+            tracing.set_attrs(**self._turn_totals())
 
     def _parts_attr(self) -> dict:
         """For an ``engine.admit`` span while an admission in parts is

@@ -200,6 +200,14 @@ class SpanRecord:
 ROOT_THRESHOLDS: dict[str, float] = {}
 
 
+# The ring's room. A decode engine at its busiest writes ~75 spans a
+# second (a turn every 0.2 s with its eight to twelve phases, four spans
+# a finished request), and a reader of a window (a benchmark's 51 s, an
+# operator's last minutes) refuses a ring that may have wrapped: room
+# for four such windows, at ~0.6 KB a span ~10 MB (PERF.md, PR 38).
+RING_CAPACITY = 16384
+
+
 class SpanCollector:
     """Bounded in-process span store with tail-based keep rules.
 
@@ -217,7 +225,7 @@ class SpanCollector:
 
     def __init__(
         self,
-        capacity: int = 4096,
+        capacity: int = RING_CAPACITY,
         max_kept: int = 128,
         default_threshold_s: float = 1.0,
         max_spans_per_trace: int = 512,
@@ -381,6 +389,16 @@ def set_status(status: str, message: str = "") -> None:
             ctx._mut["error"] = message
 
 
+def set_attrs(**attrs: Any) -> None:
+    """Attributes for the current span's RECORD, set while it is open
+    (what is only known as it closes: a turn's totals). They merge over
+    those it was entered with; children entered since do not carry
+    them. No-op outside any span."""
+    ctx = _current.get()
+    if ctx is not None:
+        ctx._mut.setdefault("attrs", {}).update(attrs)
+
+
 def discard() -> None:
     """Mark the current span as not worth recording (e.g. a retried
     gang-bind attempt that didn't land — only the landed one is the
@@ -446,7 +464,7 @@ def span(
                     duration=time.monotonic() - t0,
                     status=status,
                     error=error,
-                    attrs=dict(attrs),
+                    attrs={**attrs, **ctx._mut.get("attrs", {})},
                     events=[
                         (ts, ename, dict(eattrs))
                         for ts, ename, eattrs in ctx.events

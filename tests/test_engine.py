@@ -213,12 +213,11 @@ def test_prefix_cache_exact_and_hits(model):
         p2 = system + [5, 1]
         want1 = _reference_greedy(params, cfg, p1, 10)
         want2 = _reference_greedy(params, cfg, p2, 10)
-        got1 = engine.submit(p1, max_tokens=10).result(timeout=120)
-        assert engine.prefix_misses == 1 and engine.prefix_hits == 0
-        got2 = engine.submit(p2, max_tokens=10).result(timeout=120)
-        assert engine.prefix_hits == 1, (
-            engine.prefix_hits, engine.prefix_misses
-        )
+        first = engine.submit(p1, max_tokens=10)
+        got1 = first.result(timeout=120)
+        second = engine.submit(p2, max_tokens=10)
+        got2 = second.result(timeout=120)
+        assert not first.prefix_hit and second.prefix_hit
         assert got1 == want1, (got1, want1)
         assert got2 == want2, (got2, want2)
     finally:
@@ -283,10 +282,11 @@ def test_spec_decode_engine_greedy_exact(model):
         for i, h in enumerate(handles):
             got = h.result(timeout=120)
             assert got == want[i], (i, got, want[i])
-        assert spec.spec_rounds > 0
+        # beside a draft a decode step is a round
+        assert spec.decode_steps > 0
         # a perfect draft should average well over 1 token per round
-        assert spec.tokens_emitted / spec.spec_rounds > 1.5, (
-            spec.tokens_emitted, spec.spec_rounds
+        assert spec.tokens_emitted / spec.decode_steps > 1.5, (
+            spec.tokens_emitted, spec.decode_steps
         )
         with pytest.raises(ValueError):
             spec.submit([1, 2, 3], max_tokens=4, temperature=0.8)
@@ -314,13 +314,14 @@ def test_spec_engine_composes_with_prefix_cache(model):
         system = [3 + (i % 11) for i in range(16)]
         p1 = system + [7, 9, 2]
         p2 = system + [5, 1]
+        hits = []
         for p in (p1, p2):
             want = plain.submit(p, max_tokens=10).result(timeout=120)
-            got = spec.submit(p, max_tokens=10).result(timeout=120)
+            req = spec.submit(p, max_tokens=10)
+            got = req.result(timeout=120)
             assert got == want, (got, want)
-        assert spec.prefix_hits == 1, (
-            spec.prefix_hits, spec.prefix_misses
-        )
+            hits.append(req.prefix_hit)
+        assert hits == [False, True]
     finally:
         plain.stop()
         spec.stop()
@@ -681,7 +682,7 @@ def test_streamed_tokens_are_generates_for_every_admission(model, kind):
             for p in prompts:
                 reqs.append(engine.submit(p, max_tokens=n, stream=True))
                 got.append(list(reqs[-1].iter_tokens(timeout=300)))
-            assert engine.prefix_hits == 1
+            assert [r.prefix_hit for r in reqs] == [False, True]
         else:
             reqs = [
                 engine.submit(p, max_tokens=n, eos_id=e, stream=True)
@@ -1057,3 +1058,207 @@ def test_a_slot_freed_by_a_chunk_is_taken_in_the_very_next_turn(model):
         s for s in ring.spans_named("engine.dispatch")
         if settled_at <= s.start_mono <= waiting.admit_t
     ]
+
+
+# ---- the ledger of slot-steps and of what waiting requests wait for ---------
+
+
+def _turns_with_chunks(ring):
+    """Each ``engine.turn`` span's attributes with the number of decode
+    chunks it dispatched, in the loop's order."""
+    turns = sorted(ring.spans_named("engine.turn"), key=lambda s: s.attrs["turn"])
+    chunks = {t.span_id: 0 for t in turns}
+    for s in ring.spans_named("engine.dispatch"):
+        chunks[s.parent_span_id] += 1
+    return [(t.attrs, chunks[t.span_id]) for t in turns]
+
+
+def _steps(attrs):
+    from odh_kubeflow_tpu.models.engine import SLOT_STATES
+
+    return {s: attrs[f"slot_steps_{s}"] for s in SLOT_STATES}
+
+
+def _serve_whole_prompts(engine, model):
+    """Five short prompts on three slots: two wait for a slot."""
+    reqs = [engine.submit([3 + i, 7, 11], max_tokens=9 + i) for i in range(5)]
+    for r in reqs:
+        r.result(timeout=300)
+    return {"slots": 3}
+
+
+def _serve_parts_with_one_held(engine, model):
+    """A stream decodes, a long prompt is admitted in parts beside it,
+    a second long prompt is held behind the first for the lane; once
+    both are served, whole prompts alone."""
+    rng = np.random.default_rng(5)
+    long_a, long_b = (rng.integers(1, 200, size=n).tolist() for n in (100, 90))
+    running = engine.submit([3, 5, 8], max_tokens=150)
+    while not running.tokens:
+        time.sleep(0.002)
+    a = engine.submit(long_a, max_tokens=4)
+    b = engine.submit(long_b, max_tokens=4)
+    a.result(timeout=300), b.result(timeout=300)
+    assert b.held_t is not None and a.held_t is None
+    after = engine.turns
+    for r in [engine.submit([9, 9, 2 + i], max_tokens=6) for i in range(2)]:
+        r.result(timeout=300)
+    running.result(timeout=300)
+    return {"held": b, "lane_free_from": after + 1}
+
+
+def _serve_one_that_ends_on_its_eos(engine, model):
+    """Alone on the engine, a request whose eos is the token the first
+    chunk's second step emits (its third token: the prefill emits the
+    first)."""
+    cfg, params = model
+    prompt = next(
+        p for p in ([5, 9, 13 + i] for i in range(50))
+        if (ref := _reference_greedy(params, cfg, p, 3))[2] not in ref[:2]
+    )
+    ref = _reference_greedy(params, cfg, prompt, 3)
+    got = engine.submit(prompt, max_tokens=40, eos_id=ref[2]).result(timeout=300)
+    assert got == ref
+    return {}
+
+
+def _serve_a_cancel_mid_admission(engine, model):
+    rng = np.random.default_rng(7)
+    long_a = rng.integers(1, 200, size=100).tolist()
+    running = engine.submit([3, 5, 8], max_tokens=60)
+    while not running.tokens:
+        time.sleep(0.002)
+    a = engine.submit(long_a, max_tokens=4)  # thirteen parts
+    while engine.parts_ahead < 2:
+        time.sleep(0.002)
+    a.cancel()
+    assert a.done.wait(timeout=300) and not a.tokens
+    running.result(timeout=300)
+    return {}
+
+
+def _serve_one_alone(engine, model):
+    engine.submit([5, 9, 13], max_tokens=13).result(timeout=300)
+    return {}
+
+
+LEDGER_CASES = {
+    "whole_prompts": _serve_whole_prompts,
+    "parts_with_one_held": _serve_parts_with_one_held,
+    "eos_in_second_step": _serve_one_that_ends_on_its_eos,
+    "cancel_mid_admission": _serve_a_cancel_mid_admission,
+    "nothing_queued": _serve_one_alone,
+}
+
+
+@pytest.mark.parametrize("case", sorted(LEDGER_CASES))
+def test_every_slot_step_of_every_chunk_is_counted_once(model, case):
+    """Each decode chunk's ``chunk x n_slots`` slot-steps fall under
+    exactly one state, turn by turn on the ``engine.turn`` spans'
+    running totals, which never fall; and by case, the states that the
+    traffic must and must not have produced."""
+    cfg, params = model
+    k, n_slots = 4, 3
+    engine = DecodeEngine(
+        params, cfg, n_slots=n_slots, max_len=256, chunk=k,
+        prompt_buckets=(8,), prefill_chunk=8, cache_dtype=jnp.float32,
+    )
+    ring = tracing.SpanCollector()
+    try:
+        # every program compiled before anything is counted on
+        engine.submit(list(range(1, 21)), max_tokens=2).result(timeout=300)
+        engine.submit([5, 9, 13], max_tokens=6).result(timeout=300)
+        # the idle engine's totals: what the first turn's are held against
+        base = engine._turn_totals()
+        old = tracing.set_collector(ring)
+        try:
+            said = LEDGER_CASES[case](engine, model)
+        finally:
+            engine.stop()
+            tracing.set_collector(old)
+    finally:
+        engine.stop()
+    turns = [(base, 0)] + _turns_with_chunks(ring)
+    assert sum(n for _, n in turns) >= 1
+    deltas = []
+    for (before, _), (attrs, chunks) in zip(turns, turns[1:]):
+        assert chunks in (0, 1)
+        was, now = _steps(before), _steps(attrs)
+        d = {s: now[s] - was[s] for s in now}
+        assert all(v >= 0 for v in d.values()), d
+        assert sum(d.values()) == chunks * k * n_slots, (attrs["turn"], d)
+        for key in ("wait_lane_s", "wait_slot_s", "parts", "parts_ahead",
+                    "prefill_tokens", "prefill_positions"):
+            assert attrs[key] >= before[key], key
+        deltas.append((attrs, d, {
+            key: attrs[key] - before[key]
+            for key in ("wait_lane_s", "wait_slot_s")
+        }))
+    last = turns[-1][0]
+    assert _steps(last) == engine.slot_steps
+    assert sum(engine.slot_steps.values()) == engine.decode_calls * k * n_slots
+    assert last["parts"] == engine.parts
+    assert last["parts_ahead"] == engine.parts_ahead
+    assert last["prefill_tokens"] == engine.prefill_tokens
+    assert last["prefill_positions"] == engine.prefill_positions
+    assert last["wait_lane_s"] == engine.wait_lane_s
+    assert last["wait_slot_s"] == engine.wait_slot_s
+    total = {
+        s: engine.slot_steps[s] - _steps(turns[0][0])[s]
+        for s in engine.slot_steps
+    }
+    if case == "whole_prompts":
+        assert total["admitting"] == total["free_lane"] == 0
+        assert last["wait_lane_s"] == turns[0][0]["wait_lane_s"]
+        # two of five waited for a slot, and nothing else
+        assert last["wait_slot_s"] > turns[0][0]["wait_slot_s"]
+    elif case == "parts_with_one_held":
+        assert total["free_lane"] > 0 and total["admitting"] > 0
+        for attrs, d, waits in deltas:
+            if attrs["held"]:
+                # a slot stood free for the lane, and for nothing else;
+                # the held request's seconds went to the lane
+                assert d["free_no_work"] == 0 and attrs["held"] == 1
+                assert waits["wait_lane_s"] > 0 and waits["wait_slot_s"] == 0
+            else:
+                assert d["free_lane"] == 0 and waits["wait_lane_s"] == 0
+            if attrs["turn"] >= said["lane_free_from"]:
+                assert d["free_lane"] == d["admitting"] == 0
+        assert any(attrs["held"] and d["free_lane"] for attrs, d, _ in deltas)
+    elif case == "eos_in_second_step":
+        assert total == {
+            "live": 2, "ended": k - 2, "admitting": 0, "free_lane": 0,
+            "free_no_work": k * (n_slots - 1),
+        }
+    elif case == "cancel_mid_admission":
+        assert total["admitting"] > 0 and total["free_lane"] == 0
+        # the slot it held is free again once it has been dropped
+        last_chunk = [d for _, d, _ in deltas if sum(d.values())][-1]
+        assert last_chunk["admitting"] == 0
+        assert last_chunk["free_no_work"] == k * (n_slots - 1)
+    elif case == "nothing_queued":
+        # 1 + 12 tokens: three chunks, two slots empty for want of work
+        assert total == {
+            "live": 12, "ended": 0, "admitting": 0, "free_lane": 0,
+            "free_no_work": 3 * k * (n_slots - 1),
+        }
+        assert last["wait_slot_s"] == turns[0][0]["wait_slot_s"]
+
+
+def test_no_ledger_is_kept_beside_a_draft(model):
+    cfg, params = model
+    engine = DecodeEngine(
+        params, cfg, n_slots=2, max_len=256, chunk=4, prompt_buckets=(16,),
+        cache_dtype=jnp.float32, draft_params=params, draft_cfg=cfg, spec_k=3,
+    )
+    ring = tracing.SpanCollector()
+    old = tracing.set_collector(ring)
+    try:
+        got = engine.submit([5, 9, 13], max_tokens=11).result(timeout=300)
+    finally:
+        engine.stop()
+        tracing.set_collector(old)
+    assert got == _reference_greedy(params, cfg, [5, 9, 13], 11)
+    assert engine.decode_steps > 0 and not any(engine.slot_steps.values())
+    turns = ring.spans_named("engine.turn")
+    assert turns and all(set(t.attrs) == {"turn"} for t in turns)

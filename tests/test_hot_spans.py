@@ -263,7 +263,7 @@ def test_phases_of_a_turn_tile_it(served, case):
 
 
 def test_span_count_is_bounded_by_turns_and_requests(served):
-    c, engine, _reqs, n_requests = served
+    c, engine, reqs, n_requests = served
     spans = c.spans_named("engine.")
     idle = [s for s in spans if s.name == "engine.idle"]
     assert all(s.parent_span_id == "" for s in idle)
@@ -279,7 +279,8 @@ def test_span_count_is_bounded_by_turns_and_requests(served):
         ev[1] == "prefill"
         for s in spans if s.name == "engine.admit" for ev in s.events
     )
-    assert engine.prefix_hits == 1 and engine.prefill_calls >= n_requests - 1
+    assert [k for k, r in reqs.items() if r.prefix_hit] == ["prefix_hit"]
+    assert engine.prefill_calls >= n_requests - 1
     # healthy traffic is not kept: no turn or request was slow, no error
     assert c.kept_traces() == []
 
@@ -629,3 +630,107 @@ def test_trainer_counts_the_blocks_flash_walked(collector):
     assert (trainer.flash_blocks_live, trainer.flash_blocks_walked) == (10, 12)
     steps = collector.spans_named("trainer.step")
     assert [s.attrs.get("flash_live_share") for s in steps] == [None, None, 0.8333]
+
+
+# ---- what the loop accounts for: the lane's wait and the turn's totals ------
+
+
+@pytest.fixture(scope="module")
+def lane(model):
+    """A stream decodes; three long prompts come together: the first
+    takes the part-by-part lane, the second is held for it, the third
+    is held too and cancelled there."""
+    c = tracing.SpanCollector()
+    engine = _engine(model, n_slots=3, prompt_buckets=(8,), prefill_chunk=8)
+    reqs = {}
+    try:
+        engine.submit(list(range(1, 21)), max_tokens=2).result(timeout=300)
+        engine.submit([5, 9, 13], max_tokens=6).result(timeout=300)
+        old = tracing.set_collector(c)
+        try:
+            running = engine.submit([3, 5, 8], max_tokens=110)
+            while not running.tokens:
+                time.sleep(0.002)
+            for name, n in (("lane", 70), ("held", 60), ("held_cancelled", 50)):
+                reqs[name] = engine.submit(list(range(2, 2 + n)), max_tokens=3)
+            while len(engine._held) < 2:
+                time.sleep(0.002)
+            reqs["held_cancelled"].cancel()
+            for r in reqs.values():
+                assert r.done.wait(timeout=300)
+            running.result(timeout=300)
+        finally:
+            engine.stop()
+            tracing.set_collector(old)
+    finally:
+        engine.stop()
+    return c, reqs
+
+
+@pytest.mark.parametrize("kind", ["lane", "held", "held_cancelled"])
+def test_queued_span_says_how_long_the_request_was_held_for_the_lane(lane, kind):
+    c, reqs = lane
+    req = reqs[kind]
+    queued = _by_name(c.trace(req.request_id))["engine.request.queued"]
+    # the span itself is where it was: submit to the loop taking it
+    taken = req.finish_t if kind == "held_cancelled" else req.admit_t
+    assert queued.start_mono == req.submit_t
+    assert abs(queued.duration - (taken - req.submit_t)) < EPS
+    if kind == "lane":
+        assert req.held_t is None and queued.attrs == {"held_s": 0.0}
+    else:
+        assert req.submit_t < req.held_t < taken
+        assert queued.attrs == {"held_s": taken - req.held_t}
+        assert 0 < queued.attrs["held_s"] < queued.duration
+    assert (req.admit_t is None) == (kind == "held_cancelled")
+
+
+class _Spy:
+    """A registry metric that also lists the calls made on it."""
+
+    def __init__(self, metric):
+        self.metric, self.calls = metric, []
+
+    def inc(self, labels=None, by=1.0):
+        self.calls.append((labels, by))
+        self.metric.inc(labels, by)
+
+    def set(self, value, labels=None):
+        self.calls.append(value)
+        self.metric.set(value, labels)
+
+
+def test_the_slots_and_the_lane_reach_the_registry_once_a_turn(model, collector):
+    from odh_kubeflow_tpu.utils import prometheus
+
+    reg = prometheus.Registry()
+    engine = _engine(
+        model, n_slots=3, prompt_buckets=(8,), prefill_chunk=8,
+        metrics_registry=reg,
+    )
+    steps = engine.m_slot_steps = _Spy(engine.m_slot_steps)
+    held = engine.m_lane_held = _Spy(engine.m_lane_held)
+    try:
+        running = engine.submit([3, 5, 8], max_tokens=60)
+        longs = [
+            engine.submit(list(range(2, 2 + n)), max_tokens=3) for n in (40, 30)
+        ]
+        for r in longs + [running]:
+            r.result(timeout=300)
+    finally:
+        engine.stop()
+    turns = collector.spans_named("engine.turn")
+    chunks = collector.spans_named("engine.dispatch")
+    assert engine.turns == len(turns) == len(held.calls)
+    assert max(held.calls) == 1 and held.calls[-1] == 0
+    # five states a chunk, moved in the turn that ran it: never a token's
+    assert len(steps.calls) == len(engine_lib.SLOT_STATES) * len(chunks)
+    assert len(chunks) == engine.decode_calls < engine.tokens_emitted
+    for state, n in engine.slot_steps.items():
+        assert steps.metric.value({"state": state}) == n
+    assert steps.metric.sum_matching() == engine.decode_calls * 4 * 3
+    text = reg.exposition()
+    assert 'serving_slot_steps_total{state="free_lane"}' in text
+    assert "serving_lane_held 0" in text
+    # the queue's depth stays the sum of the two, as documented
+    assert engine.m_queue_depth.value() == 0
