@@ -24,7 +24,9 @@ bf16; random weights from a seed), in ONE process on ONE device:
 4. *parts_ahead* — one period of Qwen3-Next at published widths behind
    a small engine: a prompt admitted in three parts beside a decoding
    stream, each part dispatched behind a decode chunk before its fetch,
-   token for token what the same prompt gives admitted whole.
+   the last one narrow (40 tokens in the bucket of 64, through that
+   bucket's one program), token for token what the same prompt gives
+   admitted whole.
 
 It checks that no fallback that hides the device fired: attention
 resolved to ``flash``, no pallas op defaulted to interpret mode, the
@@ -780,16 +782,20 @@ def check_parts_go_ahead() -> dict:
     """The engine's turn on the real device (PR 36): one period of
     Qwen3-Next at published widths (three Gated DeltaNet layers to one
     gated-attention layer; 32 int8 expert banks, so the chunk's expert
-    counters live in the cache), a prompt admitted in three parts of 256
+    counters live in the cache), a prompt admitted in three parts, two
+    of 256 and a final one of 40 tokens that runs NARROW, in the bucket
+    of 64 (PR 39: through that bucket's one program, at offset 512),
     while another stream decodes. Each part is dispatched BEHIND a
     decode chunk, before that chunk's tokens are fetched, and the final
     one donates the state those tokens' counters came in. Holds:
-    ``parts_ahead`` > 0, the stream beside it and the admitted request
-    both end, and the admitted request's greedy tokens are those of the
-    same prompt admitted whole (a bucket of 1024 on an engine of its
-    own): the kernels' per-part path (``gdn_chunk_scan`` handed a state
-    from part to part, ``decode_attend`` over a part, ``moe_local_ffn``
-    at a part's tile) against their whole-prompt path."""
+    ``parts_ahead`` > 0, the final part ran 64 positions, the stream
+    beside it and the admitted request both end, and the admitted
+    request's greedy tokens are those of the same prompt admitted whole
+    (a bucket of 1024 on an engine of its own): the kernels' per-part
+    path (``gdn_chunk_scan`` handed a state from part to part and then
+    one chunk of 64 from that state, ``decode_attend`` at head 256 over
+    a part of 64 queries at an offset, ``moe_local_ffn`` at a part's
+    tile) against their whole-prompt path."""
     import numpy as np
 
     from odh_kubeflow_tpu.models import qwen3_next as qn
@@ -818,10 +824,12 @@ def check_parts_go_ahead() -> dict:
         beside = engine.submit(short, max_tokens=64, stream=True)
         stream = beside.iter_tokens(timeout=900)
         first = [next(stream)]  # it decodes
-        in_parts = engine.submit(long, max_tokens=n).result(timeout=900)
+        admitted = engine.submit(long, max_tokens=n)
+        in_parts = admitted.result(timeout=900)
         beside_tokens = first + list(stream)
         wall = time.monotonic() - t
         parts, ahead = engine.parts - parts, engine.parts_ahead - ahead
+        programs = set(engine._prefill_fns)
         experts_hit, failure = engine.moe_experts_hit, engine.failure
     finally:
         engine.stop()
@@ -829,6 +837,11 @@ def check_parts_go_ahead() -> dict:
         raise AssertionError(f"engine failed: {failure!r}")
     if parts != 3 or not 0 < ahead <= parts:
         raise AssertionError(f"{ahead} of {parts} parts went out ahead")
+    if admitted.bucket != 64 or programs != {64, ("part", 256)}:
+        raise AssertionError(
+            f"the final part ran {admitted.bucket} positions; the engine "
+            f"holds the prefill programs {programs}"
+        )
     if len(beside_tokens) != 64 or experts_hit <= 0:
         raise AssertionError(
             f"{len(beside_tokens)} tokens beside the admission, "
@@ -846,7 +859,8 @@ def check_parts_go_ahead() -> dict:
             f"{beside_tokens} / alone {alone}"
         )
     return {
-        "parts": parts, "parts_ahead": ahead, "tokens": in_parts,
+        "parts": parts, "parts_ahead": ahead,
+        "final_part_ran": admitted.bucket, "tokens": in_parts,
         "tokens_beside": len(beside_tokens), "wall_s": round(wall, 3),
     }
 

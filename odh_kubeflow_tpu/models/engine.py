@@ -35,9 +35,15 @@ into the running decode loop**:
   request's seconds are charged to the lane or to the slots, once a
   chunk and once a turn, and each ``engine.turn`` span carries the
   running totals (``_turn_totals``);
-- static shapes throughout: compile count = #prompt_buckets + 1,
-  independent of request mix (XLA discipline — no shape depends on
-  arrival order or request params);
+- static shapes throughout: one prefill program a prompt bucket and
+  two decode chunks (sampled, greedy), plus, with ``prefill_chunk``,
+  the interior part's program and one at ``prefill_chunk`` itself
+  where that is no bucket: a whole prompt and the last part of a
+  prompt admitted in parts go through the SAME program of the
+  smallest bucket that holds them (a fresh batch-1 cache at offset 0,
+  or the cache the parts filled at theirs). Independent of request mix
+  (XLA discipline — no shape depends on arrival order or request
+  params);
 - per-request ``max_tokens``/``eos`` honored exactly — a slot that
   finishes mid-chunk goes inactive (its writes stop mutating valid
   state) and frees at the next chunk boundary.
@@ -81,9 +87,10 @@ Params = dict[str, Any]
 
 # Names of the jitted programs as a profile's ``XLA Modules`` line (and
 # the compile log) shows them, ``jit_<name>``: the decode chunk under
-# exactly this name, and every program of the prefill family (whole
-# prompt, with a cached prefix, an interior part, the final part, the
-# prefix seeding, the draft's prefill) with this substring in its name.
+# exactly this name, and every program of the prefill family (a bucket's
+# own: a whole prompt or a final part; with a cached prefix, an interior
+# part, the prefix seeding, the draft's prefill) with this substring in
+# its name, which ends in ``_<positions it runs>``.
 DECODE_PROGRAM = "_decode_chunk"
 PREFILL_PROGRAM_TAG = "_prefill"
 
@@ -523,9 +530,10 @@ class DecodeEngine:
         self._cache_dtype = cache_dtype
         # the most positions one call writes into a slot before it
         # attends: what a window layer's ring holds beyond its window
-        # (``init_cache``). Parts are written at multiples of their
-        # width from 0, so they never straddle a ring's end as long as
-        # their width divides it
+        # (``init_cache``). Interior parts are written at multiples of
+        # ``prefill_chunk`` from 0 and a final part, no wider, at one
+        # more, so none straddles a ring's end as long as
+        # ``prefill_chunk`` divides it; a whole prompt starts at 0
         self._widest_part = max(
             self.prompt_buckets + ((prefill_chunk,) if prefill_chunk else ())
         )
@@ -553,6 +561,15 @@ class DecodeEngine:
         if draft_params is not None:
             dcache_cfg, _ = family_forward(draft_cfg)
             self._state["dcache"] = self._new_cache(dcache_cfg, S)
+        # what a whole prompt's prefill starts from: a batch-1 cache
+        # that nothing has written and offset 0, built once. A bucket's
+        # program takes both as arguments and donates neither (the state
+        # alone), so every whole prompt is handed the same zeros with no
+        # allocation of its own, and the last part of an admission in
+        # parts, which brings the cache its parts filled, runs through
+        # the very same program
+        self._fresh_sub = self._new_cache(cache_cfg, 1)
+        self._start0 = jnp.int32(0)
         # a second home for a chunk's expert counters, made and placed
         # as the cache's own: ``_take_chunk_stats`` swaps the two
         self._stats_spare = (
@@ -582,6 +599,9 @@ class DecodeEngine:
                 self._stats_spare = jax.device_put(
                     self._stats_spare, cspec["moe_stats"]
                 )
+            # one row cannot shard over the slots' axes: every device
+            # holds it, placed once and not at each admission
+            self._fresh_sub = jax.device_put(self._fresh_sub, rep)
         # the cache by kind of layer, and what the kinds ask of the
         # engine's own indexing
         self.cache_bytes = cache_bytes(self._state["cache"])
@@ -591,7 +611,11 @@ class DecodeEngine:
         }
         stateful = self.cache_bytes[STATE] > 0
         if (rings or stateful) and (
-            prefix_cache_entries or any(r % self._widest_part for r in rings)
+            prefix_cache_entries
+            or any(
+                r % w for r in rings
+                for w in (self._widest_part, prefill_chunk or 1)
+            )
         ):
             raise NotImplementedError(
                 "a windowed cache keeps rings and a recurrent layer one "
@@ -800,10 +824,13 @@ class DecodeEngine:
         """Run the FINAL (possibly only) prompt segment — ``packed``'s
         remainder tokens at traced cache offset ``start`` — through an
         already-seeded batch-1 ``sub_cache``, sample the first token,
-        and splice the finished slot into ``state``. Shared tail of
-        every admission flavor: cold (start 0, fresh cache), prefix-hit
-        (cache seeded with the prefix KV), and chunked (cache filled by
-        ``_prefill_part`` calls), so their semantics cannot drift."""
+        and splice the finished slot into ``state`` (the slot carried
+        in ``packed``, see ``_unpack_admission``). Shared tail of
+        every admission flavor, so their semantics cannot drift: cold
+        (``_fresh_sub`` at ``_start0``) and chunked (the cache
+        ``_prefill_part`` calls filled) call it as ``bucket``'s ONE
+        program (``_prefill_runner``), prefix-hit from ``_prefill_ext``
+        (cache seeded with the prefix KV)."""
         prompt_rem, rem_len, slot, req_vec = self._unpack_admission(
             packed, bucket
         )
@@ -832,16 +859,6 @@ class DecodeEngine:
             )[0]
         return self._write_slot_state(
             state, sub_cache, kv_mask1, slot, first, total, req_vec, rng
-        )
-
-    def _prefill(self, params, lora, state, packed, *, bucket):
-        """Prefill one whole prompt (batch 1, ``bucket`` wide) into the
-        slot carried in ``packed`` (see ``_unpack_admission``)."""
-        cache_cfg, _ = family_forward(self.cfg)
-        sub_cache = self._new_cache(cache_cfg, 1)
-        return self._prefill_tail(
-            params, lora, state, sub_cache, packed, jnp.int32(0),
-            bucket=bucket,
         )
 
     def _prefill_part(self, params, lora, sub_cache, toks, start, *,
@@ -974,7 +991,7 @@ class DecodeEngine:
             dparams, prompt, self.draft_cfg, sub, jnp.int32(0),
             positions=positions, kv_mask=kv_mask1,
             # an MoE draft's router must not let bucket-padding tokens
-            # consume expert capacity (same contract as _prefill)
+            # consume expert capacity (same contract as _prefill_tail)
             token_mask=kv_mask1[:, :S_b],
         )
         st = dict(state)
@@ -1107,10 +1124,16 @@ class DecodeEngine:
     # -- engine loop --------------------------------------------------------
 
     def _prefill_runner(self, bucket: int):
+        """``bucket``'s one prefill program: a whole prompt's and a
+        final part's alike (``_prefill_tail`` at that width)."""
         if bucket not in self._prefill_fns:
+            # donate the engine state only: the sub-cache is spliced
+            # into state's larger buffers, so its donation could never
+            # be used (it would just warn), and ``_fresh_sub`` must
+            # outlive the call
             self._prefill_fns[bucket] = jax.jit(
                 _program(
-                    self._prefill, f"{PREFILL_PROGRAM_TAG}_{bucket}",
+                    self._prefill_tail, f"{PREFILL_PROGRAM_TAG}_{bucket}",
                     bucket=bucket,
                 ),
                 donate_argnums=2,
@@ -1137,21 +1160,6 @@ class DecodeEngine:
                 _program(
                     self._prefill_part, f"{PREFILL_PROGRAM_TAG}_part_{width}",
                     width=width,
-                ),
-                donate_argnums=2,
-            )
-        return self._prefill_fns[key]
-
-    def _prefill_final_runner(self, bucket: int):
-        key = ("final", bucket)
-        if key not in self._prefill_fns:
-            # donate the engine state only: the sub-cache is spliced
-            # into state's larger buffers, so its donation could never
-            # be used (it would just warn)
-            self._prefill_fns[key] = jax.jit(
-                _program(
-                    self._prefill_tail, f"{PREFILL_PROGRAM_TAG}_final_{bucket}",
-                    bucket=bucket,
                 ),
                 donate_argnums=2,
             )
@@ -1252,7 +1260,8 @@ class DecodeEngine:
             packed = jnp.asarray(row)
             self._note_prefill(req, slot, bucket, False, "whole", L)
             self._state, first = self._prefill_runner(bucket)(
-                self.params, self.lora, self._state, packed,
+                self.params, self.lora, self._state, self._fresh_sub,
+                packed, self._start0,
             )
             self._maybe_insert_prefix(req.prompt, slot)
         # the first token stays on the device until this turn's chunk
@@ -1334,15 +1343,20 @@ class DecodeEngine:
             )
             adm["consumed"] = consumed + C
             return
-        # final part: remainder ≤ C — sample + splice into the slot
+        # final part: remainder ≤ C — sample + splice into the slot,
+        # at the smallest bucket that holds the remainder (a part's
+        # whole width only where no bucket that narrow does)
         rem = req.prompt[consumed:]
-        row = self.pack_admission(rem, self.pad_id, C, req)
-        row[0, C + 1] = slot
+        bucket = next(
+            (b for b in self.prompt_buckets if len(rem) <= b <= C), C
+        )
+        row = self.pack_admission(rem, self.pad_id, bucket, req)
+        row[0, bucket + 1] = slot
         packed = jnp.asarray(row)
         self._note_prefill(
-            req, slot, C, adm["had_prefix"], "final", len(rem)
+            req, slot, bucket, adm["had_prefix"], "final", len(rem)
         )
-        self._state, first = self._prefill_final_runner(C)(
+        self._state, first = self._prefill_runner(bucket)(
             self.params, self.lora, self._state, adm["sub"], packed,
             jnp.int32(consumed),
         )

@@ -43,3 +43,49 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: multi-process / long-running tests"
     )
+
+
+@pytest.fixture(scope="session")
+def final_part_against_whole():
+    """One prompt of two parts of 16 and ``rem`` more tokens, admitted
+    twice, each time by an engine of its own with ``max_tokens=1`` (the
+    prefill's own token ends the request, so no decode step follows
+    it): in parts under buckets (4, 8, 16), where the final part must
+    run at ``bucket`` positions, and whole in a bucket of 64. The first
+    tokens must agree; returns the prompt's length and, for each
+    admission, the slot's row of every stack of the cache on the host,
+    ``{name: [layers, ...]}``, for the family's test to compare."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from odh_kubeflow_tpu.models.engine import DecodeEngine
+    from odh_kubeflow_tpu.models.llama import stack_kind
+
+    def admit(params, cfg, prompt, max_len, **engine_kw):
+        engine = DecodeEngine(
+            params, cfg, n_slots=2, max_len=max_len, chunk=4,
+            cache_dtype=jnp.float32, **engine_kw,
+        )
+        try:
+            req = engine.submit(prompt, max_tokens=1)
+            (first,) = req.result(timeout=300)
+        finally:
+            engine.stop()
+        return req, first, {
+            name: np.asarray(leaf[:, req.slot])
+            for name, leaf in engine._state["cache"].items()
+            if stack_kind(name)
+        }
+
+    def both(params, cfg, rem, bucket, max_len):
+        prompt = np.random.default_rng(rem).integers(1, 256, size=32 + rem).tolist()
+        req, first, parts = admit(
+            params, cfg, prompt, max_len, prompt_buckets=(4, 8, 16),
+            prefill_chunk=16,
+        )
+        assert req.bucket == bucket
+        _, want, whole = admit(params, cfg, prompt, max_len, prompt_buckets=(64,))
+        assert first == want
+        return len(prompt), parts, whole
+
+    return both

@@ -139,11 +139,35 @@ def test_engine_serves_what_the_reference_computes(share):
     assert engine.cache_bytes["window"] < engine.cache_bytes["full"] * 3
 
 
+@pytest.mark.parametrize("rem, bucket", [(3, 4), (7, 8)])
+def test_a_narrow_final_part_leaves_what_the_whole_prompt_leaves(
+    share, final_part_against_whole, rem, bucket
+):
+    """Two parts of 16 and a final part of ``rem`` tokens run at
+    ``bucket`` positions, written where the ring of 32 has wrapped,
+    against the same prompt admitted whole into a cache that never
+    wraps: the same first token, the same keys and values at every
+    position a query can still see."""
+    cfg, params = share
+    n, parts, whole = final_part_against_whole(params, cfg, rem, bucket, 64)
+    assert parts["wk"].shape[1] == 32 and whole["wk"].shape[1] == 64
+    for name in parts:
+        at = np.arange(n - WINDOW if name.startswith("w") else 0, n)
+        got, want = (c[name][:, at % c[name].shape[1]] for c in (parts, whole))
+        assert np.abs(want).max() > 0
+        np.testing.assert_allclose(got, want, atol=2e-5, err_msg=name)
+
+
 def test_a_windowed_cache_refuses_what_rings_cannot_do(share):
     cfg, params = share
     with pytest.raises(NotImplementedError, match="ring"):
         DecodeEngine(params, cfg, n_slots=1, max_len=64, prompt_buckets=(8,),
                      prefix_cache_entries=2, prefix_buckets=(4,))
+    # a ring of 20 (window 8 + a bucket of 10, in tens): the part that
+    # starts at 16 would straddle its end
+    with pytest.raises(NotImplementedError, match="ring"):
+        DecodeEngine(params, cfg, n_slots=1, max_len=64, prompt_buckets=(10,),
+                     prefill_chunk=8)
 
 
 def test_sigmoid_topk_with_normalisation():

@@ -370,6 +370,120 @@ def test_chunked_prefill_allows_prompts_past_buckets(model):
         engine.stop()
 
 
+# (prompt buckets, prefill chunk) -> remainder of the final part -> the
+# width it runs at: every bucket, a part filled exactly, and a chunk
+# that is no bucket (its own width where no bucket under it holds the
+# remainder)
+_FINAL_PARTS = [
+    ((4, 16, 32), 32, 3, 4),
+    ((4, 16, 32), 32, 10, 16),
+    ((4, 16, 32), 32, 20, 32),
+    ((4, 16, 32), 32, 32, 32),
+    ((4, 64), 16, 3, 4),
+    ((4, 64), 16, 10, 16),
+]
+
+
+@pytest.fixture(scope="module")
+def engines_by_shape(model):
+    """Engines of this module's tiny model by (buckets, prefill chunk),
+    built on first use and stopped together."""
+    cfg, params = model
+    built = {}
+
+    def get(buckets, prefill_chunk):
+        key = (buckets, prefill_chunk)
+        if key not in built:
+            built[key] = DecodeEngine(
+                params, cfg, n_slots=2, max_len=256, chunk=4,
+                prompt_buckets=buckets, cache_dtype=jnp.float32,
+                prefill_chunk=prefill_chunk,
+            )
+        return built[key]
+
+    yield get
+    for engine in built.values():
+        engine.stop()
+
+
+@pytest.mark.parametrize(
+    "buckets, prefill_chunk, rem, width", _FINAL_PARTS,
+    ids=[f"chunk{c}-rem{r}-runs{w}" for _, c, r, w in _FINAL_PARTS],
+)
+def test_a_final_part_runs_at_the_smallest_bucket_that_holds_it(
+    model, engines_by_shape, buckets, prefill_chunk, rem, width
+):
+    """The last part of a prompt admitted in parts runs through the
+    program of the smallest bucket that holds it (``prefill_chunk``'s
+    own width where no bucket under it does), at its offset, and the
+    request decodes to the tokens of the same prompt admitted whole;
+    the counters and the request say the width that ran."""
+    prompt = (
+        np.random.default_rng(rem).integers(1, 200, size=2 * prefill_chunk + rem)
+    ).tolist()
+    whole = engines_by_shape((128,), None)
+    in_parts = engines_by_shape(buckets, prefill_chunk)
+    want = whole.submit(prompt, max_tokens=10).result(timeout=300)
+    tokens, positions = in_parts.prefill_tokens, in_parts.prefill_positions
+    parts = in_parts.parts
+    req = in_parts.submit(prompt, max_tokens=10)
+    assert req.result(timeout=300) == want
+    assert req.bucket == width
+    assert in_parts.prefill_tokens - tokens == len(prompt)
+    assert in_parts.prefill_positions - positions == 2 * prefill_chunk + width
+    assert in_parts.parts - parts == 3
+    assert set(in_parts._prefill_fns) <= (
+        set(buckets) | {prefill_chunk, ("part", prefill_chunk)}
+    )
+
+
+def test_the_benchmarks_warm_up_calls_every_program_a_final_part_can(model):
+    """What the benchmark's drivers call before their window opens (one
+    whole prompt a bucket, then one prompt of two parts and 5 tokens)
+    has run every prefill program the window can: a long prompt with a
+    remainder in each bucket adds no program and no executable to one,
+    and each program's name holds the tag and ends in the positions it
+    runs (what ``benchmark/metrics/hybrid_roofline.py`` reckons a scan's
+    work from)."""
+    from benchmark.drivers.engine import warm_up
+    from odh_kubeflow_tpu.models.engine import PREFILL_PROGRAM_TAG
+
+    cfg, params = model
+    C = 64
+    engine = DecodeEngine(
+        params, cfg, n_slots=2, max_len=512, chunk=4,
+        prompt_buckets=(8, 16, 32, C), cache_dtype=jnp.float32,
+        prefill_chunk=C,
+    )
+    try:
+        warm_up(engine, cfg.vocab_size, {"temperature": 0.7, "top_p": 0.95})
+        rng = np.random.default_rng(1)
+        engine.submit(
+            rng.integers(1, cfg.vocab_size, size=2 * C + 5).tolist(),
+            max_tokens=4,
+        ).result(timeout=300)
+        programs = dict(engine._prefill_fns)
+        executables = {k: f._cache_size() for k, f in programs.items()}
+        assert set(programs) == {8, 16, 32, C, ("part", C)}
+        for rem in (5, 12, 20, 40, C):
+            req = engine.submit(
+                rng.integers(1, cfg.vocab_size, size=C + rem).tolist(),
+                max_tokens=4,
+            )
+            req.result(timeout=300)
+            assert req.bucket == next(b for b in (8, 16, 32, C) if rem <= b)
+        assert engine._prefill_fns == programs
+        assert {
+            k: f._cache_size() for k, f in programs.items()
+        } == executables
+    finally:
+        engine.stop()
+    for key, fn in programs.items():
+        width = key if isinstance(key, int) else key[1]
+        assert PREFILL_PROGRAM_TAG in fn.__name__
+        assert int(fn.__name__.rsplit("_", 1)[1]) == width, fn.__name__
+
+
 def test_chunked_prefill_interleaves_decode(model):
     """The anti-head-of-line-blocking contract: while a long admission
     runs part-by-part, an already-active stream keeps emitting tokens

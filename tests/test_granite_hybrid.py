@@ -403,9 +403,9 @@ def test_engine_counts_state_tokens_and_positions(tiny, engine):
     assert engine.cache_bytes["window"] == 0 and engine.cache_bytes["full"] > 0
     tokens, positions = engine.prefill_tokens, engine.prefill_positions
     engine.submit([3] * 21, max_tokens=2).result(timeout=300)
-    # one whole part of 16 and a final one of 5 in a part's width
+    # one whole part of 16 and a final one of 5 in the bucket of 8
     assert engine.prefill_tokens - tokens == 21
-    assert engine.prefill_positions - positions == 32
+    assert engine.prefill_positions - positions == 24
     assert engine.decode_calls > 0
     assert engine.decode_steps == engine.decode_calls * engine.chunk
     # the turns that ran its parts say how many it takes in all
@@ -413,6 +413,27 @@ def test_engine_counts_state_tokens_and_positions(tiny, engine):
 
     admits = tracing.collector().spans_named("engine.admit")
     assert any(s.attrs.get("parts") == 2 for s in admits)
+
+
+@pytest.mark.parametrize("rem, bucket", [(3, 4), (7, 8)])
+def test_a_narrow_final_part_leaves_what_the_whole_prompt_leaves(
+    tiny, final_part_against_whole, rem, bucket
+):
+    """Two parts of 16 and a final part of ``rem`` tokens run at
+    ``bucket`` positions, its scan starting from the state and the
+    convolution's tail that the parts carried, against the same prompt
+    admitted whole: the same first token, the same SSM state and
+    tail, the same keys and values."""
+    cfg, params = tiny
+    n, parts, whole = final_part_against_whole(params, cfg, rem, bucket, 96)
+    assert set(parts) == set(llama.STATE_STACKS) | {"k", "v"}
+    for name in parts:
+        got, want = (
+            c[name] if name in llama.STATE_STACKS else c[name][:, :n]
+            for c in (parts, whole)
+        )
+        assert np.abs(want).max() > 0
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5, err_msg=name)
 
 
 def test_a_stopped_engines_slot_holds_the_state_of_its_stream(tiny):

@@ -531,6 +531,30 @@ def test_an_idle_slot_beside_a_busy_one(tiny, engine):
         assert_served_is_the_reference(tiny, prompt, r.result(timeout=300))
 
 
+@pytest.mark.parametrize("rem, bucket", [(3, 4), (7, 8)])
+def test_a_narrow_final_part_leaves_what_the_whole_prompt_leaves(
+    tiny, final_part_against_whole, rem, bucket
+):
+    """Two parts of 16 and a final part of ``rem`` tokens run at
+    ``bucket`` positions, its scan starting from the state and the
+    convolution's tail that the parts carried, against the same prompt
+    admitted whole: the same first token, the same delta-rule state and
+    tail, the same keys and values."""
+    cfg, params = tiny
+    n, parts, whole = final_part_against_whole(params, cfg, rem, bucket, 96)
+    assert set(parts) == set(llama.STATE_STACKS) | {"k", "v"}
+    for name in parts:
+        got, want = (
+            c[name] if name in llama.STATE_STACKS else c[name][:, :n]
+            for c in (parts, whole)
+        )
+        # float32 sums in another order, as for a scan fed in two parts
+        # above (a final part at a whole part's width is as far off)
+        scale = float(np.abs(want).max())
+        assert scale > 0
+        np.testing.assert_allclose(got, want, atol=1e-4 * scale, err_msg=name)
+
+
 def test_a_stopped_engines_slot_holds_the_state_of_its_stream(tiny):
     """Stopped with a request still decoding: the slot's row of the
     delta-rule state is the reference's after the prompt and every token
