@@ -486,3 +486,92 @@ def test_qwen3_next_share_updates_state_and_cache_in_place(one_chip, monkeypatch
         # a part's activations: the sorted rows of 2048 tokens x 10
         # choices and the projections' [2048, 12288] outputs
         assert mem.temp_size_in_bytes < 0.6e9, mem.temp_size_in_bytes
+
+
+# ---- Brumby's stage (brumby): 40 query heads onto 8 states of 65 x 128 x
+# 128 a layer a slot (34 MB), chunks of 128; 16 slots; no keys and values
+
+
+@pytest.mark.parametrize("S", [2048, 64], ids=["part", "bucket-64"])
+def test_retention_chunk_scan_compiles_at_published_widths(one_chip, S):
+    from odh_kubeflow_tpu.ops import pallas_retention as pr
+
+    Hq, Hkv, d = 40, 8, 128
+    R = pr.phi_rows(d)
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    compiled = jax.jit(pr.retention_chunk_scan).lower(*_on(one_chip, (
+        jax.ShapeDtypeStruct((1, S, Hq, d), bf16),
+        jax.ShapeDtypeStruct((1, S, Hkv, d), bf16),
+        jax.ShapeDtypeStruct((1, S, Hkv, d), bf16),
+        jax.ShapeDtypeStruct((1, S, Hkv), f32),
+        jax.ShapeDtypeStruct((1, Hkv, R, d, d), f32),
+        jax.ShapeDtypeStruct((1, Hkv, R, d), f32),
+    ))).compile()
+    assert "retention_chunk_scan" in compiled.as_text()
+
+
+def test_retention_decode_update_is_one_pass_over_the_stacked_state(one_chip):
+    from odh_kubeflow_tpu.ops import pallas_retention as pr
+
+    L, B, Hq, Hkv, d = 10, 16, 40, 8, 128
+    R = pr.phi_rows(d)
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    state = jax.ShapeDtypeStruct((L, B, Hkv, R, d, d), f32)
+    norm = jax.ShapeDtypeStruct((L, B, Hkv, R, d), f32)
+    compiled = jax.jit(pr.retention_decode_update, donate_argnums=(4, 5)).lower(
+        *_on(one_chip, (
+            jax.ShapeDtypeStruct((B, Hq, d), bf16),
+            jax.ShapeDtypeStruct((B, Hkv, d), bf16),
+            jax.ShapeDtypeStruct((B, Hkv, d), bf16),
+            jax.ShapeDtypeStruct((B, Hkv), f32),
+            state, norm, jax.ShapeDtypeStruct((), jnp.int32),
+        ))
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert "retention_decode_update" in compiled.as_text()
+    assert mem.alias_size_in_bytes >= (state.size + norm.size) * 4
+    # a few rows a head beside a 4.3 MB state, never a layer's state
+    assert mem.temp_size_in_bytes < state.size * 4 // L // 4
+
+
+@pytest.mark.parametrize("B,S", [(16, 1), (1, 2048)], ids=["decode", "part"])
+def test_brumby_stage_updates_its_state_in_place(one_chip, monkeypatch, B, S):
+    """Two layers at published widths, a decode step of 16 slots and a
+    part of 2048 positions, compiled for the chip: state and normaliser
+    (the WHOLE cache: there are no keys and values) are aliased, the
+    kernel is there, and the temporaries are far under a layer's state
+    (none is sliced out of the stack, no dequantised projection is
+    written out beside it)."""
+    from odh_kubeflow_tpu.models import brumby as bm
+
+    monkeypatch.setattr(bm, "_uses_kernels", lambda: True)
+    cfg = bm.BrumbyConfig(num_layers=2)
+    params = jax.eval_shape(
+        lambda: bm.init_params(jax.random.key(0), cfg, jnp.bfloat16)
+    )
+    for name in ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"):
+        params["layers"][name] = _int8_bank(params["layers"][name].shape)
+    cache = jax.eval_shape(lambda: init_cache(cfg, B, 20480, widest_part=2048))
+    assert set(cache) == set(llama.STATE_STACKS)
+    assert cache["ssm"].shape == (2, B, 8, 65, 128, 128)
+    assert cache["conv"].shape == (2, B, 8, 65, 128)
+
+    def step(params, cache, tokens, index, token_mask):
+        return bm.forward_with_cache(
+            params, tokens, cfg, cache, index,
+            positions=jnp.broadcast_to(jnp.arange(S), (B, S)), token_mask=token_mask,
+        )
+
+    compiled = jax.jit(step, donate_argnums=1).lower(*_on(one_chip, (
+        params, cache, jax.ShapeDtypeStruct((B, S), jnp.int32),
+        jax.ShapeDtypeStruct((B,) if S == 1 else (), jnp.int32),
+        jax.ShapeDtypeStruct((B, S), jnp.bool_),
+    ))).compile()
+    mem = compiled.memory_analysis()
+    stacks = sum(v.size * v.dtype.itemsize for v in cache.values())
+    assert mem.alias_size_in_bytes >= stacks
+    text = compiled.as_text()
+    assert ("retention_decode_update" if S == 1 else "retention_chunk_scan") in text
+    one_layer = stacks // 2
+    print(f"brumby {B}x{S}: temp {mem.temp_size_in_bytes / 1e6:.1f} MB")
+    assert mem.temp_size_in_bytes < (one_layer // 4 if S == 1 else 1.5e9)
