@@ -414,7 +414,8 @@ def check_delta_rule_kernels() -> dict:
     at that family's attention shape (head 256, 8 query heads a KV head,
     a row of 512), which the kernel's ``supported`` admits and no other
     configuration runs. Same bound as flash. Also times both kernels
-    beside their plain forms (XLA's own fusions)."""
+    beside their plain forms (XLA's own fusions), the scan at each of
+    the cell's buckets too (``gdn_chunk_scan_at_<positions>``, PR 41)."""
     from odh_kubeflow_tpu.ops import pallas_gdn as pg
     from odh_kubeflow_tpu.ops.attention import dense_attention
     from odh_kubeflow_tpu.ops.pallas_decode_attention import decode_attend
@@ -444,6 +445,13 @@ def check_delta_rule_kernels() -> dict:
         )
         for c in (64, 128)
     }
+    # at each of the cell's buckets (chunks of 64): wall time a call, so
+    # a short bucket reads the host's dispatch, not the kernel
+    for positions in (64, 256, 1024, 2048):
+        ms[f"gdn_chunk_scan_at_{positions}"] = _ms_a_call(
+            jax.jit(pg.gdn_chunk_scan),
+            *(a[:, :positions] for a in (q, kk, v, g, beta)), init,
+        )
 
     state = jax.random.normal(k[7], (L, slots, H, dk, dv), f32)
     args = (

@@ -60,11 +60,33 @@ def reference_recurrence(q, k, v, g, beta, S):
 
 
 @pytest.mark.parametrize(
-    "S,chunk,dk,dv", [(37, 16, 8, 16), (150, 64, 128, 128), (7, 8, 16, 16)],
-    ids=["tiny-37", "published-widths-150", "shorter-than-a-chunk"],
+    "S,chunk,Hk,H,dk,dv,lengths",
+    [
+        (37, 16, 2, 4, 8, 16, None), (150, 64, 2, 4, 128, 128, None),
+        (7, 8, 2, 4, 16, 16, None),
+        # what solving many systems a grid step can break: a padded batch
+        # of chunks (two systems a chunk, so four chunks a step: six
+        # chunks run as eight), one chunk with more heads than a step
+        # takes, a key head of one and of four value heads (a pack of two
+        # key heads; four systems a step and two chunks beside them),
+        # rows of different true length behind one grid
+        (64 * 5 + 9, 64, 1, 2, 16, 16, None), (64, 64, 16, 32, 8, 8, None),
+        (100, 16, 4, 4, 8, 16, None), (100, 16, 1, 4, 8, 16, None),
+        (64 * 3, 64, 1, 2, 16, 16, (64 * 3, 70)),
+        # three systems a step: the odd one has no neighbour along the lanes
+        (16, 16, 1, 3, 8, 16, None),
+    ],
+    ids=["tiny-37", "published-widths-150", "shorter-than-a-chunk",
+         "chunks-not-a-multiple-of-the-batch", "one-chunk-many-heads",
+         "rep-1", "rep-4", "two-true-lengths", "an-odd-system-out"],
 )
-def test_chunked_scan_is_its_plain_form_and_the_token_recurrence(S, chunk, dk, dv):
-    a = scan_inputs(2, S, 2, 4, dk, dv)
+def test_chunked_scan_is_its_plain_form_and_the_token_recurrence(
+    S, chunk, Hk, H, dk, dv, lengths
+):
+    a = scan_inputs(2, S, Hk, H, dk, dv)
+    if lengths is not None:
+        real = (jnp.arange(S)[None] < jnp.asarray(lengths)[:, None])[..., None]
+        a = (*a[:3], a[3] * real, a[4] * real, a[5])
     o0, f0 = pg.gdn_scan_plain(*a)
     o1, f1 = pg.gdn_chunk_scan(*a, chunk=chunk, interpret=True)
     np.testing.assert_allclose(o1, o0, atol=2e-5)
@@ -75,16 +97,28 @@ def test_chunked_scan_is_its_plain_form_and_the_token_recurrence(S, chunk, dk, d
     np.testing.assert_allclose(f0[0], frf, atol=2e-5)
 
 
-@pytest.mark.parametrize("beta,g", [(0.5, -0.05), (0.9, -0.001), (0.99, 0.0)])
-def test_a_run_of_one_token_does_not_break_the_in_chunk_solve(beta, g):
+@pytest.mark.parametrize(
+    "beta,g,run",
+    [(0.5, -0.05, None), (0.9, -0.001, None), (0.99, 0.0, None),
+     (0.9, -0.001, (100, 290)), (0.99, 0.0, (250, 262))],
+    ids=["0.5", "0.9", "0.99", "0.9-across-a-batch", "0.99-over-the-boundary"],
+)
+def test_a_run_of_one_token_does_not_break_the_in_chunk_solve(beta, g, run):
     """Keys that repeat (a prompt's run of one token) make ``A`` ``beta``
     times a matrix of ones: ``(I + A)^-1`` as a product of squarings over
     the whole chunk then cancels powers with entries of 1e17 and reads
-    NaN. The blockwise solve is the recurrence at float32's own error."""
-    S, Hk, H, dk, dv = 200, 2, 4, 128, 128
-    ks = jax.random.split(jax.random.key(11), 2)
+    NaN. The blockwise solve is the recurrence at float32's own error,
+    also where the run lies across the chunks that one grid step solves
+    together and the next's (four value heads: two chunks a step, so a
+    step ends at 128 and at 256)."""
+    S, Hk, H, dk, dv = 200 if run is None else 320, 2, 4, 128, 128
+    ks = jax.random.split(jax.random.key(11), 3)
     one = jax.random.normal(ks[0], (1, 1, Hk, dk))
-    k = jnp.broadcast_to(one / jnp.linalg.norm(one, axis=-1, keepdims=True), (1, S, Hk, dk))
+    k = jnp.broadcast_to(one, (1, S, Hk, dk))
+    if run is not None:
+        inside = ((jnp.arange(S) >= run[0]) & (jnp.arange(S) < run[1]))[None, :, None, None]
+        k = jnp.where(inside, k, jax.random.normal(ks[2], (1, S, Hk, dk)))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
     v = jax.random.normal(ks[1], (1, S, H, dv))
     a = (k * dk**-0.5, k, v, jnp.full((1, S, H), g), jnp.full((1, S, H), beta),
          jnp.zeros((1, H, dk, dv)))

@@ -383,20 +383,45 @@ def test_granite_stage_updates_state_and_cache_in_place(one_chip, monkeypatch, B
 # router of 512; attention at head 256, 16 / 2 heads
 
 
-@pytest.mark.parametrize("S", [2048, 64], ids=["part", "bucket-64"])
-def test_gdn_chunk_scan_compiles_at_published_widths(one_chip, S):
-    from odh_kubeflow_tpu.ops import pallas_gdn
-
+def _gdn_scan_operands(S):
     Hk, H, dk, dv = 16, 32, 128, 128
     f32, bf16 = jnp.float32, jnp.bfloat16
-    compiled = jax.jit(pallas_gdn.gdn_chunk_scan).lower(*_on(one_chip, (
+    return (
         jax.ShapeDtypeStruct((1, S, Hk, dk), bf16),
         jax.ShapeDtypeStruct((1, S, Hk, dk), bf16),
         jax.ShapeDtypeStruct((1, S, H, dv), bf16),
         jax.ShapeDtypeStruct((1, S, H), f32), jax.ShapeDtypeStruct((1, S, H), f32),
         jax.ShapeDtypeStruct((1, H, dk, dv), f32),
-    ))).compile()
+    )
+
+
+@pytest.mark.parametrize("S", [2048, 64], ids=["part", "bucket-64"])
+def test_gdn_chunk_scan_compiles_at_published_widths(one_chip, S):
+    from odh_kubeflow_tpu.ops import pallas_gdn
+
+    compiled = jax.jit(pallas_gdn.gdn_chunk_scan).lower(
+        *_on(one_chip, _gdn_scan_operands(S))
+    ).compile()
     assert "gdn_chunk_scan" in compiled.as_text()
+
+
+@pytest.mark.parametrize("S", [64, 256, 2048], ids=["bucket-64", "bucket-256", "part"])
+def test_every_kernel_of_the_gdn_scan_carries_its_name(one_chip, S):
+    """``gdn_prefill_roofline.gdn`` divides the scan's counted work by the
+    seconds of the device events named ``gdn_chunk_scan``: a kernel that
+    held part of the scan under another name would read as a gain."""
+    import re
+
+    from odh_kubeflow_tpu.ops import pallas_gdn
+
+    text = jax.jit(pallas_gdn.gdn_chunk_scan).lower(
+        *_on(one_chip, _gdn_scan_operands(S))
+    ).as_text()
+    calls = [line for line in text.splitlines() if "@tpu_custom_call" in line]
+    assert calls
+    for line in calls:
+        name = re.search(r'kernel_name = "([^"]*)"', line)
+        assert name and "gdn_chunk_scan" in name.group(1), line[-300:]
 
 
 def test_gdn_decode_update_is_one_pass_over_the_stacked_state(one_chip):
