@@ -213,6 +213,7 @@ def check_kernels(cfg) -> dict:
         "state_space": check_state_space_kernels(),
         "delta_rule": check_delta_rule_kernels(),
         "retention": check_retention_kernels(),
+        "sparse_attention": check_sparse_attention(),
     }
 
 
@@ -605,6 +606,150 @@ def check_retention_kernels() -> dict:
         ),
         "decode_update_temp_bytes": mem.temp_size_in_bytes,
     }
+
+
+def check_sparse_attention() -> dict:
+    """What a stack whose layers attend only the keys an indexer picks
+    adds (PR 42), at Keye-VL-2.0's published shapes (32 query heads onto
+    4 key/value heads of 128, an indexer of 16 heads of 64 that keeps
+    2048 keys; 16 slots x 24576), each piece against its plain form:
+    ``index_scores`` for one query a slot over the stacked indexer keys
+    and for a part of 2048 queries at offset 22528; ``kth_largest`` at
+    ``[16, 24576]`` and ``[2048, 24576]`` against ``lax.top_k``'s k-th
+    value (timed too: it is what the reference's selection costs); the
+    kept positions compacted and the key rows gathered from the 4-D
+    stack against ``take``; attention under the selection as a mask
+    (``decode_attend``'s ``select``) against the masked dense form; a
+    decode step's keys written in place as columns. From the gather's
+    compiled module: no layer of the stack is copied out. ms a call."""
+    from odh_kubeflow_tpu.ops import select
+    from odh_kubeflow_tpu.ops import sparse_attention as sa
+    from odh_kubeflow_tpu.ops.pallas_decode_attention import decode_attend
+
+    slots, S_max, Hq, Hkv, hd, Hi, di, topk, L, P = 16, 24576, 32, 4, 128, 16, 64, 2048, 2, 2048
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    k = jax.random.split(jax.random.key(42), 12)
+    errs, ms = {}, {}
+
+    # ---- a decode step's pieces, 16 slots at their own depths
+    ik = jax.random.normal(k[0], (L, slots, di, S_max), bf16)
+    qi = jax.random.normal(k[1], (slots, 1, Hi, di), bf16)
+    w = jax.random.normal(k[2], (slots, 1, Hi), f32) / 4
+    depth = jnp.asarray(
+        [5, 700, 2047, 2048, 4097, 9000, 12000, 24575] * 2, jnp.int32
+    )
+    seen = sa.visible(depth[:, None], None, S_max)
+    scores = sa.index_scores(qi, w, ik, 1, depth)
+    plain = jax.jit(sa.index_scores_plain)(qi, w, ik, 1)
+    errs["index_scores.decode"] = round(
+        _rel_err(jnp.where(seen, scores, 0), jnp.where(seen, plain, 0)), 5
+    )
+    ms["index_scores_16x1"] = _ms_a_call(jax.jit(sa.index_scores), qi, w, ik, 1, depth)
+    ms["index_scores_16x1_plain"] = _ms_a_call(
+        jax.jit(sa.index_scores_plain), qi, w, ik, 1
+    )
+    kth = jax.jit(select.kth_largest, static_argnums=1)
+    got = kth(scores[:, 0], topk, seen[:, 0])
+    # (two arguments: ``_ms_a_call`` hands a single one on as a state)
+    top = jax.jit(lambda x, seen: jax.lax.top_k(
+        jnp.where(seen, x, -jnp.inf), topk
+    )[0][:, -1])
+    want = top(scores[:, 0], seen[:, 0])
+    if not bool(jnp.all(got == want)):
+        raise AssertionError(f"kth_largest {got} is not lax.top_k's {want}")
+    ms["kth_largest_16x24576"] = _ms_a_call(kth, scores[:, 0], topk, seen[:, 0])
+    ms["lax_top_k_16x24576"] = _ms_a_call(top, scores[:, 0], seen[:, 0])
+
+    @jax.jit
+    def pick(scores, seen):
+        thr, cut = sa.select_threshold(scores, seen, topk)
+        return sa.compact_positions(sa.selected(scores, seen, thr, cut), topk)
+
+    ids, count = pick(scores[:, 0], seen[:, 0])
+    if count.tolist() != jnp.minimum(depth + 1, topk).tolist():
+        raise AssertionError(f"kept {count.tolist()} of {(depth + 1).tolist()}")
+    _, want_ids = jax.lax.top_k(jnp.where(seen[:, 0], scores[:, 0], -jnp.inf), topk)
+    for row in (3, 5, 15):
+        if not bool(jnp.all(jnp.sort(want_ids[row]) == ids[row])):
+            raise AssertionError(f"row {row}: the kept positions are not lax.top_k's")
+    ms["select_and_compact_16x24576"] = _ms_a_call(pick, scores[:, 0], seen[:, 0])
+
+    stack_k = jax.random.normal(k[3], (L, slots, S_max, Hkv * hd), bf16)
+    stack_v = jax.random.normal(k[4], (L, slots, S_max, Hkv * hd), bf16)
+    gather = jax.jit(sa.gather_rows)
+    rows_k, rows_v = gather(stack_k, 1, ids), gather(stack_v, 1, ids)
+    take = jnp.take_along_axis(stack_k[1], jnp.minimum(ids, S_max - 1)[..., None], 1)
+    if not bool(jnp.all(rows_k == take)):
+        raise AssertionError("the gathered rows are not the stack's")
+    text = gather.lower(stack_k, 1, ids).compile().as_text()
+    copies = cache_layer_copies(text, stack_k)
+    if copies:
+        raise AssertionError(f"the gather copies a layer out of the stack: {copies[:2]}")
+    ms["gather_rows_16x2048"] = _ms_a_call(gather, stack_k, 1, ids)
+    q1 = jax.random.normal(k[5], (slots, 1, Hq, hd), bf16)
+    attend = jax.jit(decode_attend)
+    out = attend(q1, rows_k[None], rows_v[None], 0, count - 1)
+    keep = sa.selected(scores, seen, *sa.select_threshold(scores, seen, topk))
+    heads = lambda c: c[1].reshape(slots, S_max, Hkv, hd)  # noqa: E731
+    dense = jax.jit(sa.masked_attention)(q1, heads(stack_k), heads(stack_v), keep)
+    errs["gathered_decode_attend"] = round(_rel_err(out, dense), 5)
+    ms["decode_attend_gathered_16x2048"] = _ms_a_call(
+        attend, q1, rows_k[None], rows_v[None], 0, count - 1
+    )
+    write = jax.jit(sa.write_index_keys, donate_argnums=0)
+    new = jax.random.normal(k[6], (slots, di), bf16)
+    ik_before = ik[1, 3, :, 2040:2056]
+    ik = write(ik, new, 1, depth)
+    if not bool(jnp.all(ik[1, jnp.arange(slots), :, depth] == new)):
+        raise AssertionError("a key was not written at its position")
+    if not bool(jnp.all(ik[1, 3, :, 2040:2048] == ik_before[:, :8])):
+        raise AssertionError("the write moved its neighbours")
+    del stack_k, stack_v, rows_k, rows_v, dense, keep, scores, plain
+
+    # ---- a part of a prompt: 2048 queries at offset 22528
+    off = jnp.int32(S_max - P)
+    ik1 = jax.random.normal(k[7], (L, 1, di, S_max), bf16)
+    qp = jax.random.normal(k[8], (1, P, Hi, di), bf16)
+    wp = jax.random.normal(k[9], (1, P, Hi), f32) / 4
+    q_pos = off + jnp.arange(P)[None]
+    seen = sa.visible(q_pos, None, S_max)
+    scores = sa.index_scores(qp, wp, ik1, 1, off)
+    plain = jax.jit(sa.index_scores_plain)(qp, wp, ik1, 1)
+    errs["index_scores.part"] = round(
+        _rel_err(jnp.where(seen, scores, 0), jnp.where(seen, plain, 0)), 5
+    )
+    ms["index_scores_1x2048"] = _ms_a_call(jax.jit(sa.index_scores), qp, wp, ik1, 1, off)
+    got = kth(scores[0], topk, seen[0])
+    want = top(scores[0], seen[0])
+    if not bool(jnp.all(got == want)):
+        raise AssertionError("kth_largest at [2048, 24576] is not lax.top_k's")
+    ms["kth_largest_2048x24576"] = _ms_a_call(kth, scores[0], topk, seen[0])
+    ms["lax_top_k_2048x24576"] = _ms_a_call(top, scores[0], seen[0], n=2)
+    threshold = jax.jit(lambda s, v: sa.select_threshold(s, v, topk))
+    thr, cut = threshold(scores, seen)
+    ms["select_threshold_2048x24576"] = _ms_a_call(threshold, scores, seen)
+    ck = jax.random.normal(k[10], (L, 1, S_max, Hkv * hd), bf16)
+    cv = jax.random.normal(k[11], (L, 1, S_max, Hkv * hd), bf16)
+    qa = jax.random.normal(k[5], (1, P, Hq, hd), bf16)
+    masked = jax.jit(lambda q, k, v, s, t, c: decode_attend(
+        q, k, v, 1, off, None, select=(s, t, c)
+    ))
+    out = masked(qa, ck, cv, scores, thr, cut)
+    # the plain form a block of queries at a time: its scores are [32, 256, 24576]
+    keep = sa.selected(scores, seen, thr, cut)
+    heads = lambda c: c[1].reshape(1, S_max, Hkv, hd)  # noqa: E731
+    dense = jax.jit(sa.masked_attention)(
+        qa[:, :256], heads(ck), heads(cv), keep[:, :256]
+    )
+    errs["masked_prefill_attend"] = round(_rel_err(out[:, :256], dense), 5)
+    ms["decode_attend_masked_1x2048"] = _ms_a_call(masked, qa, ck, cv, scores, thr, cut)
+    ms["decode_attend_plain_1x2048"] = _ms_a_call(
+        jax.jit(lambda q, k, v: decode_attend(q, k, v, 1, off, None)), qa, ck, cv
+    )
+    for name, err in errs.items():
+        if not err < 5e-2:
+            raise AssertionError(f"{name}: relative error {err}")
+    return {"rel_err": errs, "ms": ms}
 
 
 def cache_layer_copies(hlo_text: str, cache_leaf) -> list:

@@ -73,12 +73,14 @@ from odh_kubeflow_tpu.models.generate import (
     init_cache,
 )
 from odh_kubeflow_tpu.models.llama import (
+    INDEXED,
     STATE,
     LlamaConfig,
     kind_of,
     layer_kinds,
     stack_kind,
 )
+from odh_kubeflow_tpu.ops.select import _key_value, _largest_key, _ordered_keys
 from odh_kubeflow_tpu.utils import prometheus, tracing
 from odh_kubeflow_tpu.utils.compile_cache import install_process_cache
 from odh_kubeflow_tpu.utils.profiling import hot_span
@@ -93,6 +95,12 @@ Params = dict[str, Any]
 # its name, which ends in ``_<positions it runs>``.
 DECODE_PROGRAM = "_decode_chunk"
 PREFILL_PROGRAM_TAG = "_prefill"
+
+# What the cached forward counts a call (leaves of the cache that are no
+# stacks): a decode chunk zeroes them and the host reads them with its
+# tokens. Held experts' assignments; an indexer's positions seen and
+# attended.
+CALL_COUNTERS = ("moe_stats", "sel_stats")
 
 # What a slot did in one step of a decode chunk (``DecodeEngine.slot_steps``
 # counts every one of a chunk's ``chunk x n_slots`` under exactly one):
@@ -111,50 +119,6 @@ _TTFT_BUCKETS = (
 _ITL_BUCKETS = (
     0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0,
 )
-
-
-# bits of a key one pass of ``_largest_key`` settles: its 2**bits - 1
-# candidates share one read of the row. On a v5e the sampler takes
-# 0.80 / 0.51 / 0.45 ms a step at [32, 100352] with 1 / 2 / 4; with 4
-# every program that holds it loads 0.2 s slower (PERF.md, PR 33)
-_SEARCH_BITS = 2
-
-
-def _ordered_keys(x: jnp.ndarray) -> jnp.ndarray:
-    """uint32 keys whose integer order is the float32 order of ``x``
-    (``-0.0`` one below ``+0.0``)."""
-    b = jax.lax.bitcast_convert_type(x, jnp.uint32)
-    return jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(1 << 31))
-
-
-def _key_value(keys: jnp.ndarray) -> jnp.ndarray:
-    """The float32 an ordered key stands for."""
-    b = jnp.where(keys >> 31 == 1, keys ^ jnp.uint32(1 << 31), ~keys)
-    return jax.lax.bitcast_convert_type(b, jnp.float32)
-
-
-def _largest_key(holds, rows: int) -> jnp.ndarray:
-    """Per row the largest uint32 ``t`` with ``holds(t)``, for a
-    ``holds`` ([rows] keys -> [rows] bool) that is true at 0 and, once
-    false, stays false as ``t`` grows. The key is settled from its top
-    bits down, ``_SEARCH_BITS`` a pass: every candidate of a pass is one
-    compare and one row reduction over the same operands, which XLA
-    fuses into one read of them. The passes stay a ``while``: laid out
-    one after the other they save 0.1 ms a step at [32, 100352], and
-    every program that holds the sampler then loads 0.2-0.35 s slower
-    from the compile cache (PERF.md, PR 33)."""
-
-    def settle(i, t):
-        shift = (32 - _SEARCH_BITS * (i + 1)).astype(jnp.uint32)
-        digit = sum(
-            holds(t | (jnp.uint32(d) << shift)).astype(jnp.uint32)
-            for d in range(1, 1 << _SEARCH_BITS)
-        )
-        return t | (digit << shift)
-
-    return jax.lax.fori_loop(
-        0, 32 // _SEARCH_BITS, settle, jnp.zeros((rows,), jnp.uint32)
-    )
 
 
 def mask_logits_rowwise(
@@ -570,12 +534,12 @@ class DecodeEngine:
         # the very same program
         self._fresh_sub = self._new_cache(cache_cfg, 1)
         self._start0 = jnp.int32(0)
-        # a second home for a chunk's expert counters, made and placed
-        # as the cache's own: ``_take_chunk_stats`` swaps the two
-        self._stats_spare = (
-            jnp.zeros_like(self._state["cache"]["moe_stats"])
-            if "moe_stats" in self._state["cache"] else None
-        )
+        # a second home for a chunk's counters, made and placed as the
+        # cache's own: ``_take_chunk_stats`` swaps the two
+        self._stats_spare = {
+            name: jnp.zeros_like(self._state["cache"][name])
+            for name in CALL_COUNTERS if name in self._state["cache"]
+        }
         if mesh is not None:
             from jax.sharding import NamedSharding
 
@@ -595,10 +559,10 @@ class DecodeEngine:
                 )
                 for k, v in self._state.items()
             }
-            if self._stats_spare is not None:
-                self._stats_spare = jax.device_put(
-                    self._stats_spare, cspec["moe_stats"]
-                )
+            self._stats_spare = {
+                name: jax.device_put(spare, cspec[name])
+                for name, spare in self._stats_spare.items()
+            }
             # one row cannot shard over the slots' axes: every device
             # holds it, placed once and not at each admission
             self._fresh_sub = jax.device_put(self._fresh_sub, rep)
@@ -628,6 +592,15 @@ class DecodeEngine:
                 "speculative verify writes positions it may take back; a "
                 "recurrent layer's state has no position to take back to"
             )
+        if self.cache_bytes[INDEXED] and (
+            prefix_cache_entries or draft_params is not None
+        ):
+            raise NotImplementedError(
+                "a prefix entry holds keys and values by name and would "
+                "seed a stream whose indexer keys are zeros; speculative "
+                "verify asks an indexed layer for several tokens a row at "
+                "per-row offsets, one selection each"
+            )
         # counters of what the cached forward adds for a mixture of
         # held experts and for window layers (PERF.md section 3): read
         # with each decode chunk's own fetch
@@ -635,6 +608,10 @@ class DecodeEngine:
         self.moe_experts_hit = 0
         self.moe_dropped = 0
         self.moe_rows_computed = 0  # rows of the expert kernel's live tiles
+        # what the decode steps' queries of indexed layers could see and
+        # what they attended, in (slot, layer) positions
+        self.sel_causal_rows = 0
+        self.sel_attended_rows = 0
         self.window_blocks_skipped = 0
         kinds = layer_kinds(cache_cfg)
         # {window: how many layers of the whole stack have it}
@@ -689,10 +666,14 @@ class DecodeEngine:
         # (whole prompts, parts of a chunked admission, prefix seeding)
         self.turns = 0
         self.prefill_calls = 0
-        # prompt tokens those programs ran, and the positions they ran
-        # them in (a bucket's or a part's width)
+        # prompt tokens those programs ran, the positions they ran them
+        # in (a bucket's or a part's width) and the causal (query, key)
+        # pairs of those tokens, a layer: all, and those of the programs
+        # that started a stream (a whole prompt, a first part)
         self.prefill_tokens = 0
         self.prefill_positions = 0
+        self.prefill_pairs = 0
+        self.prefill_pairs_first = 0
         # first tokens emitted ahead of their turn's chunk fetch: every
         # request that reached a slot with max_tokens > 1
         self.first_tokens_early = 0
@@ -881,12 +862,11 @@ class DecodeEngine:
 
     def _decode_chunk(self, params_lora, state, *, greedy: bool = False):
         params, lora = params_lora
-        if "moe_stats" in state["cache"]:
-            # a chunk's own counters: zeroed here, read with its tokens
-            state = dict(state, cache={
-                **state["cache"],
-                "moe_stats": jnp.zeros_like(state["cache"]["moe_stats"]),
-            })
+        # a chunk's own counters: zeroed here, read with its tokens
+        state = dict(state, cache={
+            name: jnp.zeros_like(leaf) if name in CALL_COUNTERS else leaf
+            for name, leaf in state["cache"].items()
+        })
 
         def step(st, _):
             active = st["active"]
@@ -1221,11 +1201,13 @@ class DecodeEngine:
             return
 
     def _note_prefill(self, req: _Request, slot: int, bucket: int,
-                      prefix_hit: bool, part: str, tokens: int) -> None:
+                      prefix_hit: bool, part: str, tokens: int,
+                      start: int = 0) -> None:
         """One prefill program is about to be dispatched for ``req``:
-        counted (the call, the ``tokens`` of the prompt it runs and the
-        ``bucket`` positions it runs them in: what lies between is
-        padding, which a recurrent layer's scan walks too), stamped on
+        counted (the call, the ``tokens`` of the prompt it runs from
+        offset ``start`` on and the ``bucket`` positions it runs them in:
+        what lies between is padding, which a recurrent layer's scan
+        walks too), stamped on
         the request (for its ``engine.request`` span) and noted as an
         event on the turn's ``engine.admit``."""
         self.prefill_calls += 1
@@ -1233,6 +1215,9 @@ class DecodeEngine:
             self.parts += 1
         self.prefill_tokens += tokens
         self.prefill_positions += bucket if tokens else 0
+        pairs = tokens * start + tokens * (tokens + 1) // 2
+        self.prefill_pairs += pairs
+        self.prefill_pairs_first += 0 if start else pairs
         req.slot, req.bucket, req.prefix_hit = slot, bucket, prefix_hit
         tracing.add_event(
             "prefill", request=req.request_id, slot=slot, bucket=bucket,
@@ -1249,7 +1234,7 @@ class DecodeEngine:
             row = self.pack_admission(rem, self.pad_id, bucket, req)
             row[0, bucket + 1] = slot
             packed = jnp.asarray(row)
-            self._note_prefill(req, slot, bucket, True, "whole", len(rem))
+            self._note_prefill(req, slot, bucket, True, "whole", len(rem), plen)
             self._state, first = self._prefill_ext_runner(plen, bucket)(
                 self.params, self.lora, self._state, entry, packed,
             )
@@ -1335,7 +1320,7 @@ class DecodeEngine:
                 [req.prompt[consumed:consumed + C]], jnp.int32
             )
             self._note_prefill(
-                req, slot, C, adm["had_prefix"], f"part@{consumed}", C
+                req, slot, C, adm["had_prefix"], f"part@{consumed}", C, consumed
             )
             adm["sub"] = self._prefill_part_runner(C)(
                 self.params, self.lora, adm["sub"], seg,
@@ -1354,7 +1339,7 @@ class DecodeEngine:
         row[0, bucket + 1] = slot
         packed = jnp.asarray(row)
         self._note_prefill(
-            req, slot, bucket, adm["had_prefix"], "final", len(rem)
+            req, slot, bucket, adm["had_prefix"], "final", len(rem), consumed
         )
         self._state, first = self._prefill_runner(bucket)(
             self.params, self.lora, self._state, adm["sub"], packed,
@@ -1591,7 +1576,7 @@ class DecodeEngine:
         try:
             with hot_span("engine.dispatch", program=program):
                 self._state, (toks, mask) = chunk_fn(weights, self._state)
-                moe_stats = self._take_chunk_stats()
+                chunk_stats = self._take_chunk_stats()
         except Exception as e:  # noqa: BLE001 — state integrity unknown
             self._fail_engine(e)
             return False
@@ -1628,16 +1613,20 @@ class DecodeEngine:
                     self._emit_first(req, tok, slot)
             # the host waiting for the device: the chunk's tokens
             with hot_span("engine.fetch"):
-                toks, mask, stats = jax.device_get((toks, mask, moe_stats))
+                toks, mask, stats = jax.device_get((toks, mask, chunk_stats))
         except Exception as e:  # noqa: BLE001 — state integrity unknown
             self._fail_engine(e)
             return False
         with hot_span("engine.emit"):
-            if stats is not None:
-                self.moe_local_assignments += int(stats[0])
-                self.moe_experts_hit += int(stats[1])
-                self.moe_dropped += int(stats[2])
-                self.moe_rows_computed += int(stats[3])
+            if "moe_stats" in stats:
+                moe = stats["moe_stats"]
+                self.moe_local_assignments += int(moe[0])
+                self.moe_experts_hit += int(moe[1])
+                self.moe_dropped += int(moe[2])
+                self.moe_rows_computed += int(moe[3])
+            if "sel_stats" in stats:
+                self.sel_causal_rows += int(stats["sel_stats"][0])
+                self.sel_attended_rows += int(stats["sel_stats"][1])
             if self._window_layers and self._spec_fn is None:
                 self._count_window_blocks_skipped(mask)
             self._settle_chunk(toks, mask)
@@ -1689,7 +1678,7 @@ class DecodeEngine:
         them: CUMULATIVE, so that two turns give any interval's counts
         by their difference, whatever a caller did or did not snapshot;
         ``held`` alone is a depth."""
-        return {
+        totals = {
             **{f"slot_steps_{s}": n for s, n in self.slot_steps.items()},
             "wait_lane_s": self.wait_lane_s, "wait_slot_s": self.wait_slot_s,
             "held": len(self._held),
@@ -1697,6 +1686,14 @@ class DecodeEngine:
             "prefill_tokens": self.prefill_tokens,
             "prefill_positions": self.prefill_positions,
         }
+        if "sel_stats" in self._stats_spare:
+            totals.update(
+                sel_causal_rows=self.sel_causal_rows,
+                sel_attended_rows=self.sel_attended_rows,
+                prefill_pairs=self.prefill_pairs,
+                prefill_pairs_first=self.prefill_pairs_first,
+            )
+        return totals
 
     def _close_turn(self) -> None:
         """Once a turn, as its span closes: the registry's view of the
@@ -1715,9 +1712,9 @@ class DecodeEngine:
         }
 
     def _take_chunk_stats(self):
-        """The expert counters of the chunk just dispatched (a device
-        value, None where the cache keeps none), taken OUT of the state
-        and a spare put in their place. A final part dispatched behind
+        """The counters of the chunk just dispatched (``CALL_COUNTERS``:
+        device values by name, those the cache keeps), taken OUT of the
+        state and spares put in their place. A final part dispatched behind
         the chunk donates the state, and with it every buffer the state
         holds: the counters the host is about to fetch must not be among
         them. The spare is the buffer the last chunk's counters came in,
@@ -1726,9 +1723,10 @@ class DecodeEngine:
         Not beside a draft: its rounds add to the counters they find,
         and no part is ever dispatched beside them (``submit``)."""
         cache = self._state["cache"]
-        stats = cache.get("moe_stats")
-        if stats is not None and self._spec_fn is None:
-            cache["moe_stats"], self._stats_spare = self._stats_spare, stats
+        stats = {name: cache[name] for name in self._stats_spare}
+        if self._spec_fn is None:
+            cache.update(self._stats_spare)
+            self._stats_spare = stats
         return stats
 
     def _count_window_blocks_skipped(self, mask) -> None:
@@ -1944,9 +1942,11 @@ class DecodeEngine:
         self._wake.set()
         self._thread.join(timeout=60)
 
-    def slot_state(self, slot: int) -> dict:
-        """Row ``slot`` of every recurrent-state stack (``STATE``), on
-        the host: ``{name: [layers, ...]}``. Of a STOPPED engine only
+    def slot_state(self, slot: int, kind: str = STATE) -> dict:
+        """Row ``slot`` of every stack of ``kind`` (the recurrent
+        state's where not said; ``INDEXED``: a stream's keys, values and
+        indexer keys), on the host: ``{name: [layers, ...]}``. Of a
+        STOPPED engine only
         (while the loop runs it owns the buffers, which its programs
         donate): what the slot's stream has left behind it, its prompt
         and every token emitted but the last, which the next step would
@@ -1955,5 +1955,5 @@ class DecodeEngine:
         return {
             name: np.asarray(leaf[:, slot])
             for name, leaf in self._state["cache"].items()
-            if stack_kind(name) == STATE
+            if stack_kind(name) == kind
         }

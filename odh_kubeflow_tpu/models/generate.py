@@ -34,6 +34,7 @@ from jax.sharding import PartitionSpec as P
 
 from odh_kubeflow_tpu.models.llama import (
     CACHE_KINDS,
+    INDEXED,
     STATE,
     LlamaConfig,
     Params,
@@ -71,7 +72,10 @@ def init_cache(
     for window layers, and for recurrent layers, which keep NO keys and
     values, their state: ``cfg.state_leaves(dtype)`` names each leaf's
     shape behind ``[L_state, B]`` and its dtype (a Mamba-2 layer: the
-    float32 SSM state and the last inputs of its convolution).
+    float32 SSM state and the last inputs of its convolution). A layer
+    whose queries attend the keys an indexer picks (``INDEXED``) keeps
+    keys and values ``{"sk","sv"}`` as a full layer does and the
+    indexer's keys ``{"ik"}: [L_indexed, B, index_dim, max_len]``.
 
     A window layer can only ever be asked for the ``window`` positions
     that end at a query, so it keeps a RING: position ``p`` in slot ``p
@@ -95,13 +99,24 @@ def init_cache(
     periods = cfg.num_layers // len(kinds)
     layers = collections.Counter(kind_of(k) for k in kinds)
 
-    def stacks(kind, length):
+    def stacks(kind, length, names=None):
         shape = (periods * layers[kind], batch_size, length, cfg.kv_dim)
-        return {n: jnp.zeros(shape, dtype) for n in CACHE_KINDS[kind]}
+        return {n: jnp.zeros(shape, dtype) for n in names or CACHE_KINDS[kind]}
 
     cache = {}
     if layers["full"]:
         cache.update(stacks("full", max_len))
+    if layers[INDEXED]:
+        *kv, ik = CACHE_KINDS[INDEXED]
+        cache.update(stacks(INDEXED, max_len, kv))
+        # the indexer's keys, one narrow head: positions along the LANES
+        # (a row of ``index_dim`` = 64 lanes would be padded to 128)
+        cache[ik] = jnp.zeros(
+            (periods * layers[INDEXED], batch_size, cfg.index_dim, max_len), dtype
+        )
+        # a call's counters (``ops/sparse_attention.py``): the positions
+        # its queries could see and those they attended
+        cache["sel_stats"] = jnp.zeros((2,), jnp.int32)
     if layers["window"]:
         ring = max_len
         if widest_part is not None:
@@ -144,6 +159,8 @@ def cache_specs(cfg: LlamaConfig) -> Params:
         "full": P(None, batch, None, AXIS_TENSOR),
         "window": P(None, batch, None, AXIS_TENSOR),
         STATE: P(None, batch),
+        # attention and indexer whole on every chip: rows over the batch
+        INDEXED: P(None, batch),
         None: P(),
     }
     return {

@@ -473,20 +473,28 @@ def cache_write_and_attend(
     """
     if not isinstance(layer, CacheLayer):
         layer = CacheLayer(layer)
+    cache = _cache_write(kk, vv, cache, layer, cache_index)
+    return _cache_attend(q, cache, layer, cache_index, kv_mask), cache
+
+
+def _ring_slot(positions, window, S_kind):
+    # a window layer's stack is a ring; a full one holds every position
+    # the caller may ask for, and a window of tokens that runs past its
+    # end (ragged speculative verify) is clamped to its last slot, which
+    # the engine's kv_mask excludes
+    if window is None:
+        return jnp.clip(positions, 0, S_kind - 1)
+    return positions % S_kind
+
+
+def _cache_write(kk, vv, cache, layer: CacheLayer, cache_index):
+    """``cache_write_and_attend``'s write: this step's keys and values
+    at ``[layer, :, cache_index]`` of the layer's first two stacks."""
     B, S, Hkv, hd = kk.shape
     layer_index, window = layer.index, layer.window
     stacks = dict(zip(("k", "v"), (cache[n] for n in layer.names)))
     S_kind = stacks["k"].shape[2]
-
-    def slot(positions):
-        # a window layer's stack is a ring; a full one holds every
-        # position the caller may ask for, and a window of tokens that
-        # runs past its end (ragged speculative verify) is clamped to
-        # its last slot, which the engine's kv_mask excludes
-        if window is None:
-            return jnp.clip(positions, 0, S_kind - 1)
-        return positions % S_kind
-
+    slot = partial(_ring_slot, window=window, S_kind=S_kind)
     with jax.named_scope("kv_cache_write"):
         new = {"k": kk.reshape(B, S, Hkv * hd), "v": vv.reshape(B, S, Hkv * hd)}
         if getattr(cache_index, "ndim", 0) == 1:
@@ -513,6 +521,18 @@ def cache_write_and_attend(
                 )
                 for kv in ("k", "v")
             }
+    return {**cache, layer.names[0]: stacks["k"], layer.names[1]: stacks["v"]}
+
+
+def _cache_attend(q, cache, layer: CacheLayer, cache_index, kv_mask,
+                  select=None):
+    """``cache_write_and_attend``'s read: attention over the layer's
+    keys and values where they lie; ``select`` is ``decode_attend``'s."""
+    B, S, _, hd = q.shape
+    layer_index, window = layer.index, layer.window
+    stacks = dict(zip(("k", "v"), (cache[n] for n in layer.names)))
+    S_kind = stacks["k"].shape[2]
+    Hkv = stacks["k"].shape[3] // hd
     with jax.named_scope("kv_cache_read"):
         from odh_kubeflow_tpu.ops import pallas_decode_attention as pda
 
@@ -530,7 +550,7 @@ def cache_write_and_attend(
         if _reads_cache_in_place(stacks["k"], hd):
             attn = pda.decode_attend(
                 q, stacks["k"], stacks["v"], layer_index, cache_index,
-                slot_mask, window=window,
+                slot_mask, window=window, select=select,
             )
         else:
             ck, cv = (
@@ -539,11 +559,156 @@ def cache_write_and_attend(
                 ).reshape(B, -1, Hkv, hd)
                 for kv in ("k", "v")
             )
+            if select is not None:
+                from odh_kubeflow_tpu.ops import sparse_attention
+
+                q_pos = jnp.broadcast_to(cache_index, (B,))[:, None] + jnp.arange(S)
+                keep = sparse_attention.selected(
+                    select[0], sparse_attention.visible(q_pos, slot_mask, S_kind),
+                    *select[1:],
+                )
+                return sparse_attention.masked_attention(q, ck, cv, keep)
             attn = dense_attention(
                 q, ck, cv, causal=True, q_offset=cache_index,
                 kv_mask=slot_mask, k_positions=held, window=window,
             )
-    cache = {**cache, layer.names[0]: stacks["k"], layer.names[1]: stacks["v"]}
+    return attn
+
+
+def indexed_write_and_attend(
+    q,  # [B, S, Hq, hd]
+    kk,  # [B, S, Hkv, hd] this step's keys
+    vv,
+    qi,  # [B, S, Hi, di] the indexer's queries, rotated
+    ki,  # [B, S, di] the indexer's keys (one head), rotated
+    wi,  # [B, S, Hi] float32: the indexer's weight a head
+    cache,
+    layer: CacheLayer,  # names ``INDEXED_STACKS``
+    cache_index,  # scalar int32, or [B] int32 with S == 1
+    kv_mask,  # [B, S_max] bool or None
+    topk: int,
+    positions=None,  # [B, S]: where a leaf ``index_topk`` is filled
+    token_mask=None,  # [B, S] bool: the rows ``sel_stats`` counts
+):
+    """``cache_write_and_attend`` for a layer whose queries attend only
+    the ``topk`` keys its indexer picks (``ops/sparse_attention.py``):
+    append keys, values and the indexer's keys at ``[layer, :,
+    cache_index]`` of the layer's three stacks, score every visible
+    position with the indexer, and attend over each query's ``topk``
+    largest (all of them while there are no more).
+
+    One token a row (a decode step): the scores over the row's ``ik``
+    read in place, the threshold by search, the kept positions compacted
+    in position order, the kept rows of the key and value stacks taken by
+    one gather each and attention over the ``[B, topk]`` gathered rows:
+    what the step reads of keys and values does not grow with the
+    context, only the indexer's 2 x ``index_dim`` bytes a position do.
+    Several tokens a row at one offset (a part of a prompt): while
+    ``cache_index + S <= topk`` plain causal attention; else the scores
+    ``[S, cache_index + S]``, a threshold a query and attention over the
+    stacks under the selection as a mask. ``cache["sel_stats"]`` gains
+    the positions the call's queries could see and those they attended,
+    and a leaf ``"index_topk"`` [L, B, positions, topk] is filled if
+    there (-1 past a query's count), as is a leaf ``"index_inputs"`` [L,
+    B, positions, Hi * (di + 1)] float32: each query's ``q^I`` and ``w``
+    as the scores took them (a check computes the selection again from
+    them and the cached keys)."""
+    from odh_kubeflow_tpu.ops import sparse_attention as sa
+
+    B, S, _, _ = q.shape
+    sk, sv, ik_name = layer.names
+    kv_layer = layer._replace(names=(sk, sv))
+    cache = _cache_write(kk, vv, cache, kv_layer, cache_index)
+    ik = cache[ik_name]
+    S_max = ik.shape[3]
+    k = min(topk, S_max)
+    per_row = getattr(cache_index, "ndim", 0) == 1
+    in_place = _reads_cache_in_place(cache[sk], q.shape[3]) and sa.supported(ik)
+    with jax.named_scope("kv_cache_write"):
+        ki = ki.astype(ik.dtype)
+        if per_row:
+            if S != 1:
+                raise NotImplementedError(
+                    "several tokens a row at per-row offsets (speculative "
+                    "verify) would need one selection a token"
+                )
+            if in_place:
+                ik = sa.write_index_keys(ik, ki[:, 0], layer.index, cache_index)
+            else:
+                at = (layer.index, jnp.arange(B), slice(None),
+                      jnp.clip(cache_index, 0, S_max - 1))
+                ik = ik.at[at].set(ki[:, 0])
+        else:
+            ik = jax.lax.dynamic_update_slice(
+                ik, ki.transpose(0, 2, 1)[None],
+                (layer.index, 0, 0, jnp.clip(cache_index, 0, S_max - 1)),
+            )
+    cache = {**cache, ik_name: ik}
+
+    q_off = jnp.broadcast_to(jnp.asarray(cache_index, jnp.int32), (B,))
+    q_pos = q_off[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+    score = sa.index_scores if in_place else sa.index_scores_plain
+
+    def seen():
+        return sa.visible(q_pos, kv_mask, S_max)
+
+    def choose():
+        """(scores, thr, cut) of every query of the call."""
+        scores = score(qi, wi, ik, layer.index, cache_index)
+        with jax.named_scope("index_select"):
+            return scores, *sa.select_threshold(scores, seen(), k)
+
+    picked = None
+    if S == 1:
+        scores, thr, cut = choose()
+        with jax.named_scope("index_select"):
+            keep = sa.selected(scores, seen(), thr, cut)
+            ids, count = sa.compact_positions(keep[:, 0], k)
+        with jax.named_scope("sparse_gather"):
+            rows_k = sa.gather_rows(cache[sk], layer.index, ids)[None]
+            rows_v = sa.gather_rows(cache[sv], layer.index, ids)[None]
+        # a one-layer stack, every row live up to its count
+        gathered = CacheLayer(jnp.int32(0), ("k", "v"))
+        attn = _cache_attend(
+            q, {"k": rows_k, "v": rows_v}, gathered, count - 1, None
+        )
+        picked = jnp.where(ids < S_max, ids, -1)[:, None]
+    elif S_max <= topk:
+        attn = _cache_attend(q, cache, kv_layer, cache_index, kv_mask)
+    else:
+        attn = jax.lax.cond(
+            cache_index + S <= topk,
+            lambda: _cache_attend(q, cache, kv_layer, cache_index, kv_mask),
+            lambda: _cache_attend(
+                q, cache, kv_layer, cache_index, kv_mask, select=choose()
+            ),
+        )
+    if "index_topk" in cache:
+        if picked is None:
+            scores, thr, cut = choose()
+            keep = sa.selected(scores, seen(), thr, cut)
+            ids, _ = sa.compact_positions(keep.reshape(B * S, S_max), k)
+            picked = jnp.where(ids < S_max, ids, -1).reshape(B, S, k)
+        cache["index_topk"] = cache["index_topk"].at[
+            layer.depth, jnp.arange(B)[:, None], positions, :k
+        ].set(picked.astype(jnp.int32))
+    if "index_inputs" in cache:
+        cache["index_inputs"] = cache["index_inputs"].at[
+            layer.depth, jnp.arange(B)[:, None], positions
+        ].set(jnp.concatenate(
+            [qi.astype(ik.dtype).reshape(B, S, -1), wi], axis=-1
+        ).astype(jnp.float32))
+    with jax.named_scope("index_select"):
+        # how many positions a query can see: those the mask holds up to its own
+        n_seen = q_pos + 1 if kv_mask is None else jnp.take_along_axis(
+            jnp.cumsum(kv_mask, axis=1, dtype=jnp.int32),
+            jnp.clip(q_pos, 0, S_max - 1), axis=1,
+        )
+        if token_mask is not None:
+            n_seen = jnp.where(token_mask, n_seen, 0)
+        cache["sel_stats"] = cache["sel_stats"] + jnp.stack(
+            [jnp.sum(n_seen), jnp.sum(jnp.minimum(n_seen, k))]
+        ).astype(jnp.int32)
     return attn, cache
 
 
@@ -576,20 +741,29 @@ def _reads_cache_in_place(cache_leaf, head_dim: int) -> bool:
 # row per layer of the kind and, behind it, a row per slot. An entry of
 # a config's period of kinds is ``None`` (every position: keys and
 # values ``max_len`` long), an int (a window: a ring of keys and
-# values) or ``STATE`` (a recurrent layer: no keys and values, a state
-# of the shapes ``cfg.state_leaves`` gives).
+# values), ``STATE`` (a recurrent layer: no keys and values, a state
+# of the shapes ``cfg.state_leaves`` gives) or ``INDEXED`` (a layer whose
+# queries attend only the keys an indexer picks: keys and values
+# ``max_len`` long and, beside them, the indexer's own keys, one head of
+# ``cfg.index_dim`` a position, laid ``[index_dim, max_len]`` a row so
+# that positions lie along the lanes; ``ops/sparse_attention.py``).
 FULL_STACKS = ("k", "v")
 WINDOW_STACKS = ("wk", "wv")
 STATE_STACKS = ("ssm", "conv")
+INDEXED_STACKS = ("sk", "sv", "ik")
 STATE = "state"
-CACHE_KINDS = {"full": FULL_STACKS, "window": WINDOW_STACKS, STATE: STATE_STACKS}
+INDEXED = "indexed"
+CACHE_KINDS = {
+    "full": FULL_STACKS, "window": WINDOW_STACKS, STATE: STATE_STACKS,
+    INDEXED: INDEXED_STACKS,
+}
 
 
 def kind_of(entry) -> str:
     """The kind of cache an entry of a period of kinds asks for."""
     if entry is None:
         return "full"
-    return STATE if entry == STATE else "window"
+    return entry if entry in (STATE, INDEXED) else "window"
 
 
 def layer_kinds(cfg) -> tuple:

@@ -35,6 +35,15 @@ float32 softmax statistics, weights cast to the cache's dtype for the
 ``@ v`` matmul at float32 accumulation; the normalisation is applied to
 the float32 accumulator (online softmax over kv-blocks).
 
+A layer whose queries attend only the keys an indexer picked for them
+(``ops/sparse_attention.py``) hands the selection in as it stands: the
+indexer's float32 scores ``[B, S, S_max]`` and, a query, a threshold and
+the position of the last tie that fits (``select``). The keys a query
+keeps are those whose score lies above its threshold or equals it at a
+position up to that edge; the kernel reads a ``[positions, block_k]``
+tile of the scores beside each kv-block and the mask is two more
+compares. Without ``select`` neither operand nor compare exists.
+
 All ``Hkv`` heads of a kv-block are one grid step (a ``[block_k,
 Hkv*hd]`` tile whose lane slices are stacked into one head-batched
 matmul): at batch 16 a step per head would cost more in grid overhead
@@ -106,7 +115,10 @@ def block_k_for(cache_leaf, block_k: int = DEFAULT_BLOCK_K) -> int:
 
 
 def _kernel(layer_ref, qoff_ref, qpos_ref, q_ref, k_ref, v_ref, kpos_ref,
-            o_ref, acc, m, l, *, heads, head_dim, window, live, scale):
+            *rest, heads, head_dim, window, live, scale, group=None):
+    # ``group``: a selection is handed in (three more operands), and a
+    # row block is ``group`` runs of the same positions, one a member
+    *select, o_ref, acc, m, l = rest
     del layer_ref
     b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     block_rows = q_ref.shape[1]
@@ -138,6 +150,13 @@ def _kernel(layer_ref, qoff_ref, qpos_ref, q_ref, k_ref, v_ref, kpos_ref,
             preferred_element_type=jnp.float32, precision=precision,
         ) * scale
         s = jnp.where(mask[None], s, _NEG_INF)
+        if group is not None:
+            score_ref, thr_ref, cut_ref = select
+            sc, thr = score_ref[...], thr_ref[...]
+            keep = (sc > thr) | ((sc == thr) & (k_pos <= cut_ref[...]))
+            s = jnp.where(
+                keep[None, None], s.reshape(heads, group, *keep.shape), _NEG_INF
+            ).reshape(s.shape)
         m_prev = m[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -176,6 +195,7 @@ def decode_attend(
     kv_mask=None,  # [B, S_max] bool, True = attend
     *,
     window=None,  # positions a query sees, its own included; None: all
+    select=None,  # (scores [B, S, S_max] f32, thr [B, S] f32, cut [B, S] i32)
     block_k: int = DEFAULT_BLOCK_K,
     block_rows: int = DEFAULT_BLOCK_ROWS,
     interpret: bool = False,
@@ -184,7 +204,9 @@ def decode_attend(
     q_offset=q_offset, kv_mask=kv_mask, window=window)`` without taking
     the layer out of the stack. With a ``window`` the layer is a ring:
     position ``p`` is read from slot ``p % S_max`` (``kv_mask`` is by
-    slot). Returns [B, S, Hq, hd]."""
+    slot). With ``select`` a query sees, of those, only the keys whose
+    score lies above its ``thr`` or equals it at a position up to its
+    ``cut`` (``ops/sparse_attention.selected``). Returns [B, S, Hq, hd]."""
     B, S, Hq, hd = q.shape
     _, _, S_max, width = cache_k.shape
     heads = width // hd
@@ -196,18 +218,43 @@ def decode_attend(
         max(_MAX_STATE_ROWS // heads // _ROW_ALIGN * _ROW_ALIGN, _ROW_ALIGN),
     )
 
-    # rows of one kv head: (position, member of its group), padded to
-    # whole sublane tiles / row blocks; a padded row's output is dropped
-    rows = S * group
-    rows_p = -(-rows // _ROW_ALIGN) * _ROW_ALIGN
-    if rows_p > block_rows:
-        rows_p = -(-rows // block_rows) * block_rows
-    block_rows = min(block_rows, rows_p)
-    qg = q.reshape(B, S, heads, group, hd).transpose(0, 2, 1, 3, 4)
-    qg = qg.reshape(B, heads, rows, hd)
-    qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows_p - rows), (0, 0)))
     q_off = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32), (B,))
-    q_pos = q_off[:, None] + (jnp.arange(rows_p, dtype=jnp.int32) // group)
+    if select is None:
+        # rows of one kv head: (position, member of its group), padded to
+        # whole sublane tiles / row blocks; a padded row's output is dropped
+        rows = S * group
+        rows_p = -(-rows // _ROW_ALIGN) * _ROW_ALIGN
+        if rows_p > block_rows:
+            rows_p = -(-rows // block_rows) * block_rows
+        block_rows = min(block_rows, rows_p)
+        qg = q.reshape(B, S, heads, group, hd).transpose(0, 2, 1, 3, 4)
+        qg = qg.reshape(B, heads, rows, hd)
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows_p - rows), (0, 0)))
+        q_pos = q_off[:, None] + (jnp.arange(rows_p, dtype=jnp.int32) // group)
+    else:
+        # a row block holds ``per`` positions, ``group`` times over: rows
+        # (member, position), so that the positions' one tile of scores
+        # masks every member's run of rows as it lies
+        per = min(
+            max(block_rows // group // _ROW_ALIGN, 1) * _ROW_ALIGN,
+            -(-S // _ROW_ALIGN) * _ROW_ALIGN,
+        )
+        S_p = -(-S // per) * per
+        block_rows, rows, rows_p = per * group, S_p * group, S_p * group
+        pad_s = ((0, 0), (0, S_p - S))
+        qg = jnp.pad(q, pad_s + ((0, 0), (0, 0)))
+        qg = qg.reshape(B, S_p // per, per, heads, group, hd)
+        qg = qg.transpose(0, 3, 1, 4, 2, 5).reshape(B, heads, rows_p, hd)
+        r = jnp.arange(rows_p, dtype=jnp.int32)
+        q_pos = q_off[:, None] + (r // block_rows * per + r % per)
+        scores, thr, cut = select
+        if S_p != S:
+            scores = jnp.pad(scores, pad_s + ((0, 0),))
+            thr, cut = jnp.pad(thr, pad_s), jnp.pad(cut, pad_s)
+        select = (
+            scores.astype(jnp.float32), thr.astype(jnp.float32)[..., None],
+            cut.astype(jnp.int32)[..., None],
+        )
     if window is None:
         # a layer that sees everything holds position p in slot p
         k_pos = jnp.broadcast_to(jnp.arange(S_max, dtype=jnp.int32), (B, S_max))
@@ -232,10 +279,22 @@ def decode_attend(
         (None, None, block_k, width),
         lambda b, i, j, layer, q_off: (layer[0], b, kv_block(b, i, j, q_off), 0),
     )
+    select_specs, geometry = [], {}
+    if select is not None:
+        geometry = {"group": group}
+        per_query = pl.BlockSpec((None, per, 1), lambda b, i, j, *_: (b, i, 0))
+        select_specs = [
+            pl.BlockSpec(
+                (None, per, block_k),
+                lambda b, i, j, layer, q_off: (b, i, kv_block(b, i, j, q_off)),
+            ),
+            per_query,
+            per_query,
+        ]
     out = pl.pallas_call(
         functools.partial(
             _kernel, heads=heads, head_dim=hd, window=window, live=live,
-            scale=hd**-0.5,
+            scale=hd**-0.5, **geometry,
         ),
         name="decode_attend",
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -255,6 +314,7 @@ def decode_attend(
                     (None, 1, block_k),
                     lambda b, i, j, layer, q_off: (b, 0, kv_block(b, i, j, q_off)),
                 ),
+                *select_specs,
             ],
             out_specs=pl.BlockSpec(
                 (None, heads, block_rows, hd), lambda b, i, j, *_: (b, 0, i, 0)
@@ -270,6 +330,10 @@ def decode_attend(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(layer, q_off, q_pos[:, :, None], qg, cache_k, cache_v, k_pos)
+    )(layer, q_off, q_pos[:, :, None], qg, cache_k, cache_v, k_pos,
+      *(select or ()))
+    if select is not None:
+        out = out.reshape(B, heads, S_p // per, group, per, hd)
+        return out.transpose(0, 2, 4, 1, 3, 5).reshape(B, S_p, Hq, hd)[:, :S]
     out = out[:, :, :rows].reshape(B, heads, S, group, hd)
     return out.transpose(0, 2, 1, 3, 4).reshape(B, S, Hq, hd)

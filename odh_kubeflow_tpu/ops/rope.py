@@ -4,7 +4,9 @@ the two pairings models are published with: the half-split one
 leading slice of the head where the angles are made for one) and the
 interleaved one (``apply_rope_interleaved``: pairs ``(2i, 2i + 1)``,
 GPT-J's, which Cohere's ``rope_gptj`` names). Both take the same
-``rope_angles``.
+``rope_angles``; ``stream_angles`` makes the same tables from THREE
+position streams (temporal, height, width), each turning its own section
+of the frequencies (Qwen2-VL's M-RoPE).
 
 Angles are precomputed once per forward *outside* the layer scan so the
 sin/cos tables are computed a single time and live in registers/VMEM
@@ -25,6 +27,27 @@ def rope_angles(
     freq_exponents = jnp.arange(0, head_dim // 2, dtype=jnp.float32) / (head_dim // 2)
     inv_freq = theta**-freq_exponents  # [hd/2]
     angles = positions.astype(jnp.float32)[..., None] * inv_freq  # [B, S, hd/2]
+    return jnp.sin(angles), jnp.cos(angles)
+
+
+def stream_angles(
+    positions: jnp.ndarray,  # [3, B, S] int32: temporal, height, width
+    head_dim: int,
+    theta: float,
+    sections: tuple,  # frequencies a stream turns, in order; sums to hd/2
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """``rope_angles`` with frequency ``i`` turned by the stream whose
+    section holds it (contiguous ranges, e.g. 16 | 24 | 24 of a head of
+    128's 64). Three equal streams give ``rope_angles`` of one."""
+    half = head_dim // 2
+    assert sum(sections) == half and len(sections) == 3, (sections, half)
+    inv_freq = theta ** -(jnp.arange(0, half, dtype=jnp.float32) / half)
+    stream = jnp.repeat(
+        jnp.arange(3), jnp.asarray(sections), total_repeat_length=half
+    )
+    # [B, S, hd/2]: each frequency's own stream's position
+    pos = jnp.moveaxis(positions.astype(jnp.float32), 0, -1)[..., stream]
+    angles = pos * inv_freq
     return jnp.sin(angles), jnp.cos(angles)
 
 

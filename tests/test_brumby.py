@@ -258,7 +258,7 @@ def test_the_cache_has_the_kinds_table_at_this_familys_shapes(tiny):
     assert cache[state].shape == (4, 3, 2, 9, 16, 16) and cache[state].dtype == F32
     assert cache[norm].shape == (4, 3, 2, 9, 16) and cache[norm].dtype == F32
     assert cache_bytes(cache) == {
-        "full": 0, "window": 0,
+        "full": 0, "window": 0, "indexed": 0,
         "state": (cache[state].size + cache[norm].size) * 4,
     }
     longer = init_cache(cfg, 3, 4096, jnp.bfloat16)

@@ -95,7 +95,7 @@ def test_parts_then_decode_through_the_ring_is_the_full_forward(share):
     assert cache["k"].shape == (2, 2, 48, cfg.kv_dim)
     assert cache_bytes(cache) == {
         "full": 2 * 2 * 2 * 48 * cfg.kv_dim * 4, "window": 2 * 6 * 2 * 16 * cfg.kv_dim * 4,
-        "state": 0,
+        "state": 0, "indexed": 0,
     }
     for b in range(2):
         want, _ = ref.logits(params, tokens[b], file_config(cfg))

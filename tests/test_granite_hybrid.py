@@ -199,6 +199,7 @@ def test_the_cache_has_state_stacks_and_keys_for_attention_layers_only(tiny):
     assert cache_bytes(cache) == {
         "full": 2 * 2 * 3 * 32 * cfg.kv_dim * 2,
         "window": 0,
+        "indexed": 0,
         "state": cache["ssm"].size * 4 + cache["conv"].size * 2,
     }
     specs = cache_specs(cfg)

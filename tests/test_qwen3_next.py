@@ -369,7 +369,7 @@ def test_the_cache_has_the_kinds_table_at_this_familys_shapes(tiny):
     assert cache["conv"].dtype == jnp.bfloat16
     assert cache["moe_stats"].shape == (4,)
     assert cache_bytes(cache) == {
-        "full": 2 * 2 * 3 * 32 * cfg.kv_dim * 2, "window": 0,
+        "full": 2 * 2 * 3 * 32 * cfg.kv_dim * 2, "window": 0, "indexed": 0,
         "state": cache["ssm"].size * 4 + cache["conv"].size * 2,
     }
     assert cfg.layer_kinds == (llama.STATE,) * 3 + (None,)

@@ -600,3 +600,127 @@ def test_brumby_stage_updates_its_state_in_place(one_chip, monkeypatch, B, S):
     one_layer = stacks // 2
     print(f"brumby {B}x{S}: temp {mem.temp_size_in_bytes / 1e6:.1f} MB")
     assert mem.temp_size_in_bytes < (one_layer // 4 if S == 1 else 1.5e9)
+
+
+# ---- Keye-VL-2.0's share (keye_vl): 32 query heads onto 4 key/value heads
+# of 128, an indexer of 16 heads of 64 that keeps 2048 keys; 16 slots x 24576
+
+
+@pytest.mark.parametrize(
+    "B,S", [(16, 1), (1, 2048), (1, 64)], ids=["decode", "part", "bucket-64"]
+)
+def test_index_scores_compiles_at_published_widths(one_chip, B, S):
+    from odh_kubeflow_tpu.ops import sparse_attention as sa
+
+    sds = jax.ShapeDtypeStruct
+    stack = sds((12, B, 64, 24576), jnp.bfloat16)
+    compiled = jax.jit(sa.index_scores).lower(*_on(one_chip, (
+        sds((B, S, 16, 64), jnp.bfloat16), sds((B, S, 16), jnp.float32), stack,
+        sds((), jnp.int32), sds((B,) if S == 1 else (), jnp.int32),
+    ))).compile()
+    assert "index_scores" in compiled.as_text()
+    # the stack goes to the kernel as it is: no layer of it beside it
+    assert compiled.memory_analysis().temp_size_in_bytes < stack.size * 2 // 12
+
+
+def test_a_decode_steps_index_keys_are_written_in_place(one_chip):
+    from odh_kubeflow_tpu.ops import sparse_attention as sa
+
+    sds = jax.ShapeDtypeStruct
+    stack = sds((12, 16, 64, 24576), jnp.bfloat16)
+    compiled = sa.write_index_keys.lower(*_on(one_chip, (
+        stack, sds((16, 64), jnp.bfloat16), sds((), jnp.int32), sds((16,), jnp.int32),
+    ))).compile()
+    mem = compiled.memory_analysis()
+    assert "index_key_write" in compiled.as_text()
+    assert mem.alias_size_in_bytes >= stack.size * 2
+    assert mem.temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("S", [2048, 64], ids=["part", "bucket-64"])
+def test_decode_attend_under_a_selection_compiles_at_published_widths(one_chip, S):
+    sds = jax.ShapeDtypeStruct
+    S_max = 24576
+    cache = sds((12, 1, S_max, 512), jnp.bfloat16)
+
+    def attend(q, k, v, layer, offset, kv_mask, scores, thr, cut):
+        return decode_attend(
+            q, k, v, layer, offset, kv_mask, select=(scores, thr, cut)
+        )
+
+    compiled = jax.jit(attend).lower(*_on(one_chip, (
+        sds((1, S, 32, 128), jnp.bfloat16), cache, cache, sds((), jnp.int32),
+        sds((), jnp.int32), sds((1, S_max), jnp.bool_),
+        sds((1, S, S_max), jnp.float32), sds((1, S), jnp.float32),
+        sds((1, S), jnp.int32),
+    ))).compile()
+    assert "decode_attend" in compiled.as_text()
+    # neither a layer of the cache nor the scores are copied
+    assert compiled.memory_analysis().temp_size_in_bytes < S * S_max * 4 // 2
+
+
+@pytest.mark.parametrize("B,S", [(16, 1), (1, 2048)], ids=["decode", "part"])
+def test_keye_vl_share_reads_its_three_stacks_in_place(one_chip, monkeypatch, B, S):
+    """Two layers at published widths, a decode step of 16 slots and a
+    part of 2048 positions at 24576, compiled for the chip: the three
+    stacks are aliased, the kernels are there, and a decode step takes
+    its 2048 rows a slot from the 4-D key and value stacks by ONE gather
+    each: no layer of any stack is sliced, copied or transposed out."""
+    import re
+
+    from odh_kubeflow_tpu.models import keye_vl as kv
+    from odh_kubeflow_tpu.models import moe
+
+    monkeypatch.setattr(llama, "_reads_cache_in_place", lambda leaf, hd: True)
+    monkeypatch.setattr(moe, "reads_banks_in_place", lambda banks: True)
+    cfg = kv.KeyeVLConfig(num_layers=2, vocab_size=18992, experts_held=(0, 16))
+    params = jax.eval_shape(
+        lambda: kv.init_params(jax.random.key(0), cfg, jnp.bfloat16)
+    )
+    for name in ("moe_gate", "moe_up", "moe_down", "wq", "wk", "wv", "wo",
+                 "wq_idx", "wk_idx"):
+        params["layers"][name] = _int8_bank(params["layers"][name].shape)
+    max_len = 24576
+    cache = jax.eval_shape(lambda: init_cache(cfg, B, max_len, widest_part=2048))
+    assert cache["sk"].shape == cache["sv"].shape == (2, B, max_len, 512)
+    assert cache["ik"].shape == (2, B, 64, max_len)
+
+    def step(params, cache, tokens, index, kv_mask):
+        positions = index[:, None] if S == 1 else index + jnp.arange(S)[None]
+        return kv.forward_with_cache(
+            params, tokens, cfg, cache, index, positions=positions,
+            kv_mask=kv_mask, token_mask=kv_mask[:, :S],
+        )
+
+    compiled = jax.jit(step, donate_argnums=1).lower(*_on(one_chip, (
+        params, cache, jax.ShapeDtypeStruct((B, S), jnp.int32),
+        jax.ShapeDtypeStruct((B,) if S == 1 else (), jnp.int32),
+        jax.ShapeDtypeStruct((B, max_len), jnp.bool_),
+    ))).compile()
+    mem = compiled.memory_analysis()
+    stacks = sum(
+        v.size * v.dtype.itemsize for n, v in cache.items()
+        if llama.stack_kind(n)
+    )
+    assert mem.alias_size_in_bytes >= stacks
+    text = compiled.as_text()
+    for kernel in ("index_scores", "decode_attend", "moe_local_ffn"):
+        assert kernel in text, kernel
+    whole_layer = re.compile(
+        rf"= bf16\[(?:\d+,)?{B},(?:{max_len},512|64,{max_len})\][^ ]* "
+        r"(dynamic-slice|copy|transpose|gather)\("
+    )
+    assert not whole_layer.findall(text)
+    print(f"keye_vl {B}x{S}: temp {mem.temp_size_in_bytes / 1e6:.1f} MB")
+    if S == 1:
+        assert "index_key_write" in text
+        gathers = re.findall(
+            r"= bf16\[16,2048,512\][^ ]* gather\([^\n]*slice_sizes=\{1,1,1,512\}", text
+        )
+        assert len(gathers) == 2, gathers
+        # a slot's layer of keys is 25 MB, of indexer keys 3 MB: the
+        # step's temporaries are the rows gathered and little else
+        assert mem.temp_size_in_bytes < 100e6, mem.temp_size_in_bytes
+    else:
+        # the scores [2048, 24576] float32 (201 MB) and the search's keys
+        assert mem.temp_size_in_bytes < 1.2e9, mem.temp_size_in_bytes
